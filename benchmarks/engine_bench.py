@@ -26,8 +26,9 @@ future PR has a perf trajectory for the unified hot path.  Backends:
 
 Every row also gets a ``roofline`` entry — modeled bytes-moved / FLOPs /
 bound / achieved-vs-roofline % from the dtype-aware analytic
-:class:`repro.launch.roofline.RooflineModel` (drawn against the TPU v5e
-spec; interpret-mode achieved % is honestly tiny) — and the measured
+:class:`repro.launch.roofline.RooflineModel` (drawn against the published
+peaks of the device the run found; a device without published peaks, the
+CPU among them, gets bytes and FLOPs only) — and the measured
 autotune winner is recorded under ``autotune``.
 
 The record's ``kernel_launches`` field is the analytic per-eval Pallas
@@ -110,9 +111,11 @@ def quant_gate(record: dict | None = None,
 def roofline_gate(record: dict | None = None,
                   path: Path | str = OUT_PATH) -> None:
     """Fail (raise) unless (a) every timed backend row carries a roofline
-    entry with bytes-moved / bound / achieved %, and (b) the measured
-    autotune winner is no slower than the hand-picked block_b default
-    (within timing-noise slack)."""
+    entry with bytes-moved — plus bound / achieved % exactly when the run's
+    device has published peaks — and (b) the measured autotune winner is
+    no slower than the hand-picked block_b default (within timing-noise
+    slack)."""
+    from repro.launch.roofline import PEAKS
     if record is None:
         record = json.loads(Path(path).read_text())
     roof = record.get("roofline")
@@ -123,10 +126,12 @@ def roofline_gate(record: dict | None = None,
         if not entry:
             raise SystemExit(f"roofline gate FAILED: no roofline entry "
                              f"for backend row {name!r}")
+        priced = record.get("device", {}).get("kind") in PEAKS
         for field in ("bytes_moved", "bound", "achieved_pct"):
-            if field not in entry:
+            if (field in entry) != (priced or field == "bytes_moved"):
                 raise SystemExit(f"roofline gate FAILED: roofline[{name!r}]"
-                                 f" lacks {field!r}")
+                                 f" {'lacks' if priced else 'prices'} "
+                                 f"{field!r}")
     tuned = record["backend_us"].get("fused-tuned")
     hand = record["backend_us"].get("fused")
     if tuned is None or hand is None:
@@ -212,7 +217,12 @@ def run(out_path: Path | str | None = OUT_PATH) -> list[str]:
     scan_rows = {"reference", "pallas", "pallas-chunked", "reference-int8"}
     compact_rows = {"fused-tuned": tuned.compact, "fused-auto": True,
                     "fused-bf16": True, "fused-int8": True}
-    from repro.launch.roofline import RooflineModel
+    from repro.launch.roofline import PEAKS, RooflineModel
+    dev = jax.devices()[0]
+    record["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                        "count": len(jax.devices())}
+    # a device without published peaks gets traffic only: no achieved %
+    spec = PEAKS.get(dev.device_kind)
     base_hops = {}
     for name, eng in engines.items():
         dt, res = _time_engine(eng, x, key, policy)
@@ -227,7 +237,8 @@ def run(out_path: Path | str | None = OUT_PATH) -> list[str]:
             assert (hops == base_hops[prec]).all(), \
                 f"{name} diverged on hops"
         energy_pj = res.energy_report().per_example_pj
-        roof = RooflineModel(eng.tables.pack(prec), x.shape[1]).estimate(
+        roof = RooflineModel(eng.tables.pack(prec), x.shape[1],
+                             spec).estimate(
             name,
             B,
             iters=gc.n_groves if name in scan_rows else int(hops.max()),
@@ -243,7 +254,7 @@ def run(out_path: Path | str | None = OUT_PATH) -> list[str]:
                     f"energy_pj={energy_pj:.1f},"
                     f"launches={launches[name]},"
                     f"table_bytes={table_bytes[prec]},"
-                    f"roof_bound={roof.bound},"
+                    f"roof_bound={roof.bound if spec else 'unpriced'},"
                     f"roof_mb={roof.bytes_moved / 1e6:.2f}")
     # the auto-chunk regression fix: auto must not chunk a resident pack
     assert engines["fused-auto"]._resolve_chunk(

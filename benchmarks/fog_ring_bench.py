@@ -1,24 +1,22 @@
 """FoG ring on a device mesh (§3.2.2 scaled): per-hop wall time + traffic.
 
-Runs the FogEngine ring backend (shard_map + ppermute) on 8 forced host
-devices and reports lane occupancy decay (how fast confident lanes die ->
-the load self-balancing the paper's queue priority scheme provides), for
-both the classic 1-grove-per-shard ring and the generalized
-multiple-groves-per-shard placement.  Run as a subprocess to get its own
-XLA device count.
+Runs the FogEngine ring backend (shard_map + ppermute) on 8 devices and
+reports lane occupancy decay (how fast confident lanes die -> the load
+self-balancing the paper's queue priority scheme provides), for both the
+classic 1-grove-per-shard ring and the generalized multiple-groves-per-shard
+placement.  ``benchmarks.run`` runs it in its own process with eight host
+devices forced (``--xla_force_host_platform_device_count=8``, which only
+the CPU platform reads).
 """
 from __future__ import annotations
 
-import subprocess
-import sys
-import textwrap
-from pathlib import Path
+import time
 
-SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import time
-    import jax, jax.numpy as jnp, numpy as np
+
+def run() -> list[str]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
     from repro.core import FogEngine, FogPolicy, split
     from repro.data import make_dataset
     from repro.forest import TrainConfig, train_random_forest
@@ -28,7 +26,7 @@ SCRIPT = textwrap.dedent("""
                              TrainConfig(n_trees=16, max_depth=8, seed=1))
     gc = split(rf, 2)
     x = jnp.asarray(ds.x_test[:1024] if len(ds.x_test) >= 1024 else ds.x_test)
-
+    rows = []
     for n_shards in [8, 4]:
         mesh = jax.make_mesh((n_shards,), ("grove",))
         engine = FogEngine(gc, backend="ring", mesh=mesh)
@@ -41,26 +39,11 @@ SCRIPT = textwrap.dedent("""
             hops = np.asarray(res.hops)
             acc = (np.asarray(res.label) == ds.y_test[: len(hops)]).mean()
             occ = [float((hops > j).mean()) for j in range(8)]
-            print(f"CSV,fog_ring,shards={n_shards},thresh={thresh},"
-                  f"us={dt:.0f},acc={acc:.4f},"
-                  f"mean_hops={hops.mean():.2f},occupancy=" +
-                  "|".join(f"{o:.2f}" for o in occ))
-""")
-
-
-def run() -> list[str]:
-    repo = Path(__file__).resolve().parent.parent
-    proc = subprocess.run(
-        [sys.executable, "-c", SCRIPT],
-        env={"PYTHONPATH": str(repo / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
-             # forced-host-device scripts must not probe a real TPU: the
-             # libtpu worker handshake hangs ~8 min before falling back
-             "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        return [f"fog_ring_bench FAILED: {proc.stderr[-500:]}"]
-    return [l for l in proc.stdout.splitlines() if l.startswith("CSV")]
+            rows.append(f"CSV,fog_ring,shards={n_shards},thresh={thresh},"
+                        f"us={dt:.0f},acc={acc:.4f},"
+                        f"mean_hops={hops.mean():.2f},occupancy="
+                        + "|".join(f"{o:.2f}" for o in occ))
+    return rows
 
 
 if __name__ == "__main__":

@@ -452,26 +452,36 @@ def registry_gate(data: dict) -> list[str]:
 # CLI + benchmarks.run integration
 # --------------------------------------------------------------------------
 
-def run(smoke: bool = True):
-    """benchmarks.run section hook: subprocess for a clean jax (and so a
-    crashed bench cannot poison the parent's device state)."""
-    import subprocess
-    repo = Path(__file__).resolve().parent.parent
-    env = {
-        "PYTHONPATH": str(repo / "src"),
-        "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-        "HOME": os.environ.get("HOME", "/root"),
-        "JAX_PLATFORMS": "cpu",
-    }
-    cmd = [sys.executable, "-m", "benchmarks.registry_bench"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                          text=True, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"registry_bench failed:\n{proc.stdout}\n{proc.stderr}")
-    yield from (ln for ln in proc.stdout.splitlines() if ln.strip())
+def run(smoke: bool = True, seed: int = 0, workdir=None, out=OUT_PATH):
+    """Bench, write ``out``, yield the report lines; raise when the gate
+    fails.  Runs in this process (``benchmarks.run`` gives every section
+    its own process)."""
+    data = bench(smoke=smoke, seed=seed, workdir=workdir)
+    Path(out).write_text(json.dumps(data, indent=1))
+    c, sw = data["cache"], data["swap"]
+    yield (f"[registry_bench] {len(data['tenants'])} tenants, "
+           f"{data['completed']}/{data['offered']} done, "
+           f"acc {data['accuracy']}, {data['throughput_rps']} req/s")
+    yield (f"[registry_bench] cache hit {c['hit_rate']}, "
+           f"{c['evictions']} evictions, peak {c['peak_bytes']}/"
+           f"{c['budget_bytes']} B")
+    yield (f"[registry_bench] swap p99 {sw['p99_pre_ms']}ms -> "
+           f"{sw['p99_post_ms']}ms, inflight {sw['inflight_completed']}/"
+           f"{sw['inflight']} done, on v1 {sw['inflight_on_old_version']}")
+    for t, row in data["tenants"].items():
+        yield (f"[registry_bench] {t}: budget {row['budget_nj']} nJ, "
+               f"rolling {row['rolling_nj']} nJ, rung {row['rung_final']} "
+               f"({row['rung_precision'] or 'fp32'}), "
+               f"{row['transitions']} transitions")
+    yield (f"[registry_bench] canary observed "
+           f"{data['canary']['observed_fraction']} vs "
+           f"{data['canary']['fraction']}, promoted "
+           f"{data['canary']['promoted']}")
+    yield f"[registry_bench] wrote {out}"
+    fails = registry_gate(data)
+    if fails:
+        raise RuntimeError("[registry_gate] FAIL:\n  " + "\n  ".join(fails))
+    yield "[registry_gate] ok"
 
 
 def main() -> None:
@@ -496,33 +506,13 @@ def main() -> None:
         print("[registry_gate] ok")
         return
 
-    data = bench(smoke=args.smoke, seed=args.seed, workdir=args.workdir)
-    Path(args.out).write_text(json.dumps(data, indent=1))
-    c, sw = data["cache"], data["swap"]
-    print(f"[registry_bench] {len(data['tenants'])} tenants, "
-          f"{data['completed']}/{data['offered']} done, "
-          f"acc {data['accuracy']}, {data['throughput_rps']} req/s")
-    print(f"[registry_bench] cache hit {c['hit_rate']}, "
-          f"{c['evictions']} evictions, peak {c['peak_bytes']}/"
-          f"{c['budget_bytes']} B")
-    print(f"[registry_bench] swap p99 {sw['p99_pre_ms']}ms -> "
-          f"{sw['p99_post_ms']}ms, inflight {sw['inflight_completed']}/"
-          f"{sw['inflight']} done, on v1 {sw['inflight_on_old_version']}")
-    for t, row in data["tenants"].items():
-        print(f"[registry_bench] {t}: budget {row['budget_nj']} nJ, "
-              f"rolling {row['rolling_nj']} nJ, rung {row['rung_final']} "
-              f"({row['rung_precision'] or 'fp32'}), "
-              f"{row['transitions']} transitions")
-    print(f"[registry_bench] canary observed "
-          f"{data['canary']['observed_fraction']} vs "
-          f"{data['canary']['fraction']}, promoted "
-          f"{data['canary']['promoted']}")
-    print(f"[registry_bench] wrote {args.out}")
-    fails = registry_gate(data)
-    if fails:
-        print("[registry_gate] FAIL:\n  " + "\n  ".join(fails))
+    try:
+        for ln in run(smoke=args.smoke, seed=args.seed, workdir=args.workdir,
+                      out=args.out):
+            print(ln)
+    except RuntimeError as e:
+        print(e)
         sys.exit(1)
-    print("[registry_gate] ok")
 
 
 if __name__ == "__main__":

@@ -6,9 +6,10 @@ Reads the ``roofline`` section :mod:`benchmarks.engine_bench` attaches to
 every backend row (modeled bytes-moved / FLOPs / bound / achieved % from
 :class:`repro.launch.roofline.RooflineModel`) and prints the markdown
 table the README's backend matrix is refreshed from.  The achieved %
-column is drawn against the TPU v5e spec: on the interpret-mode CPU
-container it is honestly tiny — the number to read there is the *relative*
-bytes-moved ranking (fused moves ~iters× fewer table bytes than per-hop).
+column is drawn against the published peaks of the device the benchmark
+ran on; a run on a device without published peaks (the CPU) prints "not
+measured" there, and the number to read is the *relative* bytes-moved
+ranking (fused moves ~iters× fewer table bytes than per-hop).
 
 The legacy mode — deriving three-term rooflines from LM dry-run JSONL
 records — moved with the HLO cost model to :mod:`repro.launch.hlo_cost`;
@@ -52,8 +53,10 @@ def engine_table(rows: list[dict]) -> list[str]:
         out.append(
             f"| {r['name']} | {lat_cell} | "
             f"{r['bytes_moved'] / 1e6:.2f} MB | {r['flops']:.3g} | "
-            f"{r['bound']} | {r['ideal_s'] * 1e6:.1f} us | "
-            f"{r.get('achieved_pct', 0.0):.3f}% | {bottleneck_note(r)} |")
+            + (f"{r['bound']} | {r['ideal_s'] * 1e6:.1f} us | "
+               f"{r['achieved_pct']:.3f}% | " if "achieved_pct" in r else
+               "unpriced | — | not measured | ")
+            + f"{bottleneck_note(r)} |")
     return out
 
 

@@ -1,15 +1,65 @@
 """Benchmark runner: one section per paper table/figure (+ beyond-paper).
 
-    PYTHONPATH=src python -m benchmarks.run [--quick]
+    PYTHONPATH=src python -m benchmarks.run [--quick] [--only a,b]
 
-Prints CSV blocks; the roofline table is produced by the dry-run
-(launch/dryrun.py) since it needs 512 forced host devices.
+Prints CSV blocks.  Every section runs in its own child process, one after
+another, with this process's environment: the parent never touches JAX, so
+the child that runs a section is the one process holding the device.
+Sections that need several devices on a CPU host (``serve``: 4,
+``fog_ring``: 8) get that many forced host devices in their child; the flag
+only affects the CPU platform.  The roofline table is produced by the
+dry-run (launch/dryrun.py) since it needs 512 forced host devices.
 """
 from __future__ import annotations
 
 import argparse
+import importlib
+import os
+import subprocess
 import sys
 import time
+
+# section -> (module, callable, forced host devices or None)
+SECTIONS = {
+    "engine": ("engine_bench", "run", None),
+    "table1_accuracy": ("table1_accuracy", "run", None),
+    "table1_energy": ("table1_energy", "run", None),
+    "fig4_topology": ("fig4_topology", "run", None),
+    "fig5_threshold": ("fig5_threshold", "run", None),
+    "fog_ring": ("fog_ring_bench", "run", 8),
+    "ablation_adaptive": ("ablation_adaptive", "run", None),
+    "lm_fog_exit": ("lm_fog_exit", "run", None),
+    # data-parallel serving plane across replicas
+    "serve": ("serve_bench", "run", 4),
+    # multi-tenant registry serving
+    "registry": ("registry_bench", "run", None),
+    # host vs device trainer; full mode runs the train_gate
+    "train": ("train_bench", "run", None),
+}
+
+
+def run_section(name: str, quick: bool) -> None:
+    """Run one section in THIS process and print its rows."""
+    module, fn, n_host = SECTIONS[name]
+    if n_host and "--xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={n_host}").strip()
+    from repro.launch.compile_cache import use_repo_compile_cache
+    use_repo_compile_cache()
+    import benchmarks.common as common
+    if quick:
+        common.DATASETS = ["penbased", "segmentation"]
+    section = getattr(importlib.import_module(f"benchmarks.{module}"), fn)
+    if name == "fig5_threshold":
+        rows = section(common.DATASETS)
+    elif name in ("serve", "registry", "train"):
+        rows = section(smoke=quick)
+    else:
+        rows = section()
+    for row in rows:
+        print(row, flush=True)
 
 
 def main() -> None:
@@ -18,50 +68,25 @@ def main() -> None:
                     help="subset of datasets for a fast pass")
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
+    ap.add_argument("--section", choices=sorted(SECTIONS),
+                    help=argparse.SUPPRESS)   # the per-section child
     args = ap.parse_args()
+    if args.section:
+        run_section(args.section, args.quick)
+        return
 
-    from benchmarks import (
-        ablation_adaptive, engine_bench, fig4_topology, fig5_threshold,
-        fog_ring_bench, lm_fog_exit, registry_bench, serve_bench,
-        table1_accuracy, table1_energy, train_bench,
-    )
-    import benchmarks.common as common
-
-    if args.quick:
-        common.DATASETS = ["penbased", "segmentation"]
-
-    sections = {
-        "engine": engine_bench.run,
-        "table1_accuracy": table1_accuracy.run,
-        "table1_energy": table1_energy.run,
-        "fig4_topology": fig4_topology.run,
-        "fig5_threshold": lambda: fig5_threshold.run(common.DATASETS),
-        "fog_ring": fog_ring_bench.run,
-        "ablation_adaptive": ablation_adaptive.run,
-        "lm_fog_exit": lm_fog_exit.run,
-        # subprocess: forces 4 virtual host devices, which must land
-        # before jax initializes (this parent already initialized it)
-        "serve": lambda: serve_bench.run(smoke=args.quick),
-        # subprocess for the same reason; multi-tenant registry serving
-        "registry": lambda: registry_bench.run(smoke=args.quick),
-        # host vs device trainer; full mode runs the train_gate
-        "train": lambda: train_bench.run(smoke=args.quick),
-    }
     only = set(args.only.split(",")) if args.only else None
-
     failures = []
-    for name, fn in sections.items():
+    for name in SECTIONS:
         if only and name not in only:
             continue
         print(f"\n===== {name} =====", flush=True)
         t0 = time.time()
-        try:
-            for row in fn():
-                print(row)
-        except Exception as e:  # noqa: BLE001
-            import traceback
-            traceback.print_exc()
-            failures.append((name, str(e)))
+        cmd = [sys.executable, "-m", "benchmarks.run", "--section", name]
+        if args.quick:
+            cmd.append("--quick")
+        if subprocess.run(cmd).returncode != 0:
+            failures.append(name)
         print(f"----- {name} done in {time.time() - t0:.1f}s", flush=True)
     if failures:
         print(f"\nFAILED sections: {failures}", file=sys.stderr)

@@ -620,26 +620,19 @@ def wall_summary(data: dict) -> list[str]:
 # CLI + benchmarks.run integration
 # --------------------------------------------------------------------------
 
-def run(smoke: bool = True):
-    """benchmarks.run section hook: subprocess so the forced host-device
-    count cannot collide with the parent's already-initialized jax."""
-    import subprocess
-    repo = Path(__file__).resolve().parent.parent
-    env = {
-        "PYTHONPATH": str(repo / "src"),
-        "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-        "HOME": os.environ.get("HOME", "/root"),
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-    }
-    cmd = [sys.executable, "-m", "benchmarks.serve_bench"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env, cwd=repo, capture_output=True,
-                          text=True, timeout=1800)
-    if proc.returncode != 0:
-        raise RuntimeError(f"serve_bench failed:\n{proc.stdout}\n{proc.stderr}")
-    yield from (ln for ln in proc.stdout.splitlines() if ln.strip())
+def run(smoke: bool = True, seed: int = 0, out=OUT_PATH):
+    """Bench, write ``out``, yield the report lines; raise when the gate
+    fails.  Runs in this process: ``benchmarks.run`` gives every section
+    its own process, with the host-device count this bench needs."""
+    data = bench(smoke=smoke, seed=seed)
+    Path(out).write_text(json.dumps(data, indent=1))
+    yield f"[serve_bench] wrote {out} ({len(data['rows'])} rows)"
+    for ln in wall_summary(data):
+        yield f"[serve_bench] {ln}"
+    fails = serve_gate(data)
+    if fails:
+        raise RuntimeError("[serve_gate] FAIL:\n  " + "\n  ".join(fails))
+    yield "[serve_gate] ok"
 
 
 def main() -> None:
@@ -671,16 +664,12 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_"
                                      "count=4").strip()
-    data = bench(smoke=args.smoke, seed=args.seed)
-    Path(args.out).write_text(json.dumps(data, indent=1))
-    print(f"[serve_bench] wrote {args.out} ({len(data['rows'])} rows)")
-    for ln in wall_summary(data):
-        print(f"[serve_bench] {ln}")
-    fails = serve_gate(data)
-    if fails:
-        print("[serve_gate] FAIL:\n  " + "\n  ".join(fails))
+    try:
+        for ln in run(smoke=args.smoke, seed=args.seed, out=args.out):
+            print(ln)
+    except RuntimeError as e:
+        print(e)
         sys.exit(1)
-    print("[serve_gate] ok")
 
 
 if __name__ == "__main__":

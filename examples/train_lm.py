@@ -13,7 +13,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_arch
 from repro.data.lm_data import DataConfig, batch_at_step
 from repro.launch.mesh import make_host_mesh
@@ -37,7 +36,7 @@ from repro.configs.base import param_count
 print(f"model: {cfg.name} ({param_count(cfg)[0] / 1e6:.0f}M params)")
 
 mesh = make_host_mesh()
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step_fn, *_, init_opt = make_train_step(cfg, mesh, lr=3e-4,
                                             total_steps=args.steps,
                                             donate=False)

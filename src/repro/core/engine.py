@@ -687,9 +687,9 @@ class FogEngine:
         if backend != "fused":
             return None
         from repro.kernels.fused_fog import fit_block_b, vmem_working_set
-        from repro.kernels.tree_traverse import VMEM_BUDGET
+        from repro.kernels.tree_traverse import VMEM_BUDGET, lane_block
         tables = pack.layout("fused")
-        ws = vmem_working_set(*tables, block_b=min(block_b, B),
+        ws = vmem_working_set(*tables, block_b=lane_block(block_b, B),
                               n_features=n_features)
         if ws < VMEM_BUDGET:
             return None

@@ -35,8 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.grove import GroveCollection
 from repro.forest.tree import _traverse
@@ -158,14 +157,16 @@ def make_fog_ring(mesh: Mesh, axis: str, max_hops: int, n_groves: int,
         return prob_norm, hops
 
     gspec = P(axis)  # grove tables partitioned over the ring, dim 0
-    fn = shard_map(
+    fn = jax.shard_map(
         ring, mesh=mesh,
         in_specs=(gspec, gspec, gspec, gspec, gspec,
                   P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )
-    return jax.jit(fn)
+    # results come back replicated, so the caller's un-permute gather
+    # needs no sharding annotation on any mesh (explicit axes included)
+    return jax.jit(fn, out_shardings=NamedSharding(mesh, P()))
 
 
 def reorder_tables(gc: GroveCollection, n_shards: int):

@@ -9,14 +9,20 @@ the workload's early-exit profile actually empties lanes.  Hand-picking
 one constant (the historical ``block_b=128``/``256``) therefore leaves
 latency on the table somewhere in the (precision, field size) plane.
 
-This module keeps a best-config table keyed by the pack signature:
+This module keeps a best-config table keyed by the device and the pack
+signature:
 
-    key = (precision, n_heads, n_groves, grove_size, depth, n_classes,
-           n_features)
+    key = (device_kind, precision, n_heads, n_groves, grove_size, depth,
+           n_classes, n_features)
+
+A winner is a measurement on one kind of device, so the device kind (as
+JAX reports it, ``jax.devices()[0].device_kind``) leads every key: a block
+size timed on a CPU host is never served on a TPU, nor one TPU generation's
+on another.  Cache-file entries without a device kind are ignored.
 
 ``best_config(key)`` is what the engine consults when a policy leaves
 ``block_b`` unset: a measured entry wins; otherwise the ANALYTIC SEED —
-derived from the (fixed, 8-aligned) ``fit_block_b`` VMEM model — answers
+derived from the ``fit_block_b`` VMEM model (whole 128-lane rows) — answers
 immediately, so an untuned engine never stalls to benchmark.  ``tune()``
 runs the measured sweep (halving ladder of aligned block sizes from the
 VMEM fit, x compaction on/off, best-of-k timing on representative inputs)
@@ -30,9 +36,10 @@ level-wise histogram kernel has three tile knobs (``block_n`` batch lanes,
 crossover ``matmul_max_r``: below that many (node, class) rows the Pallas
 one-hot matmul kernel wins, above it the XLA scatter path does (deep
 levels spread few samples over many nodes, where a dense one-hot wastes
-its width).  Histogram entries are keyed by the trainer signature
+its width).  Histogram entries are keyed by the device and the trainer
+signature
 
-    key = ("hist", n_trees, depth, n_features, n_bins, n_classes)
+    key = (device_kind, "hist", n_trees, depth, n_features, n_bins, n_classes)
 
 ``best_hist_config(...)`` mirrors ``best_config``: a measured/cached entry
 wins, else an analytic seed (scatter-everywhere on interpreted backends,
@@ -92,21 +99,43 @@ class HistConfig:
                 "measured_s": self.measured_s, "source": self.source}
 
 
+def device_kind() -> str:
+    """The kind of device this process evaluates on (``device_kind``)."""
+    return jax.devices()[0].device_kind
+
+
 def pack_key(pack, n_features: int) -> tuple:
-    """The (precision, field size) signature a tuned config is valid for."""
-    return (pack.precision, pack.n_heads, pack.n_groves, pack.grove_size,
-            pack.depth, pack.n_classes, int(n_features))
+    """The (device, precision, field size) signature a tuned config is
+    valid for."""
+    return (device_kind(), pack.precision, pack.n_heads, pack.n_groves,
+            pack.grove_size, pack.depth, pack.n_classes, int(n_features))
 
 
 def hist_key(n_trees: int, depth: int, n_features: int, n_bins: int,
              n_classes: int) -> tuple:
-    """The trainer signature a tuned histogram config is valid for."""
-    return ("hist", int(n_trees), int(depth), int(n_features), int(n_bins),
-            int(n_classes))
+    """The device + trainer signature a tuned histogram config is valid
+    for."""
+    return (device_kind(), "hist", int(n_trees), int(depth),
+            int(n_features), int(n_bins), int(n_classes))
 
 
 def _key_str(key: tuple) -> str:
     return "/".join(str(k) for k in key)
+
+
+def _parse_key(kstr: str) -> tuple | None:
+    """A cache-file key back to its tuple, or None for an entry that names
+    no device kind (or is otherwise malformed): such entries are ignored."""
+    from repro.forest.pack import PRECISIONS
+    parts = kstr.split("/")
+    n_ints = {"hist": 5, **{p: 6 for p in PRECISIONS}}
+    if len(parts) < 2 or parts[1] not in n_ints \
+            or len(parts) != 2 + n_ints[parts[1]]:
+        return None
+    try:
+        return (parts[0], parts[1], *(int(p) for p in parts[2:]))
+    except ValueError:
+        return None
 
 
 def analytic_block_b(pack, n_features: int) -> int:
@@ -345,11 +374,10 @@ def _load_cache_file() -> None:
     except (OSError, ValueError):
         return
     for kstr, cfg in raw.items():
-        key = tuple(p if i == 0 else int(p)
-                    for i, p in enumerate(kstr.split("/")))
-        if key in _CACHE:       # fresher in-process measurements win
+        key = _parse_key(kstr)
+        if key is None or key in _CACHE:  # no device kind / fresher in-process
             continue
-        if key[0] == "hist":
+        if key[1] == "hist":
             _CACHE[key] = HistConfig(block_n=int(cfg["block_n"]),
                                      block_r=int(cfg["block_r"]),
                                      block_f=int(cfg["block_f"]),

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.tree_traverse import resolve_interpret
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, blk_q: int,
                   blk_k: int, seq_k: int, causal: bool):
@@ -40,12 +42,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, blk_q: int,
 
     def body(jk, carry):
         acc, m, l = carry
-        k = pl.load(k_ref,
-                    (pl.dslice(0, 1), pl.dslice(jk * blk_k, blk_k),
-                     slice(None)))[0]
-        v = pl.load(v_ref,
-                    (pl.dslice(0, 1), pl.dslice(jk * blk_k, blk_k),
-                     slice(None)))[0]
+        k = k_ref[0, pl.ds(jk * blk_k, blk_k), :]
+        v = v_ref[0, pl.ds(jk * blk_k, blk_k), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [blk_q, blk_k]
@@ -77,8 +75,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, blk_q: int,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, causal: bool = True, blk_q: int = 512,
                            blk_k: int = 512, scale: float | None = None,
-                           interpret: bool = True) -> jax.Array:
-    """q [B,Sq,H,D], k/v [B,Sk,K,Dkv] -> [B,Sq,H,Dv].  H % K == 0."""
+                           interpret: bool | None = None) -> jax.Array:
+    """q [B,Sq,H,D], k/v [B,Sk,K,Dkv] -> [B,Sq,H,Dv].  H % K == 0.
+    ``interpret=None`` derives from the runtime (``resolve_interpret``)."""
     B, Sq, H, D = q.shape
     Sk, K = k.shape[1], k.shape[2]
     Dv = v.shape[3]
@@ -111,7 +110,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, blk_q, Dv), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * K * G, Sq, Dv), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
 
     out = out.reshape(B, K, G, Sq, Dv).transpose(0, 3, 1, 2, 4)

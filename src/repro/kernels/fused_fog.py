@@ -14,31 +14,30 @@ mask, per-lane ``[B]`` threshold and hop budget, rotation start
 loop exits as soon as every lane in the block is confident (or budgeted
 out), so an easy block touches VMEM tables for one hop and stops.
 
-Tables arrive packed (``forest.pack.ForestPack`` dtypes): fp32, bf16 or
-per-tree-scaled int8 with fp32 scales.  The resident tables and every load
-from them stay at the packed width — int8 pins ~4x the field of groves in
-the same VMEM — and only the *gathered* [BB, t] values are dequantized to
-fp32 for the compare/accumulate, mirroring the ASIC's fixed-point SRAM.
+The walk is ``tree_traverse.walk_tree``: batch on lanes, every table read a
+one-hot select (see that module for the layouts and why they are exact).
+Each lane picks its own grove per hop, so node columns are chosen by a
+select chain over the grove masks and leaves by a one-hot over
+``(grove, leaf)``.  The gate is ``top2_confidence.maxdiff_lanes``.
 
 Live-lane compaction (``compact=True``): after each hop the block's live
-lanes are permuted to a contiguous prefix (a stable cumsum-ranked
-partition — per-lane state just relocates, so hops/labels are bit-identical
-to the uncompacted walk), and the next hop's gather-compare walk runs over
-the smallest power-of-two prefix that covers the survivors instead of the
-full block.  Exited lanes therefore stop occupying walk lanes: at a high
-threshold most lanes exit on hop 1 and every later hop touches a fraction
-of the block's VMEM lane state — the same sparsity win the reference-lazy
-path shows at batch granularity (22.3 -> 11.9 ms), recovered inside the
-kernel.  The engine's autotuner measures compaction on/off per (precision,
-field size) and serves the faster setting.
+lanes are moved to a contiguous prefix (a stable partition — per-lane
+state just relocates, so hops/labels are bit-identical to the uncompacted
+walk), and the next hop walks the smallest power-of-two prefix (down to
+one 128-lane row) that covers the survivors instead of the full block.
+The partition ranks come from one MXU product with a triangular matrix,
+and the relocation is a one-hot permutation matmul applied to each 32-bit
+word's four bytes (each byte is exact in bf16), so any payload — ±inf and
+NaN inputs included — moves bit for bit.  The engine's autotuner measures
+compaction on/off per (precision, field size) and serves the faster
+setting.
 
-Block sizing (mirrors tree_traverse.py): BB lanes x t trees x d levels of
-int32 index state is small; the resident tables dominate VMEM at their
-packed byte size — the whole field of groves, not one grove, must fit.
-The wrapper rejects working sets over the ~16 MB v5e VMEM budget with a
-ValueError reporting required vs available bytes and the two remedies
-(``chunk_b=...`` batch slices, ``precision="int8"`` tables); the engine's
-``chunk_b="auto"`` applies the first remedy automatically.
+Block sizing: batch blocks are whole 128-lane rows.  The resident tables
+dominate VMEM in their padded kernel layout — the whole field of groves,
+not one grove, must fit.  The wrapper rejects working sets over
+``VMEM_BUDGET`` with a ValueError reporting required vs available bytes and
+the two remedies (``chunk_b=...`` batch slices, ``precision="int8"``
+tables); the engine's ``chunk_b="auto"`` applies the first remedy.
 
 Batches need not align: the batch is dead-lane padded to the block boundary
 (padded lanes enter with live=0, so they never walk, never count hops, and
@@ -52,129 +51,159 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tree_traverse import (VMEM_BUDGET, _dequant_gathered,
-                                         resolve_interpret, vmem_error)
+from repro.kernels.top2_confidence import maxdiff_lanes
+from repro.kernels.tree_traverse import (
+    LANE, SELECT_ROWS, VMEM_BUDGET, compiler_params, feature_rows,
+    lane_block, leaf_rows, leaf_table_bytes, node_columns, node_table_bytes,
+    resolve_interpret, round_up, vmem_error, walk_lane_bytes, walk_tree,
+    x_columns)
 
-# TPU lane tiling: batch blocks are sized in multiples of this so a block's
-# [BB, t] walk state maps onto whole sublanes (fit_block_b rounds down to it)
-LANE_ALIGN = 8
+# batch blocks are sized in multiples of this (one vreg row of lanes);
+# fit_block_b rounds down to it
+LANE_ALIGN = LANE
 
-# smallest compacted walk prefix: shrinking below one aligned sublane group
-# buys nothing (the VPU processes whole sublanes either way)
-MIN_COMPACT_WIDTH = 8
+# smallest compacted walk prefix: one vreg row of lanes
+MIN_COMPACT_WIDTH = LANE
+
+# per-lane [1, w] rows the kernel carries: live, hops, start, budget,
+# compaction origin, threshold
+_LANE_ROWS = 6
+
+
+def _field_dims(feature, leaf):
+    O, G, t, _ = feature.shape
+    L, C = leaf.shape[3], leaf.shape[4]
+    return O, G, t, int(np.log2(L) + 0.5), C
 
 
 def vmem_table_bytes(feature, threshold, leaf, thr_scale, leaf_scale) -> int:
-    """Bytes of packed grove tables the kernel pins whole in VMEM."""
-    return int(feature.nbytes + threshold.nbytes + leaf.nbytes
-               + thr_scale.nbytes + leaf_scale.nbytes)
+    """VMEM bytes of the tables in the kernel's layout: the fp32 node
+    columns and the packed leaf rows, padded to whole VMEM tiles (the
+    per-tree scales live in SMEM)."""
+    O, G, t, depth, C = _field_dims(feature, leaf)
+    return (node_table_bytes(O * t * G, feature.shape[3])
+            + leaf_table_bytes(O * t, C, G << depth, leaf.dtype.itemsize))
 
 
-def vmem_lane_bytes(*, n_heads: int, n_classes: int, grove_size: int,
-                    depth: int, n_features: int) -> int:
-    """Per-lane VMEM state, byte-exact per dtype: the fp32 input row, the
-    [O, C] prob accumulators (x2 for the normalized copy), the [t] x
-    (depth + 2) int32 walk/gather state, five 4-byte per-lane scalars
-    (start, threshold, hop budget, hop count, compaction origin index) —
-    and the live mask at its actual int8 width, ONE byte, not four."""
-    words = (n_features                    # x row, fp32
-             + 2 * n_heads * n_classes    # prob + normalized copy, fp32
-             + grove_size * (depth + 2)   # walk idx + gathered f/thr, int32
-             + 5)                         # start/thresh/budget/hops/orig
-    return 4 * words + 1                  # + int8 live mask
+def vmem_lane_bytes(*, n_heads: int, n_classes: int, n_features: int,
+                    block_b: int, compact: bool = True) -> int:
+    """Per-lane VMEM: double-buffered input/output blocks (``x^T`` column,
+    [O, C] probabilities, the per-lane rows each padded to an 8-row tile),
+    the working copy of ``x^T``, the loop-carried state, one walk's
+    temporaries and — with compaction — the [block_b, block_b] bf16
+    permutation and prefix-count matrices."""
+    xf = feature_rows(n_features)
+    oc = n_heads * round_up(n_classes, 8)
+    blocks = 2 * 4 * (xf + oc + 5 * 8)
+    carry = 4 * (xf + 2 * oc + _LANE_ROWS * 8)
+    walk = walk_lane_bytes(n_classes=n_classes)
+    perm = 2 * 2 * block_b if compact else 0
+    return blocks + carry + walk + perm
 
 
 def vmem_working_set(feature, threshold, leaf, thr_scale, leaf_scale, *,
-                     block_b: int, n_features: int) -> int:
-    """Bytes resident in VMEM: every packed table + one batch block's state."""
-    O, _, t, _ = feature.shape
-    C = leaf.shape[4]
-    depth = int(np.log2(leaf.shape[3]) + 0.5)
+                     block_b: int, n_features: int,
+                     compact: bool = True) -> int:
+    """Bytes resident in VMEM: every table + one batch block's state."""
+    O, _, _, _, C = _field_dims(feature, leaf)
     tables = vmem_table_bytes(feature, threshold, leaf, thr_scale, leaf_scale)
-    block = block_b * vmem_lane_bytes(n_heads=O, n_classes=C, grove_size=t,
-                                      depth=depth, n_features=n_features)
-    return tables + block
+    return tables + block_b * vmem_lane_bytes(
+        n_heads=O, n_classes=C, n_features=n_features, block_b=block_b,
+        compact=compact)
 
 
 def fit_block_b(feature, threshold, leaf, thr_scale, leaf_scale, *,
                 n_features: int) -> int:
-    """Largest LANE_ALIGN-aligned batch block that fits VMEM beside the
-    packed tables (0 when the tables alone are over budget).  The raw
-    lane-count quotient is rounded DOWN to a multiple of 8 — an unaligned
-    block (say 731) defeats TPU sublane tiling and pads up inside Mosaic,
-    silently overshooting the modeled footprint.  A sliver of headroom
-    below one aligned group (0 < fit < 8) is returned unrounded so the
-    evaluation still runs rather than refusing.  ``FogEngine``'s
+    """Largest LANE_ALIGN-multiple batch block whose working set (with
+    compaction, the larger footprint) stays under the VMEM budget beside
+    the tables — 0 when not even one 128-lane row fits.  ``FogEngine``'s
     auto-chunking and the autotuner's analytic seed size from this."""
-    O, _, t, _ = feature.shape
-    C = leaf.shape[4]
-    depth = int(np.log2(leaf.shape[3]) + 0.5)
-    tables = vmem_table_bytes(feature, threshold, leaf, thr_scale, leaf_scale)
-    lane = vmem_lane_bytes(n_heads=O, n_classes=C, grove_size=t, depth=depth,
-                           n_features=n_features)
-    fit = max(0, (VMEM_BUDGET - 1 - tables) // lane)
-    return fit - fit % LANE_ALIGN if fit >= LANE_ALIGN else fit
+    fit = 0
+    b = LANE_ALIGN
+    while vmem_working_set(feature, threshold, leaf, thr_scale, leaf_scale,
+                           block_b=b, n_features=n_features) < VMEM_BUDGET:
+        fit = b
+        b += LANE_ALIGN
+    return fit
 
 
-def _compact_perm(live):
-    """Gather permutation moving live lanes to a contiguous prefix.
-
-    Stable on both sides (cumsum ranks preserve relative order), so the
-    permutation is a pure relocation of per-lane state: every lane keeps
-    its own values and the walk/gate math is bit-identical.  Returns
-    ``perm`` with ``new[i] = old[perm[i]]``.
-    """
-    BB = live.shape[0]
-    livei = live.astype(jnp.int32)
-    n_live = jnp.sum(livei)
-    rank_live = jnp.cumsum(livei) - 1
-    rank_dead = jnp.cumsum(1 - livei) - 1
-    pos = jnp.where(livei > 0, rank_live, n_live + rank_dead)   # old -> new
-    iota = jax.lax.iota(jnp.int32, BB)
-    return jnp.zeros((BB,), jnp.int32).at[pos].set(iota)
+def _bytes_of(a):
+    bits = a if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(
+        a, jnp.int32)
+    return [((bits >> s) & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+            for s in (0, 8, 16, 24)]
 
 
-def _fused_fog_kernel(feature_ref, threshold_ref, leaf_ref, thr_scale_ref,
-                      leaf_scale_ref, x_ref, start_ref, thresh_ref,
-                      budget_ref, live_ref, proba_out, hops_out,
-                      *, depth: int, max_hops: int, n_groves: int,
-                      compact: bool):
-    x0 = x_ref[...]                      # [BB, F]
-    start0 = start_ref[...]              # [BB]
-    thresh0 = thresh_ref[...]            # [BB] per-lane gate
-    budget0 = budget_ref[...]            # [BB] per-lane hop cap
-    live0 = live_ref[...]                # [BB] int8 (0 = dead-padded lane)
-    feature = feature_ref[...]           # [O, G, t, nodes]
-    threshold = threshold_ref[...]       # packed dtype
-    leaf = leaf_ref[...]                 # [O, G, t, L, C] packed dtype
-    thr_scale = thr_scale_ref[...]       # [O, G, t, 1] fp32
-    leaf_scale = leaf_scale_ref[...]     # [O, G, t, 1, 1]
-    O = feature.shape[0]
-    t = feature.shape[2]
-    L, C = leaf.shape[3], leaf.shape[4]
-    BB = x0.shape[0]
+def _perm_matrix(dest):
+    """``pt[i, k] = (dest[k] == i)``: the one-hot matrix that moves lane k
+    of a ``[R, w]`` array to lane ``dest[k]``."""
+    w = dest.shape[1]
+    return (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+            == dest).astype(jnp.bfloat16)
 
-    def walk(o, g, xs):
-        # per-lane grove walk against head o's VMEM-resident tables: the
-        # same d gather-compare levels as tree_traverse, but the grove is
-        # selected per lane (g) and the lane width follows the compacted
-        # prefix instead of being fixed at BB
-        w = xs.shape[0]
-        trange = jax.lax.broadcasted_iota(jnp.int32, (w, t), 1)
-        gcol = g[:, None]
-        ts = thr_scale[o][gcol, trange, 0]                 # [w, t]
-        idx = jnp.zeros((w, t), jnp.int32)
-        for _ in range(depth):           # static unroll
-            f = feature[o][gcol, trange, idx]              # [w, t]
-            thr = _dequant_gathered(threshold[o][gcol, trange, idx], ts,
-                                    sentinel=True)
-            xv = jnp.take_along_axis(xs, f, axis=1)        # [w, t]
-            idx = 2 * idx + 1 + (xv > thr).astype(jnp.int32)
-        dists = _dequant_gathered(
-            leaf[o][gcol, trange, idx - (L - 1)],          # [w, t, C]
-            leaf_scale[o][gcol, trange, 0, 0][..., None])
-        return dists.mean(axis=1)
+
+def _relocate(arrays, pt):
+    """Move every ``[R, w]`` 32-bit array's lanes by ``pt``.
+
+    One one-hot permutation matmul per byte of each word: a byte is exact
+    in bf16 and lands alone in its output cell, so the words reassemble
+    bit for bit whatever they hold."""
+    out = []
+    for a in arrays:
+        word = None
+        for s, byte in zip((0, 8, 16, 24), _bytes_of(a)):
+            moved = jax.lax.dot_general(
+                byte, pt, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(jnp.int32) << s
+            word = moved if word is None else word | moved
+        out.append(word if a.dtype == jnp.int32
+                   else jax.lax.bitcast_convert_type(word, a.dtype))
+    return out
+
+
+def _compact_dest(live):
+    """Stable partition of ``live [1, w]`` (int32 0/1): live lanes to the
+    front in order, dead lanes after them in order.  Returns each lane's
+    destination; the live-before counts come from one exact bf16 matmul
+    with a strictly-lower-triangular ones matrix (counts < 2**24)."""
+    w = live.shape[1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (w, w), 0)
+           < jax.lax.broadcasted_iota(jnp.int32, (w, w), 1))
+    before = jnp.dot(live.astype(jnp.float32).astype(jnp.bfloat16),
+                     tri.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    n_live = jnp.sum(live)
+    return jnp.where(live > 0, before, n_live + lane - before)
+
+
+def _fused_fog_kernel(tab_ref, leaf_ref, scale_ref, x_ref, start_ref,
+                      thresh_ref, budget_ref, live_ref, proba_out, hops_out,
+                      xs_ref, *, depth: int, max_hops: int, n_groves: int,
+                      n_heads: int, grove_size: int, n_features: int,
+                      compact: bool, quant: bool):
+    O, t, G = n_heads, grove_size, n_groves
+    K = O * t * G
+    C = leaf_ref.shape[1]
+    BB = x_ref.shape[1]
+    xs_ref[...] = x_ref[...]             # [F, BB] working copy, batch on lanes
+
+    def contrib(g, w):
+        # each head's grove mean over its t trees, for the lanes' groves g
+        gmasks = [g == gi for gi in range(G)]
+        out = []
+        for o in range(O):
+            total = None
+            for j in range(t):
+                d = walk_tree(tab_ref, leaf_ref, scale_ref, xs_ref, w, gmasks,
+                              tree=o * t + j, col=(o * t + j) * G, n_cols=K,
+                              depth=depth, n_features=n_features,
+                              quant=quant)
+                total = d if total is None else total + d
+            out.append(total / t)
+        return tuple(out)
 
     # compacted walk prefix widths: BB, BB/2, ... down to MIN_COMPACT_WIDTH.
     # Only one branch executes per hop (lax.switch); survivors always sit in
@@ -184,79 +213,82 @@ def _fused_fog_kernel(feature_ref, threshold_ref, leaf_ref, thr_scale_ref,
         while widths[-1] % 2 == 0 and widths[-1] // 2 >= MIN_COMPACT_WIDTH:
             widths.append(widths[-1] // 2)
 
-    def walk_all(g, xs, n_live):
+    def contrib_all(g, n_live):
         if len(widths) == 1:
-            return jnp.stack([walk(o, g, xs) for o in range(O)])
+            return contrib(g, BB)
 
         def prefix_branch(w):
-            def run(_):
-                out = jnp.stack([walk(o, g[:w], xs[:w]) for o in range(O)])
-                # lanes beyond the prefix are dead (livef = 0 masks them);
-                # pad with zeros to keep the [O, BB, C] accumulate shape
-                return jnp.pad(out, ((0, 0), (0, BB - w), (0, 0)))
+            def run(g):
+                out = contrib(g[:, :w], w)
+                if w == BB:
+                    return out
+                # lanes beyond the prefix are dead (livef = 0 masks them)
+                pad = jnp.zeros((C, BB - w), jnp.float32)
+                return tuple(jnp.concatenate([d, pad], axis=1) for d in out)
             return run
 
-        # halving level: how many times the prefix can shrink and still
-        # cover every live lane
         lvl = jnp.zeros((), jnp.int32)
         for w in widths[1:]:
             lvl = lvl + (n_live <= w).astype(jnp.int32)
-        return jax.lax.switch(lvl, [prefix_branch(w) for w in widths], None)
+        return jax.lax.switch(lvl, [prefix_branch(w) for w in widths], g)
+
+    def do_compact(ops):
+        probs, live, hops, start, budget, orig, thresh = ops
+        pt = _perm_matrix(_compact_dest(live))
+
+        def move_x(k, carry):
+            r0 = pl.multiple_of(k * SELECT_ROWS, SELECT_ROWS)
+            rows = xs_ref[pl.ds(r0, SELECT_ROWS), :]
+            xs_ref[pl.ds(r0, SELECT_ROWS), :] = _relocate([rows], pt)[0]
+            return carry
+
+        jax.lax.fori_loop(0, xs_ref.shape[0] // SELECT_ROWS, move_x, 0)
+        rows = jnp.concatenate(
+            [live, hops, start, budget, orig,
+             jax.lax.bitcast_convert_type(thresh, jnp.int32)], axis=0)
+        moved = _relocate([*probs, rows], pt)
+        rows = moved[-1]
+        return (tuple(moved[:O]), rows[0:1], rows[1:2], rows[2:3],
+                rows[3:4], rows[4:5],
+                jax.lax.bitcast_convert_type(rows[5:6], jnp.float32))
 
     def body(state):
-        j, prob, live, hops, x, start, thresh, budget, orig = state
-        n_live = jnp.sum(live.astype(jnp.int32))
+        j, probs, live, hops, start, budget, orig, thresh = state
+        n_live = jnp.sum(live)
         if compact:
-            def do_compact(args):
-                prob, live, hops, x, start, thresh, budget, orig = args
-                perm = _compact_perm(live)
-                take = lambda a: jnp.take(a, perm, axis=0)
-                return (jnp.take(prob, perm, axis=1), take(live), take(hops),
-                        take(x), take(start), take(thresh), take(budget),
-                        take(orig))
-
-            # hop 1 (and any fully-live block) skips the permutation
-            prob, live, hops, x, start, thresh, budget, orig = jax.lax.cond(
-                n_live < BB, do_compact, lambda args: args,
-                (prob, live, hops, x, start, thresh, budget, orig))
-        g = (start + j) % n_groves
+            # hop 1 of a full block (and any fully-live block) skips it
+            probs, live, hops, start, budget, orig, thresh = jax.lax.cond(
+                n_live < BB, do_compact, lambda ops: ops,
+                (probs, live, hops, start, budget, orig, thresh))
+        g = (start + j) % G
         livef = live.astype(jnp.float32)
-        prob = prob + walk_all(g, x, n_live) * livef[None, :, None]
-        hops = hops + live.astype(jnp.int32)
-        denom = jnp.maximum(hops, 1).astype(jnp.float32)
-        prob_norm = prob / denom[None, :, None]
-        # MaxDiff with first-max masking (identical to grove_aggregate)
-        m1 = jnp.max(prob_norm, axis=-1)                   # [O, BB]
-        is_max = prob_norm == m1[..., None]
-        first = jnp.cumsum(is_max.astype(jnp.int32), axis=-1) == 1
-        m2 = jnp.max(jnp.where(is_max & first, -jnp.inf, prob_norm), axis=-1)
-        # min-over-outputs rule: live until EVERY head clears the gate
-        margin = jnp.abs(m1 - m2).min(axis=0)              # [BB]
-        live = (live.astype(bool) & (margin < thresh)
-                & (hops < budget)).astype(jnp.int8)
-        return j + 1, prob, live, hops, x, start, thresh, budget, orig
+        probs = tuple(p + d * livef
+                      for p, d in zip(probs, contrib_all(g, n_live)))
+        hops = hops + live
+        margin = None
+        for p in probs:                  # min-over-outputs rule
+            m = maxdiff_lanes(p / jnp.maximum(hops, 1).astype(jnp.float32))
+            margin = m if margin is None else jnp.minimum(margin, m)
+        live = ((live > 0) & (margin < thresh)
+                & (hops < budget)).astype(jnp.int32)
+        return j + 1, probs, live, hops, start, budget, orig, thresh
 
     def cond(state):
-        j, _, live = state[0], state[1], state[2]
-        return (j < max_hops) & (jnp.sum(live.astype(jnp.int32)) > 0)
+        return (state[0] < max_hops) & (jnp.sum(state[2]) > 0)
 
     state0 = (jnp.zeros((), jnp.int32),
-              jnp.zeros((O, BB, C), jnp.float32),
-              live0,
-              jnp.zeros((BB,), jnp.int32),
-              x0, start0, thresh0, budget0,
-              jax.lax.iota(jnp.int32, BB))
-    _, prob, _, hops, _, _, _, _, orig = jax.lax.while_loop(cond, body, state0)
-    denom = jnp.maximum(hops, 1).astype(jnp.float32)
-    proba = (prob / denom[None, :, None]).transpose(1, 0, 2)   # [BB, O, C]
+              tuple(jnp.zeros((C, BB), jnp.float32) for _ in range(O)),
+              live_ref[...], jnp.zeros((1, BB), jnp.int32), start_ref[...],
+              budget_ref[...], jax.lax.broadcasted_iota(jnp.int32, (1, BB), 1),
+              thresh_ref[...])
+    _, probs, _, hops, _, _, orig, _ = jax.lax.while_loop(cond, body, state0)
     if compact:
-        # undo the accumulated compaction permutation: lane orig[i] of the
-        # input lives at row i, so scatter row i back to slot orig[i]
-        inv = jnp.zeros((BB,), jnp.int32).at[orig].set(
-            jax.lax.iota(jnp.int32, BB))
-        proba = jnp.take(proba, inv, axis=0)
-        hops = jnp.take(hops, inv, axis=0)
-    proba_out[...] = proba
+        # undo the accumulated compaction: row i holds input lane orig[i]
+        moved = _relocate([*probs, hops], _perm_matrix(orig))
+        probs, hops = moved[:O], moved[O]
+    denom = jnp.maximum(hops, 1).astype(jnp.float32)
+    for o in range(O):
+        proba_out[o] = probs[o] / denom
     hops_out[...] = hops
 
 
@@ -277,17 +309,17 @@ def fused_fog_pallas(feature: jax.Array, threshold: jax.Array,
     leaf_scale float32         [O, G, t, 1, 1]   (default ones)
     x          float32 [B, F];  start int32 [B];  thresh float32 [B];
     budget     int32   [B]
-    compact    permute live lanes to a prefix each hop and walk only the
+    block_b    batch lanes per grid block, rounded up to whole 128-lane rows
+    compact    move live lanes to a prefix each hop and walk only the
                covering power-of-two prefix (bit-identical results)
     interpret  None derives from ``jax.default_backend()`` (compiled on a
                real TPU, interpreted elsewhere); a bool overrides
     returns    (proba float32 [B, O, C] hop-normalized, hops int32 [B])
     """
     B, F = x.shape
-    O, G, t, _ = feature.shape
-    L, C = leaf.shape[3], leaf.shape[4]
-    depth = int(np.log2(L) + 0.5)
-    block_b = min(block_b, B)
+    O, G, t, depth, C = _field_dims(feature, leaf)
+    K = O * t * G
+    block_b = lane_block(block_b, B)
     interpret = resolve_interpret(interpret)
     if thr_scale is None:
         thr_scale = jnp.ones((O, G, t, 1), jnp.float32)
@@ -295,7 +327,7 @@ def fused_fog_pallas(feature: jax.Array, threshold: jax.Array,
         leaf_scale = jnp.ones((O, G, t, 1, 1), jnp.float32)
 
     ws = vmem_working_set(feature, threshold, leaf, thr_scale, leaf_scale,
-                          block_b=block_b, n_features=F)
+                          block_b=block_b, n_features=F, compact=compact)
     if ws >= VMEM_BUDGET:
         tables = vmem_table_bytes(feature, threshold, leaf, thr_scale,
                                   leaf_scale)
@@ -308,47 +340,42 @@ def fused_fog_pallas(feature: jax.Array, threshold: jax.Array,
             f"{fit_block_b(feature, threshold, leaf, thr_scale, leaf_scale, n_features=F)}",
             chunkable=True)
 
-    pad = (-B) % block_b
-    live8 = jnp.ones((B,), jnp.int8)
-    if pad:  # dead-lane pad: padded lanes enter dead and are sliced off
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        start = jnp.pad(start, (0, pad))
-        thresh = jnp.pad(thresh, (0, pad))
-        budget = jnp.pad(budget, (0, pad), constant_values=1)
-        live8 = jnp.pad(live8, (0, pad))
-        B = B + pad
+    # kernel layouts: node columns / scales ordered (head, tree, grove),
+    # leaf rows per (head, tree) with lanes (grove, leaf)
+    def per_tree(a):
+        return jnp.swapaxes(a, 1, 2)                 # [O, t, G, ...]
 
-    whole4 = lambda i: (0, 0, 0, 0)
-    whole5 = lambda i: (0, 0, 0, 0, 0)
-    row = lambda i: (i, 0)
-    vec = lambda i: (i,)
+    tab = node_columns(per_tree(feature).reshape(K, -1),
+                       per_tree(threshold).reshape(K, -1))
+    leaves = leaf_rows(per_tree(leaf).reshape(O * t, G, 1 << depth, C))
+    scales = jnp.stack([per_tree(thr_scale).reshape(K),
+                        per_tree(leaf_scale).reshape(K)]).astype(jnp.float32)
+
+    Bp = round_up(B, block_b)
+    pad = Bp - B                   # padded lanes enter dead and are sliced off
+    row = lambda v, dt, fill=0: jnp.pad(
+        jnp.asarray(v, dt), (0, pad), constant_values=fill).reshape(1, Bp)
+    live = row(jnp.ones((B,), jnp.int32), jnp.int32)
+
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)     # whole, single copy
+    lanes = pl.BlockSpec((1, block_b), lambda i: (0, i))
     proba, hops = pl.pallas_call(
         functools.partial(_fused_fog_kernel, depth=depth, max_hops=max_hops,
-                          n_groves=G, compact=compact),
-        grid=(B // block_b,),
-        in_specs=[
-            pl.BlockSpec(feature.shape, whole4),    # tables: whole, VMEM-pinned
-            pl.BlockSpec(threshold.shape, whole4),
-            pl.BlockSpec(leaf.shape, whole5),
-            pl.BlockSpec(thr_scale.shape, whole4),
-            pl.BlockSpec(leaf_scale.shape, whole5),
-            pl.BlockSpec((block_b, F), row),        # batch: tiled
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, O, C), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_b,), vec),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, O, C), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
+                          n_groves=G, n_heads=O, grove_size=t,
+                          n_features=F, compact=compact,
+                          quant=threshold.dtype == jnp.int8),
+        grid=(Bp // block_b,),
+        in_specs=[vmem, vmem, pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((feature_rows(F), block_b),
+                               lambda i: (0, i)),
+                  lanes, lanes, lanes, lanes],
+        out_specs=[pl.BlockSpec((O, C, block_b), lambda i: (0, 0, i)),
+                   lanes],
+        out_shape=[jax.ShapeDtypeStruct((O, C, Bp), jnp.float32),
+                   jax.ShapeDtypeStruct((1, Bp), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((feature_rows(F), block_b), jnp.float32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(feature, threshold, leaf, thr_scale, leaf_scale, x, start, thresh,
-      budget, live8)
-    if pad:
-        proba, hops = proba[:-pad], hops[:-pad]
-    return proba, hops
+    )(tab, leaves, scales, x_columns(x, Bp), row(start, jnp.int32),
+      row(thresh, jnp.float32), row(budget, jnp.int32, 1), live)
+    return jnp.transpose(proba, (2, 0, 1))[:B], hops[0, :B]

@@ -10,6 +10,10 @@ broadcast to ``[B]`` before the call, so mixed-QoS batches (every lane with
 its own accuracy/energy trade-off, ``FogPolicy.threshold`` as a vector) run
 the same kernel at identical cost.
 
+The batch rides the lane axis: probabilities arrive as ``[C, B]`` blocks of
+whole 128-lane rows, per-lane state as ``[1, B]`` rows (the TPU compiler
+tiles no rank-1 int8 block), and the margin is ``maxdiff_lanes``.
+
 Precision contract: grove tables are packed (fp32/bf16/int8 — see
 ``forest.pack.ForestPack``) and the per-hop grove walk dequantizes its
 *contribution* rows to fp32 before this kernel sees them, so the
@@ -23,32 +27,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tree_traverse import resolve_interpret
+from repro.kernels.top2_confidence import maxdiff_lanes
+from repro.kernels.tree_traverse import (compiler_params, lane_block,
+                                         resolve_interpret, round_up)
 
 
 def _aggregate_kernel(prob_ref, contrib_ref, live_ref, hops_ref, thresh_ref,
                       prob_out, hops_out, live_out, margin_out):
-    prob = prob_ref[...]           # [BB, C]
-    contrib = contrib_ref[...]     # [BB, C]
-    live = live_ref[...]           # [BB] (int8 mask: pallas bools are awkward)
-    hops = hops_ref[...]           # [BB]
-    thresh = thresh_ref[...]       # [BB] per-lane gate
+    prob = prob_ref[...]           # [C, BB] batch on lanes
+    contrib = contrib_ref[...]     # [C, BB]
+    live = live_ref[...]           # [1, BB] int32 0/1
+    hops = hops_ref[...]           # [1, BB]
+    thresh = thresh_ref[...]       # [1, BB] per-lane gate
 
-    livef = live.astype(prob.dtype)
-    prob = prob + contrib * livef[:, None]
-    hops = hops + live.astype(jnp.int32)
-    denom = jnp.maximum(hops, 1).astype(prob.dtype)
-    prob_norm = prob / denom[:, None]
-
-    m1 = jnp.max(prob_norm, axis=-1)
-    is_max = prob_norm == m1[:, None]
-    first = jnp.cumsum(is_max.astype(jnp.int32), axis=-1) == 1
-    m2 = jnp.max(jnp.where(is_max & first, -jnp.inf, prob_norm), axis=-1)
-    margin = jnp.abs(m1 - m2)
+    prob = prob + contrib * live.astype(prob.dtype)
+    hops = hops + live
+    margin = maxdiff_lanes(prob / jnp.maximum(hops, 1).astype(prob.dtype))
 
     prob_out[...] = prob
     hops_out[...] = hops
-    live_out[...] = (live.astype(bool) & (margin < thresh)).astype(jnp.int8)
+    live_out[...] = ((live > 0) & (margin < thresh)).astype(jnp.int32)
     margin_out[...] = margin
 
 
@@ -70,44 +68,29 @@ def grove_aggregate_pallas(prob_acc: jax.Array, contrib: jax.Array,
             f"packed contributions before the hop update (got "
             f"{contrib.dtype})")
     B, C = prob_acc.shape
-    block_b = min(block_b, B)
-    pad = (-B) % block_b
-    thresh = jnp.broadcast_to(jnp.asarray(thresh, prob_acc.dtype), (B,))
-    live8 = live.astype(jnp.int8)
-    if pad:
-        prob_acc = jnp.pad(prob_acc, ((0, pad), (0, 0)))
-        contrib = jnp.pad(contrib, ((0, pad), (0, 0)))
-        live8 = jnp.pad(live8, (0, pad))
-        hops = jnp.pad(hops, (0, pad))
-        thresh = jnp.pad(thresh, (0, pad))
-        B = B + pad
-    row = lambda i: (i, 0)
-    vec = lambda i: (i,)
-    prob, hops, live8, margin = pl.pallas_call(
+    block_b = lane_block(block_b, B)
+    Bp = round_up(B, block_b)
+    pad = Bp - B
+    dt = prob_acc.dtype
+    cols = lambda a: jnp.pad(a, ((0, pad), (0, 0))).T            # [C, Bp]
+    row = lambda v, d: jnp.pad(jnp.broadcast_to(jnp.asarray(v, d), (B,)),
+                               (0, pad)).reshape(1, Bp)
+    mat = pl.BlockSpec((C, block_b), lambda i: (0, i))
+    lanes = pl.BlockSpec((1, block_b), lambda i: (0, i))
+    prob, hops, live, margin = pl.pallas_call(
         _aggregate_kernel,
-        grid=(B // block_b,),
-        in_specs=[
-            pl.BlockSpec((block_b, C), row),
-            pl.BlockSpec((block_b, C), row),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, C), row),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-            pl.BlockSpec((block_b,), vec),
-        ],
+        grid=(Bp // block_b,),
+        in_specs=[mat, mat, lanes, lanes, lanes],
+        out_specs=[mat, lanes, lanes, lanes],
         out_shape=[
-            jax.ShapeDtypeStruct((B, C), prob_acc.dtype),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int8),
-            jax.ShapeDtypeStruct((B,), prob_acc.dtype),
+            jax.ShapeDtypeStruct((C, Bp), dt),
+            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((1, Bp), dt),
         ],
+        compiler_params=compiler_params(),
         interpret=resolve_interpret(interpret),
-    )(prob_acc, contrib, live8, hops, thresh)
-    if pad:
-        prob, hops, live8, margin = (prob[:-pad], hops[:-pad], live8[:-pad],
-                                     margin[:-pad])
-    return prob, hops, live8.astype(bool), margin
+    )(cols(prob_acc), cols(contrib.astype(dt)), row(live, jnp.int32),
+      row(hops, jnp.int32), row(thresh, dt))
+    return (prob[:, :B].T, hops[0, :B], live[0, :B].astype(bool),
+            margin[0, :B])

@@ -14,7 +14,12 @@ cumsum.  This module builds that tensor two ways behind one dispatcher:
     rows one-hot in (node, class) weighted by the bootstrap multiplicity,
     columns one-hot in (feature, bin), accumulated with one MXU matmul per
     tile.  The tree axis rides the leading grid dimension (equivalently a
-    vmap: it would add the same leading grid dim).
+    vmap: it would add the same leading grid dim).  Per-sample rows arrive
+    as ``[1, block_n]`` lane rows of ``[T, 1, N]`` arrays, and the flat
+    (feature, bin) column axis is tiled in whole 128-lane blocks; each
+    column's feature is found by an exact one-hot matmul against the bins
+    block instead of a reshape.  Both matmuls run at HIGHEST precision, so
+    the weighted counts are exact whatever the weights.
 
 ``histogram_level_scatter``
     The XLA path: the SAME one-hot contraction, but as a row-wise
@@ -42,11 +47,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tree_traverse import (VMEM_BUDGET, resolve_interpret,
-                                         vmem_error)
+from repro.kernels.tree_traverse import (LANE, VMEM_BUDGET, compiler_params,
+                                         lane_block, resolve_interpret,
+                                         round_up, vmem_error)
 
 # default tile sizes (the analytic seed; autotune may override)
-BLOCK_N = 1024      # batch lanes per tile
+BLOCK_N = 1024      # batch lanes per tile (a multiple of 128)
 BLOCK_R = 512       # (node, class) histogram rows resident per block
 BLOCK_COLS = 512    # target (feature, bin) columns per block
 
@@ -58,33 +64,56 @@ def default_block_f(n_features: int, n_bins: int) -> int:
 
 
 def _hist_kernel(node_ref, y_ref, w_ref, bins_ref, out_ref, *,
-                 n_classes: int, n_bins: int, block_r: int):
+                 n_classes: int, n_bins: int, block_r: int, block_c: int):
     ir = pl.program_id(1)
+    jc = pl.program_id(2)
     ib = pl.program_id(3)
-    node = node_ref[0]              # [BN] level-local node ids
-    y = y_ref[...]                  # [BN]
-    w = w_ref[0]                    # [BN] bootstrap multiplicity (0 = OOB/pad)
-    bins = bins_ref[...]            # [BN, BF] bin indices
+    node = node_ref[0]              # [1, BN] level-local node ids
+    y = y_ref[...]                  # [1, BN]
+    w = w_ref[0]                    # [1, BN] bootstrap multiplicity (0 = OOB/pad)
+    bins = bins_ref[...]            # [BN, F] bin indices
+    BN, F = bins.shape
 
     # rows: one-hot in (node, class), weighted — local to this row block
     row = node * n_classes + y - ir * block_r
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (node.shape[0], block_r), 1)
-    a = jnp.where(row[:, None] == r_iota, w[:, None], 0.0)
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (block_r, BN), 0)
+    a = jnp.where(r_iota == row, w, 0.0)                    # [block_r, BN]
 
-    # cols: one-hot in (feature, bin) for this feature block
-    b_iota = jax.lax.broadcasted_iota(
-        jnp.int32, (bins.shape[0], bins.shape[1], n_bins), 2)
-    b = (bins[:, :, None] == b_iota).astype(jnp.float32)
-    b = b.reshape(bins.shape[0], bins.shape[1] * n_bins)
+    # cols: one-hot in the flat (feature, bin) index c = f * n_bins + bin.
+    # Each column's feature f(c) picks the sample's global column
+    # f(c) * n_bins + bins[n, f(c)] through an exact one-hot matmul
+    c0 = jc * block_c
+    cf = jax.lax.broadcasted_iota(jnp.int32, (F, block_c), 1) + c0
+    lo = jax.lax.broadcasted_iota(jnp.int32, (F, block_c), 0) * n_bins
+    pick = ((lo <= cf) & (cf < lo + n_bins)).astype(jnp.float32)
+    gcol = (bins.astype(jnp.float32) + n_bins * jax.lax.broadcasted_iota(
+        jnp.int32, (BN, F), 1).astype(jnp.float32))
+    gcol = jnp.dot(gcol, pick, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)      # [BN, block_c]
+    cn = jax.lax.broadcasted_iota(jnp.int32, (BN, block_c), 1) + c0
+    b = (gcol == cn.astype(jnp.float32)).astype(jnp.float32)
 
-    part = jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+    part = jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
     @pl.when(ib == 0)       # first batch tile zero-inits the resident block
     def _():
         out_ref[0] = jnp.zeros_like(out_ref[0])
 
     out_ref[0] += part
+
+
+def hist_vmem(*, block_n: int, block_r: int, block_c: int,
+              n_features: int) -> int:
+    """Modeled VMEM bytes of one grid step: the weighted row one-hot, the
+    double-buffered bins block and its fp32 copy, the column pick matrix,
+    the column one-hot and its global-index operand, the partial and the
+    double-buffered resident histogram block, the per-sample rows (each
+    padded to an 8-row tile, double-buffered)."""
+    f = n_features
+    return 4 * (block_r * block_n + 3 * block_n * f + f * block_c
+                + 2 * block_n * block_c + 3 * block_r * block_c
+                + 2 * 3 * 8 * block_n)
 
 
 def histogram_level_pallas(node: jax.Array, y: jax.Array, w: jax.Array,
@@ -96,9 +125,11 @@ def histogram_level_pallas(node: jax.Array, y: jax.Array, w: jax.Array,
     [T, n_nodes, F, n_bins, C] fp32 count histograms.
 
     The batch (grid dim 3, innermost) is dead-padded with w=0 lanes; rows
-    (node*C + y) and feature columns are padded to their block sizes and
-    sliced off the output.  Weighted counts are integer-valued, so fp32
-    accumulation is exact below 2**24 samples per cell.
+    (node*C + y) and the flat (feature, bin) columns are padded to their
+    block sizes and sliced off the output.  ``block_f`` features' worth of
+    columns, rounded up to whole 128-lane blocks, make one column block.
+    Weighted counts are integer-valued, so fp32 accumulation is exact below
+    2**24 samples per cell.
     """
     T, N = node.shape
     F = bins.shape[1]
@@ -106,46 +137,48 @@ def histogram_level_pallas(node: jax.Array, y: jax.Array, w: jax.Array,
         block_f = default_block_f(F, n_bins)
     R = n_nodes * n_classes
     block_r = min(block_r, R)
-    block_n = min(block_n, N)
-    block_f = min(block_f, F)
-    cols = block_f * n_bins
+    if block_r < R:
+        block_r = round_up(block_r, 8)
+    block_n = lane_block(block_n, N)
+    cols = F * n_bins
+    block_c = min(round_up(min(block_f, F) * n_bins, LANE),
+                  round_up(cols, LANE))
 
-    need = 4 * (block_n * block_r            # one-hot rows
-                + 2 * block_n * cols         # bins block + one-hot cols
-                + 2 * block_r * cols         # partial + resident hist block
-                + block_n * (3 + block_f))   # node/y/w lanes
+    need = hist_vmem(block_n=block_n, block_r=block_r, block_c=block_c,
+                     n_features=F)
     if need >= VMEM_BUDGET:
         raise vmem_error(
             "histogram", need,
             f"block_n={block_n} x block_r={block_r} rows x "
-            f"block_f={block_f}*{n_bins} cols")
+            f"{block_c} (feature, bin) cols")
 
-    pad_n = (-N) % block_n
-    pad_r = (-R) % block_r
-    pad_f = (-F) % block_f
-    node = jnp.pad(node.astype(jnp.int32), ((0, 0), (0, pad_n)))
-    y = jnp.pad(y.astype(jnp.int32), (0, pad_n))
-    w = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, pad_n)))
-    bins = jnp.pad(bins.astype(jnp.int32), ((0, pad_n), (0, pad_f)))
-    Np, Fp, Rp = N + pad_n, F + pad_f, R + pad_r
+    Np = round_up(N, block_n)
+    Rp = round_up(R, block_r)
+    Cp = round_up(cols, block_c)
+    pad_n = Np - N
+    node = jnp.pad(node.astype(jnp.int32), ((0, 0), (0, pad_n)))[:, None]
+    y = jnp.pad(y.astype(jnp.int32), (0, pad_n))[None]
+    w = jnp.pad(w.astype(jnp.float32), ((0, 0), (0, pad_n)))[:, None]
+    bins = jnp.pad(bins.astype(jnp.int32), ((0, pad_n), (0, 0)))
 
     out = pl.pallas_call(
         functools.partial(_hist_kernel, n_classes=n_classes, n_bins=n_bins,
-                          block_r=block_r),
-        grid=(T, Rp // block_r, Fp // block_f, Np // block_n),
+                          block_r=block_r, block_c=block_c),
+        grid=(T, Rp // block_r, Cp // block_c, Np // block_n),
         in_specs=[
-            pl.BlockSpec((1, block_n), lambda t, ir, jf, ib: (t, ib)),
-            pl.BlockSpec((block_n,), lambda t, ir, jf, ib: (ib,)),
-            pl.BlockSpec((1, block_n), lambda t, ir, jf, ib: (t, ib)),
-            pl.BlockSpec((block_n, block_f), lambda t, ir, jf, ib: (ib, jf)),
+            pl.BlockSpec((1, 1, block_n), lambda t, ir, jc, ib: (t, 0, ib)),
+            pl.BlockSpec((1, block_n), lambda t, ir, jc, ib: (0, ib)),
+            pl.BlockSpec((1, 1, block_n), lambda t, ir, jc, ib: (t, 0, ib)),
+            pl.BlockSpec((block_n, F), lambda t, ir, jc, ib: (ib, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_r, cols),
-                               lambda t, ir, jf, ib: (t, ir, jf)),
-        out_shape=jax.ShapeDtypeStruct((T, Rp, Fp * n_bins), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_r, block_c),
+                               lambda t, ir, jc, ib: (t, ir, jc)),
+        out_shape=jax.ShapeDtypeStruct((T, Rp, Cp), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=resolve_interpret(interpret),
     )(node, y, w, bins)
 
-    out = out[:, :R, : F * n_bins]
+    out = out[:, :R, :cols]
     out = out.reshape(T, n_nodes, n_classes, F, n_bins)
     return jnp.transpose(out, (0, 1, 3, 4, 2))      # [T, nodes, F, B, C]
 

@@ -26,8 +26,9 @@ def _interpret() -> bool:
 def tree_traverse(feature, threshold, leaf, x, thr_scale=None,
                   leaf_scale=None, *, block_b: int = 128):
     """Grove bundle eval [B,F] -> [B,C] over packed fp32/bf16/int8 tables
-    (Pallas; oracle: ref.tree_traverse_ref).  int8 tables stay int8 in
-    VMEM; gathered values dequantize in-kernel via the per-tree scales."""
+    (Pallas; oracle: ref.tree_traverse_ref).  Leaves stay at the packed
+    width in VMEM; int8 values dequantize in-kernel via the per-tree
+    scales."""
     return tree_traverse_pallas(feature, threshold, leaf, x,
                                 thr_scale, leaf_scale,
                                 block_b=block_b, interpret=_interpret())
@@ -54,12 +55,11 @@ def fused_fog(feature, threshold, leaf, x, start, thresh, budget,
               max_hops: int, block_b: int = 128, compact: bool = True,
               interpret: bool | None = None):
     """Whole Algorithm-2 loop in ONE kernel launch: head-stacked packed
-    grove tables [O,G,t,...] pinned in VMEM at their packed width (fp32/
-    bf16/int8 — int8 fits ~4x the field), per-lane thresh/budget, early-
-    exit while_loop inside the kernel, gathered values dequantized in-
-    register.  ``compact`` permutes live lanes to a prefix each hop and
-    walks only the covering power-of-two prefix (bit-identical results);
-    ``interpret=None`` derives from the runtime backend.  Returns
+    grove tables [O,G,t,...] pinned in VMEM (leaves at the packed width,
+    int8 dequantized in-kernel), per-lane thresh/budget, early-exit
+    while_loop inside the kernel.  ``compact`` moves live lanes to a
+    prefix each hop and walks only the covering power-of-two prefix
+    (bit-identical results); ``interpret=None`` derives from the runtime backend.  Returns
     (proba [B,O,C], hops [B]); oracle: the FogEngine reference backend
     over the same pack."""
     return fused_fog_pallas(feature, threshold, leaf, x, start, thresh,

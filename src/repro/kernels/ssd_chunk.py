@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tree_traverse import resolve_interpret
+
 
 def _ssd_chunk_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, *,
                       n_heads: int):
@@ -53,7 +55,7 @@ def _ssd_chunk_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, st_ref, *,
 
 
 def ssd_chunk_pallas(xbar: jax.Array, a: jax.Array, Bm: jax.Array,
-                     Cm: jax.Array, *, interpret: bool = True):
+                     Cm: jax.Array, *, interpret: bool | None = None):
     """Fused intra-chunk SSD.
 
     xbar [B, nc, Q, H, P] (dt-scaled inputs), a [B, nc, H, Q] (log decays),
@@ -87,6 +89,6 @@ def ssd_chunk_pallas(xbar: jax.Array, a: jax.Array, Bm: jax.Array,
             jax.ShapeDtypeStruct((B * nc, Q, H, P), xbar.dtype),
             jax.ShapeDtypeStruct((B * nc, H, P, N), xbar.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, af, bf, cf)
     return (y.reshape(B, nc, Q, H, P), st.reshape(B, nc, H, P, N))

@@ -27,7 +27,6 @@ from repro.configs import ARCHS, get_arch
 from repro.launch.mesh import make_production_mesh
 from repro.launch import hlo_cost as R
 from repro.train.loop import SHAPES, input_specs, make_train_step_lowerable, shape_supported
-from repro import compat
 
 
 def dryrun_cell(arch: str, shape: str, *, multi_pod: bool = False,
@@ -44,7 +43,7 @@ def dryrun_cell(arch: str, shape: str, *, multi_pod: bool = False,
     sp = SHAPES[shape]
     t0 = time.time()
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if sp.kind == "train":
             jitted, (params_shape, opt_shape, batch_shape) = \
                 make_train_step_lowerable(cfg, mesh, shape,

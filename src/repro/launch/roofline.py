@@ -32,10 +32,11 @@ Compute lane-hops are ``Σ hops`` when the backend skips exited lanes
 
 ``bound`` is whichever of ``bytes/peak_bw`` and ``flops/peak_flops`` is
 slower; ``achieved`` = ideal over measured.  Machine peaks come from a
-:class:`MachineSpec` — pass your own to re-rate for new hardware; the
-bundled specs cover the TPU v5e target and an order-of-magnitude host-CPU
-stand-in for the interpret-mode container (whose achieved % is honestly
-tiny: the interpreted kernel is a correctness vehicle, not a fast path).
+:class:`MachineSpec`: pass one, or the device kind JAX reports
+(``jax.devices()[0].device_kind``), which is looked up in ``PEAKS`` — the
+one table of published peaks, each with its source.  A kind that is not in
+the table is an error: a device that cannot be priced gets no roofline
+share, never a borrowed one.
 
 The LM dry-run HLO cost model that used to live here is first-class in
 :mod:`repro.launch.hlo_cost`; importing its names from here still works
@@ -56,16 +57,24 @@ class MachineSpec:
     ici_bw: float = 0.0  # interconnect bytes/s (ring backend); 0 = ignore
 
 
-# TPU v5e per chip: bf16 MXU peak and HBM bandwidth (the deploy target the
-# kernels are written for)
+# TPU v5e, one chip — Google Cloud documentation, "TPU v5e": 197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect
 TPU_V5E = MachineSpec("tpu-v5e", peak_flops=197e12, peak_bw=819e9,
-                      ici_bw=4 * 50e9)
+                      ici_bw=1600e9 / 8)
 
-# order-of-magnitude stand-in for the CPU container the interpret-mode
-# kernels run in; override with a measured spec for real host numbers
-HOST_CPU = MachineSpec("host-cpu", peak_flops=1e11, peak_bw=5e10)
+# published peaks by the device kind JAX reports
+PEAKS = {"TPU v5 lite": TPU_V5E}
 
-SPECS = {s.name: s for s in (TPU_V5E, HOST_CPU)}
+
+def spec_for(device_kind: str) -> MachineSpec:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); a roofline share needs the device's "
+            "peaks") from None
 
 # fixed per-lane bookkeeping bytes a per-hop iteration touches: live mask,
 # hop counter read+write, threshold and budget reads
@@ -79,7 +88,7 @@ _LANE_FUSED_IN_BYTES = 13
 class RooflineEstimate:
     """One backend's modeled traffic/compute and the resulting bound."""
     backend: str
-    spec: MachineSpec
+    spec: MachineSpec | None
     bytes_moved: float
     flops: float
 
@@ -108,10 +117,13 @@ class RooflineEstimate:
         return self.ideal_s / measured_s
 
     def to_dict(self, measured_s: float | None = None) -> dict:
-        d = {"backend": self.backend, "spec": self.spec.name,
-             "bytes_moved": self.bytes_moved, "flops": self.flops,
-             "memory_s": self.memory_s, "compute_s": self.compute_s,
-             "bound": self.bound, "ideal_s": self.ideal_s}
+        d = {"backend": self.backend, "bytes_moved": self.bytes_moved,
+             "flops": self.flops}
+        if self.spec is None:           # unpriced device: traffic only
+            return d
+        d.update(spec=self.spec.name, memory_s=self.memory_s,
+                 compute_s=self.compute_s, bound=self.bound,
+                 ideal_s=self.ideal_s)
         if measured_s is not None:
             d["achieved_pct"] = round(100.0 * self.achieved(measured_s), 4)
         return d
@@ -123,14 +135,16 @@ class RooflineModel:
     pack:       a :class:`~repro.forest.pack.ForestPack` — supplies the
                 head/grove/tree/class shape and the dtype-aware table bytes
     n_features: width of the input rows
-    spec:       :class:`MachineSpec` (default: the TPU v5e target)
+    spec:       a :class:`MachineSpec`, or a device kind looked up in
+                ``PEAKS`` (unknown kinds raise ``ValueError``), or None
+                for traffic and FLOPs only (no bound, no achieved share)
     """
 
     def __init__(self, pack, n_features: int,
-                 spec: MachineSpec | str = TPU_V5E):
+                 spec: MachineSpec | str | None):
         self.pack = pack
         self.n_features = int(n_features)
-        self.spec = SPECS[spec] if isinstance(spec, str) else spec
+        self.spec = spec_for(spec) if isinstance(spec, str) else spec
 
     # -- per-unit terms ---------------------------------------------------
     @property

@@ -200,6 +200,8 @@ def _serve_registry(args) -> None:
 def main() -> None:
     ap = build_parser()
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_repo_compile_cache
+    use_repo_compile_cache()
     if args.registry is not None:
         _serve_registry(args)
         return

@@ -25,7 +25,6 @@ from repro.models import transformer as T
 from repro.train import checkpoint as ckpt
 from repro.train.fault import Heartbeat
 from repro.train.loop import make_train_step
-from repro import compat
 
 
 def main() -> None:
@@ -49,7 +48,7 @@ def main() -> None:
     print(f"[train] arch={cfg.name} mesh={dict(mesh.shape)} "
           f"B={args.batch} S={args.seq}")
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, p_specs, o_specs, init_opt = make_train_step(
             cfg, mesh, lr=args.lr, total_steps=args.steps, donate=False)
         params = T.init_params(cfg, jax.random.key(args.seed), jnp.float32)

@@ -50,9 +50,8 @@ def _capacity(n_tokens: int, cfg: ArchConfig) -> int:
 def _constrain(x: jax.Array, *parts) -> jax.Array:
     """with_sharding_constraint iff an ambient mesh is set (no-op in tests)."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
     parts = tuple(pp if (pp is None or
                          x.shape[i] % mesh.shape[pp] == 0) else None

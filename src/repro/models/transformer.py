@@ -44,9 +44,8 @@ def constrain_batch(x: jax.Array) -> jax.Array:
     No-op when no mesh is set (unit tests) or batch doesn't divide.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or "data" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "data" not in mesh.axis_names:
         return x
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     import numpy as _np

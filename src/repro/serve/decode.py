@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.core.policy import FogPolicy, margin_backend
 from repro.launch.mesh import dp_axes
@@ -96,8 +95,8 @@ def make_serve_step(cfg: ArchConfig, mesh, shape: str, *, fog: bool = False,
 
     jitted = jax.jit(
         wrapped,
-        in_shardings=compat.jit_shardings(mesh, in_specs),
-        out_shardings=compat.jit_shardings(mesh, out_specs))
+        in_shardings=in_specs,
+        out_shardings=out_specs)
     return jitted, (params_shape, cache_shape, inp)
 
 
@@ -124,6 +123,6 @@ def make_prefill_step(cfg: ArchConfig, mesh, shape: str, *,
 
     jitted = jax.jit(
         wrapped,
-        in_shardings=compat.jit_shardings(mesh, (p_specs, i_specs[key])),
+        in_shardings=(p_specs, i_specs[key]),
         out_shardings=None)
     return jitted, (params_shape, inp)

@@ -126,6 +126,14 @@ class DeviceDispatcher:
         self._fn_buckets = [_takes_bucket(fn) for fn in self._fns]
 
     @property
+    def replicas(self) -> list:
+        """The bound per-device decode callables, in device order."""
+        if self._fns is None:
+            raise ValueError("dispatcher not bound; construct the batcher "
+                             "with it first")
+        return list(self._fns)
+
+    @property
     def bucket_aware(self) -> bool:
         """Can the replicas route (model, version) buckets?  True only
         when EVERY replica decode fn takes a ``bucket`` keyword."""
@@ -668,6 +676,7 @@ class ForestReplicaServer:
             return nxt, hops, energy
 
         decode.packed = True
+        decode.state = state            # resident device buffers (inspection)
         decode.admit = admit
         decode.admit_many = admit_many
         decode.retire = retire

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.launch.mesh import dp_axes
 from repro.launch.sharding import batch_spec, param_shardings
@@ -113,8 +112,8 @@ def make_train_step(cfg: ArchConfig, mesh, *, lr: float = 3e-4,
     jitted = jax.jit(
         train_step,
         # batch spec inferred on call
-        in_shardings=compat.jit_shardings(mesh, (p_specs, o_specs, None)),
-        out_shardings=compat.jit_shardings(mesh, (p_specs, o_specs, None)),
+        in_shardings=(p_specs, o_specs, None),
+        out_shardings=(p_specs, o_specs, None),
         donate_argnums=(0, 1) if donate else (),
     )
     return jitted, p_specs, o_specs, init_opt
@@ -184,7 +183,7 @@ def make_train_step_lowerable(cfg: ArchConfig, mesh, shape: str,
 
     jitted = jax.jit(
         train_step,
-        in_shardings=compat.jit_shardings(mesh, (p_specs, o_specs, b_specs)),
-        out_shardings=compat.jit_shardings(mesh, (p_specs, o_specs, None)),
+        in_shardings=(p_specs, o_specs, b_specs),
+        out_shardings=(p_specs, o_specs, None),
     )
     return jitted, (params_shape, opt_shape, batch_shape)

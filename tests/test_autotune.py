@@ -60,10 +60,10 @@ def test_tune_measures_and_caches(tiny):
     pack, x, start, thresh, budget = tiny
     won = autotune.tune(pack, x, start, thresh, budget,
                         max_hops=pack.n_groves, repeats=1,
-                        blocks=[32, 64], persist=False)
+                        blocks=[128, 256], persist=False)
     assert won.source == "measured"
     assert won.measured_s > 0
-    assert won.block_b in (32, 64)
+    assert won.block_b in (128, 256)
     # the engine-facing lookup now returns the measured winner
     hit = autotune.best_config(pack, x.shape[1])
     assert hit == won
@@ -86,7 +86,7 @@ def test_cache_file_roundtrip(tiny, tmp_path, monkeypatch):
     path = tmp_path / "autotune.json"
     monkeypatch.setenv(autotune.CACHE_ENV, str(path))
     won = autotune.tune(pack, x, start, thresh, budget,
-                        max_hops=pack.n_groves, repeats=1, blocks=[32])
+                        max_hops=pack.n_groves, repeats=1, blocks=[128])
     saved = json.loads(path.read_text())
     assert len(saved) == 1
     (cfg,) = saved.values()
@@ -96,6 +96,35 @@ def test_cache_file_roundtrip(tiny, tmp_path, monkeypatch):
     hit = autotune.best_config(pack, x.shape[1])
     assert hit.source == "cache-file"
     assert (hit.block_b, hit.compact) == (won.block_b, won.compact)
+
+
+def test_cache_is_keyed_by_device_kind(tiny, tmp_path, monkeypatch):
+    """A winner measured on one kind of device is never served on another,
+    and a cache-file entry that names no device kind is ignored."""
+    pack, x, start, thresh, budget = tiny
+    path = tmp_path / "autotune.json"
+    monkeypatch.setenv(autotune.CACHE_ENV, str(path))
+    won = autotune.tune(pack, x, start, thresh, budget,
+                        max_hops=pack.n_groves, repeats=1, blocks=[128])
+    key = autotune.pack_key(pack, x.shape[1])
+    assert key[0] == jax.devices()[0].device_kind
+    saved = json.loads(path.read_text())
+    (kstr,) = saved
+    # the same signature measured elsewhere, plus a legacy kind-less entry
+    other = kstr.replace(key[0], "TPU v5 lite", 1)
+    legacy = kstr.split("/", 1)[1]
+    saved[other] = dict(saved[kstr], block_b=256, measured_s=1e-9)
+    saved[legacy] = dict(saved[kstr], block_b=384, measured_s=1e-9)
+    del saved[kstr]
+    path.write_text(json.dumps(saved))
+    autotune.clear_cache()
+    hit = autotune.best_config(pack, x.shape[1])
+    assert hit.source == "analytic"       # neither foreign entry is served
+    monkeypatch.setattr(autotune, "device_kind", lambda: "TPU v5 lite")
+    autotune.clear_cache()
+    hit = autotune.best_config(pack, x.shape[1])
+    assert (hit.source, hit.block_b) == ("cache-file", 256)
+    assert won.block_b == 128
 
 
 def test_hist_analytic_seed_scatter_on_interpret():
@@ -130,7 +159,7 @@ def test_cache_file_mixed_fused_and_hist_entries(tiny, tmp_path,
     path = tmp_path / "autotune.json"
     monkeypatch.setenv(autotune.CACHE_ENV, str(path))
     fused = autotune.tune(pack, x, start, thresh, budget,
-                          max_hops=pack.n_groves, repeats=1, blocks=[32])
+                          max_hops=pack.n_groves, repeats=1, blocks=[128])
     hist = autotune.tune_histogram(2, 3, 4, 5, 3, n_samples=256, repeats=1)
     assert len(json.loads(path.read_text())) == 2
     autotune.clear_cache()                    # "fresh process"

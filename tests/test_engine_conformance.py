@@ -507,12 +507,10 @@ def test_prime_batch_auto_chunk_int8_tiny_vmem(gc, x257, monkeypatch):
     eng = FogEngine(gc, backend="fused", block_b=256)
     pack = eng.tables.pack("int8")
     tables = pack.layout("fused")
-    # a budget that admits the int8 tables plus ~40 lanes, far below B=257
-    lane = (vmem_working_set(*tables, block_b=1, n_features=x257.shape[1])
-            - vmem_working_set(*tables, block_b=0,
-                               n_features=x257.shape[1]))
-    tiny_budget = vmem_working_set(*tables, block_b=0,
-                                   n_features=x257.shape[1]) + 40 * lane
+    # a budget that admits the int8 tables plus one 128-lane block, far
+    # below B=257
+    tiny_budget = vmem_working_set(*tables, block_b=LANE_ALIGN,
+                                   n_features=x257.shape[1]) + 1
     # fused_fog imports VMEM_BUDGET by value: patch BOTH module globals
     monkeypatch.setattr(ff, "VMEM_BUDGET", tiny_budget)
     monkeypatch.setattr(tt, "VMEM_BUDGET", tiny_budget)

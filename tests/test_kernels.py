@@ -437,23 +437,30 @@ def test_ssd_chunked_kernel_backend_equivalence():
 # ---------------------------------------------------------------------------
 
 def test_vmem_lane_bytes_accounting():
-    """Byte-exact per-lane model: fp32 row + two [O, C] fp32 accumulators +
-    [t] x (depth + 2) int32 walk state + five 4-byte scalars + the int8
-    live mask at ONE byte (the historical bug charged it four)."""
+    """Byte-exact per-lane model in the kernel's lane-major layout:
+    double-buffered x^T column (features padded to whole select steps),
+    [O, C] probabilities and five per-lane rows (each an 8-row tile), the
+    working x^T copy + carried probabilities + six carried lane rows, one
+    walk's temporaries, and — with compaction only — two bf16 bytes per
+    lane of the [block_b, block_b] permutation and prefix-count matrices."""
     from repro.kernels.fused_fog import vmem_lane_bytes
-    got = vmem_lane_bytes(n_heads=2, n_classes=10, grove_size=3, depth=6,
-                          n_features=16)
-    words = 16 + 2 * 2 * 10 + 3 * (6 + 2) + 5
-    assert got == 4 * words + 1
-    # one extra lane of an int8-masked field must cost an ODD byte count —
-    # a multiple of 4 would mean the mask is charged at scalar width again
-    assert got % 4 == 1
+    from repro.kernels.tree_traverse import walk_lane_bytes
+    got = vmem_lane_bytes(n_heads=2, n_classes=10, n_features=16,
+                          block_b=256, compact=True)
+    xf, oc = 64, 2 * 16
+    want = (2 * 4 * (xf + oc + 5 * 8) + 4 * (xf + 2 * oc + 6 * 8)
+            + walk_lane_bytes(n_classes=10) + 2 * 2 * 256)
+    assert got == want
+    # compaction's matrices are the only term that grows with the block
+    off = vmem_lane_bytes(n_heads=2, n_classes=10, n_features=16,
+                          block_b=256, compact=False)
+    assert got - off == 4 * 256
 
 
 def test_fit_block_b_aligned():
-    """fit_block_b rounds DOWN to a lane-tiling multiple of 8 (731-style
-    raw quotients defeat TPU sublane tiling), keeps sub-8 slivers
-    unrounded, and its modeled footprint stays under the budget."""
+    """fit_block_b returns a whole number of 128-lane rows (the TPU tiles
+    the batch axis in lanes), and its modeled footprint is maximal under
+    the budget."""
     from repro.kernels.fused_fog import (LANE_ALIGN, fit_block_b,
                                          vmem_working_set)
     from repro.kernels.tree_traverse import VMEM_BUDGET

@@ -10,7 +10,7 @@ from repro.launch.hlo_cost import (
     HloCostModel, _shape_bytes, analytic_model_flops, collective_bytes_from_hlo,
 )
 from repro.launch.roofline import (
-    HOST_CPU, TPU_V5E, MachineSpec, RooflineModel,
+    PEAKS, TPU_V5E, MachineSpec, RooflineModel, spec_for,
 )
 
 HLO = """\
@@ -108,7 +108,7 @@ def test_roofline_fused_moves_fewer_table_bytes(small_pack):
     """The paper's claim, in model form: the fused pin touches the tables
     once while the per-hop loop re-gathers a grove slice per lane per
     iteration — at any realistic batch the per-hop traffic dominates."""
-    m = RooflineModel(small_pack, n_features=12)
+    m = RooflineModel(small_pack, n_features=12, spec=TPU_V5E)
     per_hop = m.estimate("reference", batch=1000, iters=4)
     fused = m.estimate("fused", batch=1000, iters=4, hops_total=1300.0)
     assert fused.bytes_moved < per_hop.bytes_moved
@@ -119,8 +119,10 @@ def test_roofline_fused_moves_fewer_table_bytes(small_pack):
 def test_roofline_dtype_aware_bytes(small_pack):
     """int8 tables move a quarter of the fp32 per-hop table traffic."""
     p8 = small_pack.astype("int8")
-    f32 = RooflineModel(small_pack, 12).estimate("reference", 100, iters=4)
-    i8 = RooflineModel(p8, 12).estimate("reference", 100, iters=4)
+    f32 = RooflineModel(small_pack, 12, spec=TPU_V5E).estimate(
+        "reference", 100, iters=4)
+    i8 = RooflineModel(p8, 12, spec=TPU_V5E).estimate("reference", 100,
+                                                      iters=4)
     assert i8.bytes_moved < f32.bytes_moved
     # table term shrinks ~4x; the fp32 row/state terms are shared
     assert p8.table_bytes < small_pack.table_bytes / 2
@@ -141,10 +143,14 @@ def test_roofline_bound_and_achieved(small_pack):
 
 
 def test_roofline_spec_selection(small_pack):
-    """Specs are configurable by name or value; slower machines lower the
-    roofline (bigger ideal_s)."""
-    by_name = RooflineModel(small_pack, 12, spec="host-cpu")
-    assert by_name.spec is HOST_CPU
+    """Specs are configurable by device kind or value; slower machines lower
+    the roofline (bigger ideal_s); a device kind without published peaks is
+    an error, not a default."""
+    by_kind = RooflineModel(small_pack, 12, spec="TPU v5 lite")
+    assert by_kind.spec is TPU_V5E is spec_for("TPU v5 lite")
+    assert PEAKS["TPU v5 lite"].peak_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        RooflineModel(small_pack, 12, spec="cpu")
     custom = MachineSpec("slow", peak_flops=1e9, peak_bw=1e9)
     slow = RooflineModel(small_pack, 12, spec=custom).estimate(
         "fused", 100, iters=4)
@@ -157,7 +163,7 @@ def test_roofline_spec_selection(small_pack):
 def test_roofline_compaction_cuts_compute_not_bytes(small_pack):
     """Compaction scales the fused compute term with Σ hops; HBM traffic
     is unchanged (state lives in VMEM either way)."""
-    m = RooflineModel(small_pack, 12)
+    m = RooflineModel(small_pack, 12, spec=TPU_V5E)
     off = m.estimate("fused", 1000, iters=4, hops_total=1300.0,
                      compact=False)
     on = m.estimate("fused", 1000, iters=4, hops_total=1300.0, compact=True)
@@ -170,7 +176,7 @@ def test_roofline_row_names_map_to_traffic_class(small_pack):
     "reference-lazy"): the traffic class comes from the name root, the
     estimate reports the full name — so BENCH_engine.json roofline rows
     are labeled by the backend that was actually measured."""
-    m = RooflineModel(small_pack, 12)
+    m = RooflineModel(small_pack, 12, spec=TPU_V5E)
     ref = m.estimate("reference", 1000, iters=4)
     for name in ("pallas", "pallas-chunked", "reference-lazy"):
         est = m.estimate(name, 1000, iters=4)

@@ -11,7 +11,6 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.train import checkpoint as ckpt
 from repro.train.loop import make_train_step
-from repro import compat
 
 
 @pytest.fixture(scope="module")
@@ -24,7 +23,7 @@ def tiny_cfg():
 def _run(cfg, steps, *, compress=False, params=None, opt_state=None,
          start=0, seed=0):
     mesh = make_host_mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, *_, init_opt = make_train_step(
             cfg, mesh, lr=5e-3, total_steps=steps, donate=False,
             compress_pod_grads=compress)
