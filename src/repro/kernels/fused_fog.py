@@ -129,6 +129,34 @@ def fit_block_b(feature, threshold, leaf, thr_scale, leaf_scale, *,
     return fit
 
 
+def walk_widths(block: int, compact: bool) -> list[int]:
+    """The lane widths one hop of a ``block``-lane block may walk, widest
+    first: the whole block, and with compaction its power-of-two prefixes
+    down to ``MIN_COMPACT_WIDTH``."""
+    widths = [block]
+    if compact:
+        while widths[-1] % 2 == 0 and widths[-1] // 2 >= MIN_COMPACT_WIDTH:
+            widths.append(widths[-1] // 2)
+    return widths
+
+
+def walked_lane_hops(hops, block: int, compact: bool) -> int:
+    """Lane-hops the kernel walked for a batch whose lanes took ``hops``
+    (``[B]``, dead-lane padded here as in the kernel): each block loops
+    until its last live lane stops, and each hop walks the narrowest of
+    :func:`walk_widths` that covers the lanes still live (the whole block
+    without compaction), used lanes or not."""
+    h = np.asarray(hops, np.int64)
+    h = np.pad(h, (0, (-h.size) % block)).reshape(-1, block)
+    if not h.size or not h.max():
+        return 0
+    # live[b, j]: lanes of block b still live on its hop j (hops > j)
+    live = (h[:, :, None] > np.arange(h.max())).sum(axis=1)
+    widths = np.asarray(walk_widths(block, compact))
+    narrowest = widths[(widths >= live[..., None]).sum(axis=-1) - 1]
+    return int(np.where(live > 0, narrowest, 0).sum())
+
+
 def _bytes_of(a):
     bits = a if a.dtype == jnp.int32 else jax.lax.bitcast_convert_type(
         a, jnp.int32)
@@ -205,13 +233,9 @@ def _fused_fog_kernel(tab_ref, leaf_ref, scale_ref, x_ref, start_ref,
             out.append(total / t)
         return tuple(out)
 
-    # compacted walk prefix widths: BB, BB/2, ... down to MIN_COMPACT_WIDTH.
     # Only one branch executes per hop (lax.switch); survivors always sit in
     # a prefix after compaction, so the smallest width covering them is exact.
-    widths = [BB]
-    if compact:
-        while widths[-1] % 2 == 0 and widths[-1] // 2 >= MIN_COMPACT_WIDTH:
-            widths.append(widths[-1] // 2)
+    widths = walk_widths(BB, compact)
 
     def contrib_all(g, n_live):
         if len(widths) == 1:

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from contextlib import nullcontext
 from functools import partial
 from typing import Callable, Sequence
 
@@ -44,6 +45,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.policy import NO_BUDGET, FogPolicy, LanePolicies
+from repro.kernels.fused_fog import walked_lane_hops
+from repro.kernels.tree_traverse import lane_block
+
+# the packed serving path's fused kernel compacts live lanes every hop
+# (the engine's default); the lane-hop counter follows the same walk
+SERVE_COMPACT = True
+# host sub-phases the dispatcher times into the batcher's ``phase_ns``
+PHASES = ("harvest_wait", "harvest_copy", "splice", "enqueue")
+NO_SPAN = nullcontext()
+
+
+def span(name: str, **args):
+    """A host span on the profiler's clock (``jax.profiler.TraceAnnotation``;
+    ``args`` become the span's statistics).  Built only where tracing is
+    on: callers write ``with (span(...) if trace else NO_SPAN):``."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def replicate(tree, devices: Sequence) -> list:
@@ -62,7 +79,6 @@ class Pending:
     local: np.ndarray            # those lanes' offsets inside the span
     logits: object               # [span, C] device array (not yet ready)
     hops: object                 # [span] device array | None
-    dispatched_at: float = 0.0
     # the (model, version) registry bucket this call serves (None = the
     # single built-in model)
     bucket: tuple | None = None
@@ -87,6 +103,10 @@ class DeviceDispatcher:
 
     The dispatcher is bound to a slot count by the batcher
     (:meth:`bind`); ``n_slots`` must divide evenly over the devices.
+
+    ``phase_ns`` accumulates the packed cycle's host sub-phases (``PHASES``;
+    a batcher shares its own dict here) and ``trace`` (set by the batcher)
+    wraps each in a ``serve.dispatch.*`` / ``serve.harvest.*`` span.
     """
 
     def __init__(self, decode_factory: Callable, devices: Sequence | None = None):
@@ -100,6 +120,8 @@ class DeviceDispatcher:
         self._fns: list[Callable] | None = None
         # per-device dispatch queues, drained at harvest time
         self._queues: list[list[Pending]] = [[] for _ in self.devices]
+        self.phase_ns = dict.fromkeys(PHASES, 0)
+        self.trace = False
 
     @property
     def n_devices(self) -> int:
@@ -194,7 +216,7 @@ class DeviceDispatcher:
                                             span_pol)
             p = Pending(device=d, precision=policy.precision, lanes=mine,
                         local=mine - lo, logits=logits, hops=hops,
-                        dispatched_at=time.perf_counter(), bucket=bucket)
+                        bucket=bucket)
             self._queues[d].append(p)
             out.append(p)
         return out
@@ -258,42 +280,77 @@ class DeviceDispatcher:
         re-splice)."""
         lanes = np.fromiter(lanes, np.int64, len(lanes)) \
             if not isinstance(lanes, np.ndarray) else lanes.astype(np.int64)
+        pc, ph, tr = time.perf_counter_ns, self.phase_ns, self.trace
         out = []
         for d in np.unique(lanes // self.span):
             d = int(d)
             lo = d * self.span
             mine = lanes[(lanes >= lo) & (lanes < lo + self.span)]
-            nxt, hops, energy = self._fns[d](
-                np.float32(default_thresh), np.int32(default_budget),
-                precision, bucket=bucket)
+            fn = self._fns[d]
+            with (span("serve.dispatch.splice", replica=d) if tr
+                  else NO_SPAN):
+                t0 = pc()
+                fn.splice()
+                t1 = pc()
+            with (span("serve.dispatch.enqueue", replica=d,
+                       lanes=len(mine), precision=precision) if tr
+                  else NO_SPAN):
+                nxt, hops, energy = fn.enqueue(
+                    np.float32(default_thresh), np.int32(default_budget),
+                    precision, bucket=bucket)
+                t2 = pc()
+            ph["splice"] += t1 - t0
+            ph["enqueue"] += t2 - t1
             p = Pending(device=d, precision=precision, lanes=mine,
                         local=mine - lo, logits=None, hops=hops,
-                        dispatched_at=time.perf_counter(), bucket=bucket,
-                        nxt=nxt, energy=energy)
+                        bucket=bucket, nxt=nxt, energy=energy)
             self._queues[d].append(p)
             out.append(p)
         return out
 
-    def harvest_packed(self, n_slots: int):
+    def harvest_packed(self, n_slots: int, counts: dict | None = None):
         """Drain the packed queues: one deferred ``block_until_ready``,
         then scatter each group's lanes into full-batch HOST arrays.
 
         Returns ``(next [n_slots] int32, hops [n_slots] int64,
         energy_pj [n_slots] float64, dispatches)`` — no logits cross the
-        host boundary and nothing is re-priced here."""
+        host boundary and nothing is re-priced here.  With ``counts``, adds
+        the lane-hops the fused kernel walked over each call's whole span
+        (``lane_hops_walked``) and those of the lanes harvested from it
+        (``lane_hops_served``)."""
         pending = [p for q in self._queues for p in q]
         for q in self._queues:
             q.clear()
         if not pending:
             raise ValueError("harvest_packed() with nothing dispatched")
-        jax.block_until_ready([(p.nxt, p.hops, p.energy) for p in pending])
-        nxt = np.zeros((n_slots,), np.int32)
-        hops = np.zeros((n_slots,), np.int64)
-        energy = np.zeros((n_slots,), np.float64)
-        for p in pending:
-            nxt[p.lanes] = np.asarray(p.nxt)[p.local]
-            hops[p.lanes] = np.asarray(p.hops)[p.local]
-            energy[p.lanes] = np.asarray(p.energy)[p.local]
+        pc, ph, tr = time.perf_counter_ns, self.phase_ns, self.trace
+        with (span("serve.harvest.wait", pending=len(pending)) if tr
+              else NO_SPAN):
+            t0 = pc()
+            jax.block_until_ready([(p.nxt, p.hops, p.energy)
+                                   for p in pending])
+            t1 = pc()
+        with (span("serve.harvest.copy", pending=len(pending)) if tr
+              else NO_SPAN):
+            nxt = np.zeros((n_slots,), np.int32)
+            hops = np.zeros((n_slots,), np.int64)
+            energy = np.zeros((n_slots,), np.float64)
+            span_hops = []
+            for p in pending:
+                h = np.asarray(p.hops)
+                span_hops.append(h)
+                nxt[p.lanes] = np.asarray(p.nxt)[p.local]
+                hops[p.lanes] = h[p.local]
+                energy[p.lanes] = np.asarray(p.energy)[p.local]
+            t2 = pc()
+        ph["harvest_wait"] += t1 - t0
+        ph["harvest_copy"] += t2 - t1
+        if counts is not None:
+            for p, h in zip(pending, span_hops):
+                fn = self._fns[p.device]
+                counts["lane_hops_walked"] += walked_lane_hops(
+                    h, fn.block_b, fn.compact)
+                counts["lane_hops_served"] += int(h[p.local].sum())
         return nxt, hops, energy, pending
 
     def harvest(self, n_slots: int):
@@ -377,7 +434,7 @@ def _serve_eval_packed(pack, x, key, step, thresh, budget, def_thresh,
     thr = jnp.where(jnp.isnan(thresh), def_thresh, thresh)
     bud = jnp.where(budget < 0, def_budget, budget)
     res = _eval_core(pack, x, start, thr, bud, max_hops, backend,
-                     block_b, False)
+                     block_b, False, SERVE_COMPACT)
     nxt = jnp.argmax(res.proba, axis=-1).astype(jnp.int32)
     h = res.hops.astype(jnp.float32)
     energy = h * per_hop_pj + jnp.maximum(h - 1.0, 0.0) * transfer_pj
@@ -637,8 +694,7 @@ class ForestReplicaServer:
                     state["x"], state["thr"], state["bud"],
                     idx, mirror[idx], thr, bud, donate=False)
 
-        def decode(def_thresh, def_budget, precision=None, bucket=None):
-            _apply_staged()
+        def enqueue(def_thresh, def_budget, precision=None, bucket=None):
             prec = precision or self.default_precision
             if bucket is not None:
                 if self.cache is None:
@@ -675,7 +731,17 @@ class ForestReplicaServer:
                 max_hops=pack.n_groves, backend=backend, block_b=block_b)
             return nxt, hops, energy
 
+        def decode(def_thresh, def_budget, precision=None, bucket=None):
+            _apply_staged()
+            return enqueue(def_thresh, def_budget, precision, bucket)
+
         decode.packed = True
+        # the two halves of a dispatch, which the dispatcher times apart
+        decode.splice = _apply_staged
+        decode.enqueue = enqueue
+        # the fused kernel's walk: lane block and compaction
+        decode.block_b = lane_block(block_b, span)
+        decode.compact = SERVE_COMPACT
         decode.state = state            # resident device buffers (inspection)
         decode.admit = admit
         decode.admit_many = admit_many

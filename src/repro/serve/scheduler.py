@@ -53,6 +53,7 @@ contract is hard).  Telemetry lives in :class:`ServeStats` — the old
 from __future__ import annotations
 
 import dataclasses
+import gc
 import heapq
 import inspect
 import time
@@ -67,6 +68,7 @@ import numpy as np
 from repro.core.engine import HopMeter
 from repro.core.policy import (BUDGET_DEFAULT, NO_BUDGET, THRESH_DEFAULT,
                                FogPolicy, assemble, lane_knobs)
+from repro.serve.dispatch import NO_SPAN, PHASES, span
 
 
 @dataclasses.dataclass
@@ -99,8 +101,10 @@ class Request:
     shed: bool = False
     # wall-clock stamps for latency accounting: submit() stamps t_submit
     # (shed requests included — the shed tail is part of the latency
-    # story), completion stamps t_done.  Callers may pre-stamp t_submit.
+    # story), slot assignment t_admit (one clock read per refill burst),
+    # completion t_done.  Callers may pre-stamp t_submit.
     t_submit: float | None = None
+    t_admit: float | None = None
     t_done: float | None = None
 
     @property
@@ -296,6 +300,14 @@ class ContinuousBatcher:
         incoming request (submit returns False) or evict the ``"oldest"``
         queued request.
     meter: DEPRECATED — pass nothing and read ``batcher.stats`` instead.
+
+    Host instrumentation of the packed step: ``phase_ns`` (always on)
+    times its phases and the dispatcher's sub-phases in ns.  Setting
+    ``trace = True`` wraps each in a ``serve.*`` profiler span, times
+    Python's garbage-collector pauses into ``phase_ns["gc"]`` (one
+    ``serve.gc`` span each) and sums the fused kernel's walked and served
+    lane-hops into ``counts``; set it back to False to uninstall the
+    collector callback.
     """
 
     def __init__(self, n_slots: int, decode_fn: Callable | None,
@@ -409,10 +421,17 @@ class ContinuousBatcher:
         self._inflight_occ: np.ndarray | None = None
         self._tel_buf: list[tuple] = []
         self._steps_since_flush = 0
-        # per-phase host-time accumulators (ns) for the packed step — the
-        # bench-serve-profile breakdown reads these
-        self.phase_ns = {"harvest": 0, "bookkeep": 0, "telemetry": 0,
-                         "refill": 0, "dispatch": 0}
+        # per-phase host-time accumulators (ns) for the packed step, the
+        # dispatcher's sub-phases (shared dict) and, while tracing, the
+        # collector's pauses
+        self.phase_ns = dict.fromkeys(
+            ("harvest", "bookkeep", "telemetry", "refill", "dispatch",
+             *PHASES, "gc"), 0)
+        if dispatcher is not None:
+            dispatcher.phase_ns = self.phase_ns
+        self.counts = {"lane_hops_walked": 0, "lane_hops_served": 0}
+        self._trace = False
+        self._gc_open: tuple | None = None
         self.n_steps = 0
         # fleet-level FoG accounting: hop counts (and, with a governor's
         # energy model, modeled pJ) of every decoded token
@@ -423,6 +442,37 @@ class ContinuousBatcher:
                 "telemetry lives in batcher.stats (and the governor's "
                 "rolling estimate)", DeprecationWarning, stacklevel=2)
         self._meter = meter
+
+    @property
+    def trace(self) -> bool:
+        """Profiler spans, collector pauses and lane-hop counts (see the
+        class docstring); off by default."""
+        return self._trace
+
+    @trace.setter
+    def trace(self, on: bool) -> None:
+        on = bool(on)
+        if on == self._trace:
+            return
+        self._trace = on
+        if self.dispatcher is not None:
+            self.dispatcher.trace = on
+        if on:
+            gc.callbacks.append(self._on_gc)
+        else:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_open = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            s = span("serve.gc", generation=info["generation"])
+            s.__enter__()
+            self._gc_open = (s, time.perf_counter_ns())
+        elif self._gc_open is not None:
+            s, t0 = self._gc_open
+            self._gc_open = None
+            self.phase_ns["gc"] += time.perf_counter_ns() - t0
+            s.__exit__(None, None, None)
 
     @property
     def meter(self):
@@ -521,9 +571,11 @@ class ContinuousBatcher:
         self.stats.note_shed(req.tier)
 
     def _refill(self) -> None:
+        now = time.perf_counter() if self.queue else None
         for i, slot in enumerate(self.slots):
             if slot.request is None and self.queue:
                 req = self.queue.popleft()
+                req.t_admit = now
                 if (req.model is not None and req.version is None
                         and self.registry is not None):
                     # resolve the serving version HERE, once: the request
@@ -618,19 +670,24 @@ class ContinuousBatcher:
 
     def _step_packed(self) -> int:
         pc = time.perf_counter_ns
-        t0 = pc()
-        if self._inflight:
-            self._process_harvest()
-        t1 = pc()
-        self._refill_packed()
-        t2 = pc()
-        self.phase_ns["refill"] += t2 - t1
-        if self._n_active:
-            self._dispatch_packed()
-            t3 = pc()
-            self.phase_ns["dispatch"] += t3 - t2
-            if not self.pipeline:
+        tr = self._trace
+        with (span("serve.step", step=self.n_steps) if tr else NO_SPAN):
+            if self._inflight:
                 self._process_harvest()
+            with (span("serve.refill", admitted=min(len(self.queue),
+                                                    len(self._free)))
+                  if tr else NO_SPAN):
+                t1 = pc()
+                self._refill_packed()
+                t2 = pc()
+            self.phase_ns["refill"] += t2 - t1
+            if self._n_active:
+                with (span("serve.dispatch") if tr else NO_SPAN):
+                    self._dispatch_packed()
+                    t3 = pc()
+                self.phase_ns["dispatch"] += t3 - t2
+                if not self.pipeline:
+                    self._process_harvest()
         self.n_steps += 1
         return self._n_active
 
@@ -644,9 +701,11 @@ class ContinuousBatcher:
         heappop, popleft = heapq.heappop, q.popleft
         lanes, rows, thrs, buds = [], [], [], []
         n_admitted = 0
+        now = time.perf_counter()
         while q and free:
             i = heappop(free)
             req = popleft()
+            req.t_admit = now
             if (req.model is not None and req.version is None
                     and registry is not None):
                 # pin the serving version at slot assignment, exactly like
@@ -726,14 +785,32 @@ class ContinuousBatcher:
 
     def _process_harvest(self) -> None:
         pc = time.perf_counter_ns
-        t0 = pc()
-        nxt, hops, energy, pend = self.dispatcher.harvest_packed(
-            len(self.slots))
-        self.last_dispatches = pend
-        self._inflight = False
-        occ = self._inflight_occ
-        t1 = pc()
+        tr = self._trace
+        with (span("serve.harvest") if tr else NO_SPAN):
+            t0 = pc()
+            nxt, hops, energy, pend = self.dispatcher.harvest_packed(
+                len(self.slots), counts=self.counts if tr else None)
+            self.last_dispatches = pend
+            self._inflight = False
+            occ = self._inflight_occ
+            t1 = pc()
         self.phase_ns["harvest"] += t1 - t0
+        with (span("serve.bookkeep") if tr else NO_SPAN):
+            reqs = self._bookkeep(occ, nxt, hops)
+            t2 = pc()
+        self.phase_ns["bookkeep"] += t2 - t1
+        with (span("serve.telemetry") if tr else NO_SPAN):
+            if occ.size:
+                self._tel_buf.append((hops[occ], energy[occ], reqs, occ))
+                self._steps_since_flush += 1
+                if self._steps_since_flush >= self.telemetry_every:
+                    self._flush_telemetry()
+            t3 = pc()
+        self.phase_ns["telemetry"] += t3 - t2
+
+    def _bookkeep(self, occ, nxt, hops) -> list:
+        """Fold one harvest into the occupied lanes' requests, complete and
+        retire the finished ones; returns the lanes' requests in order."""
         occ_l = occ.tolist()
         nxt_l = nxt[occ].tolist()
         hops_l = hops[occ].tolist()
@@ -768,14 +845,7 @@ class ContinuousBatcher:
             # one bulk dead-stamp per replica (an admit in the same step
             # simply overwrites the staged entry)
             self.dispatcher.retire_lanes(retired)
-        t2 = pc()
-        self.phase_ns["bookkeep"] += t2 - t1
-        if occ.size:
-            self._tel_buf.append((hops[occ], energy[occ], reqs, occ))
-            self._steps_since_flush += 1
-            if self._steps_since_flush >= self.telemetry_every:
-                self._flush_telemetry()
-        self.phase_ns["telemetry"] += pc() - t2
+        return reqs
 
     def _flush_telemetry(self) -> None:
         """Replay the buffered per-step telemetry batches IN ORDER: the
