@@ -87,6 +87,8 @@ class Pending:
     # downloads [span, C] logits or re-prices hops on the host
     nxt: object | None = None    # [span] int32 device array
     energy: object | None = None  # [span] float32 device array
+    # outputs whose device-to-host copy was started at enqueue
+    prefetched: int = 0
 
 
 class DeviceDispatcher:
@@ -295,29 +297,42 @@ class DeviceDispatcher:
             with (span("serve.dispatch.enqueue", replica=d,
                        lanes=len(mine), precision=precision) if tr
                   else NO_SPAN):
-                nxt, hops, energy = fn.enqueue(
+                outs = fn.enqueue(
                     np.float32(default_thresh), np.int32(default_budget),
                     precision, bucket=bucket)
+                # every packed output goes to the host at harvest: start
+                # its copy now, queued on the device behind the kernel, so
+                # the harvest finds the bytes there instead of paying one
+                # synchronous round trip per array
+                with (span("serve.dispatch.prefetch", replica=d) if tr
+                      else NO_SPAN):
+                    for a in outs:
+                        a.copy_to_host_async()
                 t2 = pc()
             ph["splice"] += t1 - t0
             ph["enqueue"] += t2 - t1
+            nxt, hops, energy = outs
             p = Pending(device=d, precision=precision, lanes=mine,
                         local=mine - lo, logits=None, hops=hops,
-                        bucket=bucket, nxt=nxt, energy=energy)
+                        bucket=bucket, nxt=nxt, energy=energy,
+                        prefetched=len(outs))
             self._queues[d].append(p)
             out.append(p)
         return out
 
     def harvest_packed(self, n_slots: int, counts: dict | None = None):
         """Drain the packed queues: one deferred ``block_until_ready``,
-        then scatter each group's lanes into full-batch HOST arrays.
+        then scatter each group's lanes into full-batch HOST arrays (their
+        copies were started at enqueue, see :meth:`dispatch_packed`).
 
         Returns ``(next [n_slots] int32, hops [n_slots] int64,
         energy_pj [n_slots] float64, dispatches)`` — no logits cross the
         host boundary and nothing is re-priced here.  With ``counts``, adds
         the lane-hops the fused kernel walked over each call's whole span
         (``lane_hops_walked``) and those of the lanes harvested from it
-        (``lane_hops_served``)."""
+        (``lane_hops_served``), the outputs harvested (``host_copies``) and
+        those whose copy was started at enqueue
+        (``host_copies_prefetched``)."""
         pending = [p for q in self._queues for p in q]
         for q in self._queues:
             q.clear()
@@ -351,6 +366,8 @@ class DeviceDispatcher:
                 counts["lane_hops_walked"] += walked_lane_hops(
                     h, fn.block_b, fn.compact)
                 counts["lane_hops_served"] += int(h[p.local].sum())
+                counts["host_copies"] += 3      # nxt, hops, energy
+                counts["host_copies_prefetched"] += p.prefetched
         return nxt, hops, energy, pending
 
     def harvest(self, n_slots: int):

@@ -305,9 +305,10 @@ class ContinuousBatcher:
     times its phases and the dispatcher's sub-phases in ns.  Setting
     ``trace = True`` wraps each in a ``serve.*`` profiler span, times
     Python's garbage-collector pauses into ``phase_ns["gc"]`` (one
-    ``serve.gc`` span each) and sums the fused kernel's walked and served
-    lane-hops into ``counts``; set it back to False to uninstall the
-    collector callback.
+    ``serve.gc`` span each) and sums into ``counts`` the fused kernel's
+    walked and served lane-hops and the packed outputs harvested to the
+    host, all and those whose copy started at enqueue; set it back to
+    False to uninstall the collector callback.
     """
 
     def __init__(self, n_slots: int, decode_fn: Callable | None,
@@ -429,7 +430,9 @@ class ContinuousBatcher:
              *PHASES, "gc"), 0)
         if dispatcher is not None:
             dispatcher.phase_ns = self.phase_ns
-        self.counts = {"lane_hops_walked": 0, "lane_hops_served": 0}
+        self.counts = dict.fromkeys(
+            ("lane_hops_walked", "lane_hops_served", "host_copies",
+             "host_copies_prefetched"), 0)
         self._trace = False
         self._gc_open: tuple | None = None
         self.n_steps = 0
@@ -445,7 +448,7 @@ class ContinuousBatcher:
 
     @property
     def trace(self) -> bool:
-        """Profiler spans, collector pauses and lane-hop counts (see the
+        """Profiler spans, collector pauses and counters (see the
         class docstring); off by default."""
         return self._trace
 

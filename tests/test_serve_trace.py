@@ -1,7 +1,7 @@
 """Host instrumentation of the packed serving step: the always-on phase
 counters and admission stamps, and what ``ContinuousBatcher.trace`` adds
 (``serve.*`` profiler spans, collector pauses, the fused kernel's lane-hop
-counters)."""
+counters and the host-copy counters)."""
 import gc
 from pathlib import Path
 
@@ -27,6 +27,7 @@ SPANS = {
     "serve.dispatch": set(),
     "serve.dispatch.splice": {"replica"},
     "serve.dispatch.enqueue": {"replica", "lanes", "precision"},
+    "serve.dispatch.prefetch": {"replica"},
     "serve.gc": {"generation"},
 }
 
@@ -94,7 +95,9 @@ def test_trace_off_builds_no_span(trained, monkeypatch):
     _serve(b, trained[0].x_test, 12)
     assert not [n for n in names if n.startswith("serve.")]
     assert b._on_gc not in gc.callbacks
-    assert b.counts == {"lane_hops_walked": 0, "lane_hops_served": 0}
+    assert b.counts == dict.fromkeys(
+        ("lane_hops_walked", "lane_hops_served", "host_copies",
+         "host_copies_prefetched"), 0)
     assert b.phase_ns["gc"] == 0
     # the same run with tracing on does build them (the stand-in works)
     b.trace = True
@@ -142,14 +145,57 @@ def test_trace_on_writes_every_span_inside_the_step(trained, tmp_path):
     assert b.phase_ns["gc"] > 0
 
 
-def test_sub_phases_fit_inside_their_phase(trained):
+class _Nesting:
+    """Stands in for ``jax.profiler.TraceAnnotation``, noting each
+    ``serve.*`` span's entry and exit in order."""
+
+    def __init__(self, events):
+        self.events = events
+
+    def __call__(self, name, **kw):
+        events = self.events
+
+        class _Span:
+            def __enter__(self):
+                events.append(("enter", name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", name))
+
+        return _Span()
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_sub_phases_fit_inside_their_phase(trained, monkeypatch, trace):
+    events = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Nesting(events))
     b = _plane(trained, 8)
-    _serve(b, trained[0].x_test, 12)
+    b.trace = trace
+    try:
+        _serve(b, trained[0].x_test, 12)
+    finally:
+        b.trace = False
     ph = b.phase_ns
     assert all(ph[k] > 0 for k in ("harvest_wait", "harvest_copy", "splice",
                                    "enqueue"))
     assert ph["harvest_wait"] + ph["harvest_copy"] <= ph["harvest"]
     assert ph["splice"] + ph["enqueue"] <= ph["dispatch"]
+    names = [n for _, n in events if n.startswith("serve.")]
+    if not trace:
+        assert not names
+        return
+    # each prefetch opens and closes inside an enqueue, one per enqueue
+    stack, inside = [], []
+    for what, name in events:
+        if what == "enter":
+            if name == "serve.dispatch.prefetch":
+                inside.append(stack[-1])
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert not stack
+    assert inside == ["serve.dispatch.enqueue"] * events.count(
+        ("enter", "serve.dispatch.enqueue")) != []
 
 
 def _mock_decode(n_slots):
@@ -213,8 +259,8 @@ def test_lane_hop_counters_match_a_recount(trained, n_slots):
             h = np.asarray(p.hops)
             walked += _recount_walked(h, block)
             served += int(h[p.local].sum())
-    assert b.counts == {"lane_hops_walked": walked,
-                        "lane_hops_served": served}
+    assert (b.counts["lane_hops_walked"],
+            b.counts["lane_hops_served"]) == (walked, served)
     assert served == sum(r.hops[0] for r in b.completed)
     assert 0 < served < walked
 
