@@ -8,11 +8,12 @@ production mesh path goes through serve/decode.make_serve_step (the same
 functions the dry-run lowers).
 
 ``--devices N`` serves data-parallel: the params are replicated per device,
-each device owns the KV caches for a fixed span of slots, and the batcher
-fans each step out through a :class:`~repro.serve.dispatch.DeviceDispatcher`
-(on CPU, export ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-first).  ``--max-queue`` / ``--shed-policy`` expose the admission-control
-knobs.
+each device owns the KV caches for a fixed span of slots, the batcher's
+router places each refill burst on the replica with the most free slots,
+and each step fans out through a
+:class:`~repro.serve.dispatch.DeviceDispatcher` (on CPU, export
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first).
+``--max-queue`` / ``--shed-policy`` expose the admission-control knobs.
 """
 from __future__ import annotations
 
@@ -81,8 +82,10 @@ def build_parser() -> argparse.ArgumentParser:
                          "models/fog_exit.lm_hop_energy)")
     ap.add_argument("--devices", type=int, default=1,
                     help="data-parallel serving: replicate params over the "
-                         "first N local devices and shard the slot batch "
-                         "across them (CPU: export XLA_FLAGS="
+                         "first N local devices, each owning a fixed span "
+                         "of slots; the scheduler routes each refill burst "
+                         "to the replica with the most free slots (CPU: "
+                         "export XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N first)")
     ap.add_argument("--max-queue", type=int, default=None,
                     help="admission control: bound the request queue "

@@ -5,7 +5,11 @@ The ring backend shards *groves* across a mesh; this module shards the
 program, each bound to its own device and to a fixed contiguous span of
 the batcher's slots (slot ``i`` lives on device ``i // span`` forever, so
 per-device state — packed tables, KV caches, feature buffers — never
-migrates and every replica compiles exactly one program shape).
+migrates and every replica compiles exactly one program shape).  Which
+replica a request lands on is the batcher's choice: its router gives each
+refill burst the slots of one replica, the one with the most free slots,
+in turn (:meth:`~repro.serve.scheduler.ContinuousBatcher._route`), so a
+step's dispatch touches one replica while that replica has room.
 
 Dispatch is asynchronous: each step the
 :class:`~repro.serve.scheduler.ContinuousBatcher` calls
@@ -104,7 +108,10 @@ class DeviceDispatcher:
         CPU via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 
     The dispatcher is bound to a slot count by the batcher
-    (:meth:`bind`); ``n_slots`` must divide evenly over the devices.
+    (:meth:`bind`); ``n_slots`` must divide evenly over the devices.  The
+    batcher reads ``span`` to keep one free-slot heap per replica and
+    routes each refill burst to one of them; the dispatcher calls only the
+    replicas whose spans a dispatch's lanes touch.
 
     ``phase_ns`` accumulates the packed cycle's host sub-phases (``PHASES``;
     a batcher shares its own dict here) and ``trace`` (set by the batcher)
@@ -465,6 +472,10 @@ class ForestReplicaServer:
     :class:`~repro.forest.pack.ForestPack` replicas (one per precision in
     ``precisions``, so per-request ``FogPolicy(precision=...)`` contracts
     dispatch against resident tables instead of re-packing mid-step).
+    Every replica serves the whole field; the batcher's router decides
+    which replica's slots a request takes, and replica ``index`` draws the
+    start groves of its ``k``-th dispatch from
+    ``fold_in(key(seed + index), k)``.
 
         server = ForestReplicaServer(gc, n_features=16)
         disp = DeviceDispatcher(server.factory, devices=serve_devices(4))
@@ -640,6 +651,8 @@ class ForestReplicaServer:
         evaluation, argmax and energy pricing in one launch.  ``step()``
         therefore stops paying per-step row uploads, policy re-assembly and
         logits downloads; only three [span] vectors come back per dispatch.
+        The splice is compiled here, on ``device``, at every padded width a
+        burst can take, so no replica compiles one while serving.
         """
         from repro.core.engine import splice_slot_state
         self._span = span
@@ -674,6 +687,15 @@ class ForestReplicaServer:
         # fused splice, and what prefill()-style callers (calibration) read
         mirror = np.zeros((span, self.n_features), np.float32)
         self._buffers[index] = mirror
+        # compile the splice at every padded width it can take, on THIS
+        # device, now: which widths a replica meets depends on where the
+        # router puts each burst, and a splice compiled mid-serving stalls
+        # the step that needs it (outputs discarded, inputs not donated)
+        for width in {min(1 << k, span) for k in range(span.bit_length() + 1)}:
+            idx = np.arange(width)
+            splice_slot_state(state["x"], state["thr"], state["bud"], idx,
+                              mirror[idx], lp.thresh[idx], lp.budget[idx],
+                              donate=False)
 
         def admit_many(locals_, rows, thr, bud) -> None:
             if rows is not None:

@@ -30,6 +30,10 @@ and each precision group's dispatch fans out across the dispatcher's device
 replicas (fixed per-device slot spans, per-device dispatch queues, one
 deferred ``jax.block_until_ready`` at harvest) — the slot model, policy
 assembly and telemetry are unchanged; only the execution plane widens.
+The refill routes each burst of admitted requests to ONE replica, the one
+with the most free slots (ties in turn after the replica used last), and
+spills into the next in turn only when it is full: a step's dispatch then
+touches one replica and the replicas take turns (``_route``).
 
 Admission control: ``max_queue`` bounds the request queue.  When it is
 full, ``shed_policy`` decides who pays: ``"reject"`` sheds the incoming
@@ -302,12 +306,14 @@ class ContinuousBatcher:
     meter: DEPRECATED — pass nothing and read ``batcher.stats`` instead.
 
     Host instrumentation of the packed step: ``phase_ns`` (always on)
-    times its phases and the dispatcher's sub-phases in ns.  Setting
+    times its phases and the dispatcher's sub-phases in ns; ``route``, the
+    refill's choice of replica, is part of ``refill``.  Setting
     ``trace = True`` wraps each in a ``serve.*`` profiler span, times
     Python's garbage-collector pauses into ``phase_ns["gc"]`` (one
     ``serve.gc`` span each) and sums into ``counts`` the fused kernel's
-    walked and served lane-hops and the packed outputs harvested to the
-    host, all and those whose copy started at enqueue; set it back to
+    walked and served lane-hops, the packed outputs harvested to the
+    host, all and those whose copy started at enqueue, and the requests
+    admitted to each replica ``d`` (``routed_<d>``); set it back to
     False to uninstall the collector callback.
     """
 
@@ -410,10 +416,16 @@ class ContinuousBatcher:
                 "(the legacy step accounts inline)")
         self.pipeline = bool(pipeline)
         self.telemetry_every = int(telemetry_every)
-        # empty-slot min-heap + occupancy mask: the packed step never walks
-        # all n_slots in Python — refill pops the heap, harvest walks only
-        # the occupied lanes, bucket membership is maintained incrementally
-        self._free: list[int] = list(range(n_slots))
+        # one empty-slot min-heap per replica (the bound dispatcher's spans;
+        # one heap over every slot without one) + occupancy mask: the packed
+        # step never walks all n_slots in Python — refill pops the routed
+        # replica's heap (``_route``), harvest walks only the occupied
+        # lanes, bucket membership is maintained incrementally
+        self._span = dispatcher.span if dispatcher is not None else n_slots
+        self._free: list[list[int]] = [
+            list(range(lo, lo + self._span))
+            for lo in range(0, n_slots, self._span)]
+        self._route_last = len(self._free) - 1
         self._occ_mask = np.zeros((n_slots,), bool)
         self._n_active = 0
         self._bucket_lanes: dict[tuple, set[int]] = {}
@@ -426,13 +438,14 @@ class ContinuousBatcher:
         # dispatcher's sub-phases (shared dict) and, while tracing, the
         # collector's pauses
         self.phase_ns = dict.fromkeys(
-            ("harvest", "bookkeep", "telemetry", "refill", "dispatch",
-             *PHASES, "gc"), 0)
+            ("harvest", "bookkeep", "telemetry", "refill", "route",
+             "dispatch", *PHASES, "gc"), 0)
         if dispatcher is not None:
             dispatcher.phase_ns = self.phase_ns
+        self._routed = [f"routed_{d}" for d in range(len(self._free))]
         self.counts = dict.fromkeys(
             ("lane_hops_walked", "lane_hops_served", "host_copies",
-             "host_copies_prefetched"), 0)
+             "host_copies_prefetched", *self._routed), 0)
         self._trace = False
         self._gc_open: tuple | None = None
         self.n_steps = 0
@@ -573,22 +586,71 @@ class ContinuousBatcher:
         self.shed_requests.append(req)
         self.stats.note_shed(req.tier)
 
+    def _route(self, n_queued: int) -> list[int]:
+        """The replicas a refill burst takes its slots from, in order: the
+        one with the most free slots (ties go in turn after the replica used
+        last), then the others in turn after it.  The burst takes the
+        lowest free slots of the first and spills into the next only when
+        that one is full, so a step touches one replica while it has room
+        and the replicas take turns.  One replica: always itself, which is
+        the single heap's order.  Times itself into ``phase_ns["route"]``;
+        with ``trace`` on, one ``serve.route`` span (``replica``,
+        ``admitted``, ``spilled``)."""
+        frees = self._free
+        n = len(frees)
+        with (span("serve.route") if self._trace else NO_SPAN) as s:
+            t0 = time.perf_counter_ns()
+            if n == 1:
+                order = [0]
+            else:
+                last, best, most = self._route_last, 0, -1
+                for k in range(1, n + 1):
+                    d = (last + k) % n
+                    if len(frees[d]) > most:
+                        best, most = d, len(frees[d])
+                order = [(best + k) % n for k in range(n)]
+            self.phase_ns["route"] += time.perf_counter_ns() - t0
+            if s is not None:
+                admitted = min(n_queued, sum(map(len, frees)))
+                s.set_metadata(replica=order[0], admitted=admitted,
+                               spilled=max(0, admitted - len(frees[order[0]])))
+        return order
+
+    def _take_slots(self):
+        """Yield ``(slot, request)`` for a refill burst: queued requests in
+        order, each popped onto the lowest free slot of the replicas
+        ``_route`` gives, until the queue or every replica is empty.  Notes
+        the replica used last and, with ``trace`` on, ``routed_<d>``."""
+        q = self.queue
+        heappop, popleft = heapq.heappop, q.popleft
+        for d in self._route(len(q)):
+            free, n0 = self._free[d], len(q)
+            while q and free:
+                yield heappop(free), popleft()
+            if n0 > len(q):
+                self._route_last = d
+                if self._trace:
+                    self.counts[self._routed[d]] += n0 - len(q)
+            if not q:
+                return
+
     def _refill(self) -> None:
-        now = time.perf_counter() if self.queue else None
-        for i, slot in enumerate(self.slots):
-            if slot.request is None and self.queue:
-                req = self.queue.popleft()
-                req.t_admit = now
-                if (req.model is not None and req.version is None
-                        and self.registry is not None):
-                    # resolve the serving version HERE, once: the request
-                    # rides this version to completion even if a publish
-                    # hot-swaps the tenant's live version mid-decode
-                    req.version = self.registry.route(req.model, req.rid)
-                slot.request = req
-                slot.length = self.prefill_fn(i, req.prompt)
-                self._tokens[i] = req.prompt[-1]
-                self._lengths[i] = slot.length
+        if not self.queue:
+            return
+        now = time.perf_counter()
+        for i, req in self._take_slots():
+            slot = self.slots[i]
+            req.t_admit = now
+            if (req.model is not None and req.version is None
+                    and self.registry is not None):
+                # resolve the serving version HERE, once: the request
+                # rides this version to completion even if a publish
+                # hot-swaps the tenant's live version mid-decode
+                req.version = self.registry.route(req.model, req.rid)
+            slot.request = req
+            slot.length = self.prefill_fn(i, req.prompt)
+            self._tokens[i] = req.prompt[-1]
+            self._lengths[i] = slot.length
 
     @property
     def active(self) -> int:
@@ -677,8 +739,8 @@ class ContinuousBatcher:
         with (span("serve.step", step=self.n_steps) if tr else NO_SPAN):
             if self._inflight:
                 self._process_harvest()
-            with (span("serve.refill", admitted=min(len(self.queue),
-                                                    len(self._free)))
+            with (span("serve.refill", admitted=min(
+                    len(self.queue), len(self.slots) - self._n_active))
                   if tr else NO_SPAN):
                 t1 = pc()
                 self._refill_packed()
@@ -695,19 +757,14 @@ class ContinuousBatcher:
         return self._n_active
 
     def _refill_packed(self) -> None:
-        if not self.queue or not self._free:
+        if not self.queue or self._n_active == len(self.slots):
             return
-        q, free = self.queue, self._free
         slots, occ_mask = self.slots, self._occ_mask
         registry, ledger = self.registry, self.ledger
         lane_key, bucket_lanes = self._lane_key, self._bucket_lanes
-        heappop, popleft = heapq.heappop, q.popleft
         lanes, rows, thrs, buds = [], [], [], []
-        n_admitted = 0
         now = time.perf_counter()
-        while q and free:
-            i = heappop(free)
-            req = popleft()
+        for i, req in self._take_slots():
             req.t_admit = now
             if (req.model is not None and req.version is None
                     and registry is not None):
@@ -718,7 +775,6 @@ class ContinuousBatcher:
             slot.request = req
             slot.length = 1          # one resident feature row per slot
             occ_mask[i] = True
-            n_admitted += 1
             pol = req.policy
             if pol is not None:
                 thr, bud = lane_knobs(pol)
@@ -746,7 +802,7 @@ class ContinuousBatcher:
             if bucket is None:
                 bucket = bucket_lanes[key] = set()
             bucket.add(i)
-        self._n_active += n_admitted
+        self._n_active += len(lanes)
         if lanes:
             # one vectorized staging write per replica for the whole burst
             self.dispatcher.admit_lanes(
@@ -761,7 +817,7 @@ class ContinuousBatcher:
         s.length = 0
         self._occ_mask[i] = False
         self._n_active -= 1
-        heapq.heappush(self._free, i)
+        heapq.heappush(self._free[i // self._span], i)
         key = self._lane_key[i]
         if key is not None:
             self._bucket_lanes[key].discard(i)
@@ -1030,6 +1086,7 @@ class ContinuousBatcher:
                 self.stats.note_done(req.tier)
                 self.completed.append(req)
                 self.slots[i] = SlotState()
+                heapq.heappush(self._free[i // self._span], i)
                 self._tokens[i] = 0
                 self._lengths[i] = 0
         if step_hops:

@@ -24,6 +24,7 @@ SPANS = {
     "serve.bookkeep": set(),
     "serve.telemetry": set(),
     "serve.refill": {"admitted"},
+    "serve.route": {"replica", "admitted", "spilled"},
     "serve.dispatch": set(),
     "serve.dispatch.splice": {"replica"},
     "serve.dispatch.enqueue": {"replica", "lanes", "precision"},
@@ -97,7 +98,7 @@ def test_trace_off_builds_no_span(trained, monkeypatch):
     assert b._on_gc not in gc.callbacks
     assert b.counts == dict.fromkeys(
         ("lane_hops_walked", "lane_hops_served", "host_copies",
-         "host_copies_prefetched"), 0)
+         "host_copies_prefetched", "routed_0"), 0)
     assert b.phase_ns["gc"] == 0
     # the same run with tracing on does build them (the stand-in works)
     b.trace = True
@@ -143,6 +144,36 @@ def test_trace_on_writes_every_span_inside_the_step(trained, tmp_path):
             assert in_step(e), e.name
     assert any(in_step(e) for e in events if e.name == "serve.gc")
     assert b.phase_ns["gc"] > 0
+
+
+# the phase_ns keys before the router; each is still counted
+PHASES_BEFORE = ("harvest", "bookkeep", "telemetry", "refill", "dispatch",
+                 "harvest_wait", "harvest_copy", "splice", "enqueue", "gc")
+
+
+def test_route_span_counter_and_phase(trained, monkeypatch):
+    events = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Nesting(events))
+    b = _plane(trained, 8)
+    b.trace = True
+    try:
+        _serve(b, trained[0].x_test, 12)
+    finally:
+        b.trace = False
+    assert set(PHASES_BEFORE) < set(b.phase_ns)
+    assert 0 < b.phase_ns["route"] < b.phase_ns["refill"]
+    # one replica: every admitted request is routed to it
+    assert b.counts["routed_0"] == len(b.completed) == 36
+    # each route opens and closes inside a refill
+    stack, inside = [], []
+    for what, name in events:
+        if what == "enter":
+            if name == "serve.route":
+                inside.append(stack[-1])
+            stack.append(name)
+        else:
+            assert stack.pop() == name
+    assert inside and set(inside) == {"serve.refill"}
 
 
 class _Nesting:
