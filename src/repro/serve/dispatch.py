@@ -109,7 +109,7 @@ class DeviceDispatcher:
 
     The dispatcher is bound to a slot count by the batcher
     (:meth:`bind`); ``n_slots`` must divide evenly over the devices.  The
-    batcher reads ``span`` to keep one free-slot heap per replica and
+    batcher reads ``span`` to count the free slots of each replica and
     routes each refill burst to one of them; the dispatcher calls only the
     replicas whose spans a dispatch's lanes touch.
 

@@ -58,7 +58,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import heapq
 import inspect
 import time
 import warnings
@@ -70,8 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import HopMeter
-from repro.core.policy import (BUDGET_DEFAULT, NO_BUDGET, THRESH_DEFAULT,
-                               FogPolicy, assemble, lane_knobs)
+from repro.core.policy import NO_BUDGET, FogPolicy, assemble, lane_knobs
 from repro.serve.dispatch import NO_SPAN, PHASES, span
 
 
@@ -159,12 +157,13 @@ class ServeStats:
         for tier, k in counts.items():
             self._tier(tier)["n_done"] += k
 
-    def update(self, hops, energy_pj=None, tiers=None) -> None:
+    def update(self, hops, energy_pj=None, tiers=None, names=None) -> None:
         """Fold one batch of decoded events in.  ``energy_pj`` may carry
         NaN for events nothing could price (a ledgered batch with an
         unledgered tenant) — only finite entries feed the energy totals.
         ``tiers`` optionally labels each event with its request's QoS tier
-        for the per-tier breakdown."""
+        for the per-tier breakdown: one label per event or, with
+        ``names``, one integer code into ``names`` per event."""
         h = np.asarray(hops)
         self.total_hops += int(h.sum())
         self.n_events += int(h.size)
@@ -176,15 +175,24 @@ class ServeStats:
             self.n_priced += int(priced.sum())
             if priced.any():
                 self.has_energy = True
-        if tiers is not None:
-            e = (np.asarray(energy_pj, np.float64)
-                 if energy_pj is not None else None)
-            for i, tier in enumerate(tiers):
-                t = self._tier(tier)
-                t["n_events"] += 1
-                if priced is not None and priced[i]:
-                    t["total_pj"] += float(e[i])
-                    t["n_priced"] += 1
+        if tiers is None:
+            return
+        if names is None:
+            index: dict[str, int] = {}
+            tiers = [index.setdefault(t, len(index)) for t in tiers]
+            names = list(index)
+        codes = np.asarray(tiers, np.int64)
+        n = np.bincount(codes, minlength=len(names))
+        if priced is not None:
+            n_priced = np.bincount(codes[priced], minlength=len(names))
+            pj = np.bincount(codes[priced], weights=e[priced],
+                             minlength=len(names))
+        for c in np.flatnonzero(n).tolist():
+            t = self._tier(names[c])
+            t["n_events"] += int(n[c])
+            if priced is not None and n_priced[c]:
+                t["total_pj"] += float(pj[c])
+                t["n_priced"] += int(n_priced[c])
 
     def tier_summary(self) -> dict:
         """{tier: {n_done, n_shed, n_events, mean_energy_nj}} — the
@@ -235,8 +243,35 @@ class ServeStats:
 
 @dataclasses.dataclass
 class SlotState:
+    """A read view of one slot (``ContinuousBatcher.slots``)."""
     request: Request | None = None
     length: int = 0               # tokens already in this slot's cache
+
+
+def _runs(codes: np.ndarray) -> list:
+    """``(code, indices)`` of each distinct code, in order of first
+    appearance (one whole-slice run when every code is the same)."""
+    if not codes.size:
+        return []
+    if (codes == codes[0]).all():
+        return [(int(codes[0]), slice(None))]
+    u, first = np.unique(codes, return_index=True)
+    return [(int(c), np.flatnonzero(codes == c))
+            for c in u[np.argsort(first)].tolist()]
+
+
+class _Codes(dict):
+    """Small-int codes of keys, assigned on first lookup
+    (``names[code] == key``)."""
+
+    def __init__(self):
+        super().__init__()
+        self.names: list = []
+
+    def __missing__(self, key) -> int:
+        c = self[key] = len(self.names)
+        self.names.append(key)
+        return c
 
 
 def _policy_mode(decode_fn: Callable) -> str:
@@ -312,9 +347,19 @@ class ContinuousBatcher:
     Python's garbage-collector pauses into ``phase_ns["gc"]`` (one
     ``serve.gc`` span each) and sums into ``counts`` the fused kernel's
     walked and served lane-hops, the packed outputs harvested to the
-    host, all and those whose copy started at enqueue, and the requests
-    admitted to each replica ``d`` (``routed_<d>``); set it back to
-    False to uninstall the collector callback.
+    host, all and those whose copy started at enqueue, the requests
+    admitted to each replica ``d`` (``routed_<d>``), the policy
+    validations ``submit`` ran (``policy_checks``: once per distinct
+    policy object) and the admission groups refill bursts resolved
+    (``admit_groups``); set it back to False to uninstall the collector
+    callback.
+
+    The packed path keeps per-lane state in arrays indexed by lane: the
+    request, tokens left, serving bucket, tier and (tenant, version)
+    codes.  Work that depends only on a request's (tenant, version,
+    policy, tier) group — routing, knob and bucket resolution, telemetry
+    folds — runs once per group per burst or harvest; only the
+    ``Request`` fields the API returns are written per request.
     """
 
     def __init__(self, n_slots: int, decode_fn: Callable | None,
@@ -324,7 +369,9 @@ class ContinuousBatcher:
                  max_queue: int | None = None, shed_policy: str = "reject",
                  registry=None, pipeline: bool = False,
                  telemetry_every: int = 1):
-        self.slots = [SlotState() for _ in range(n_slots)]
+        self._n_slots = n_slots
+        # the request in each lane (None = free)
+        self._lane_req = np.full((n_slots,), None, object)
         self.queue: deque[Request] = deque()
         self.decode_fn = decode_fn
         self.prefill_fn = prefill_fn
@@ -416,20 +463,34 @@ class ContinuousBatcher:
                 "(the legacy step accounts inline)")
         self.pipeline = bool(pipeline)
         self.telemetry_every = int(telemetry_every)
-        # one empty-slot min-heap per replica (the bound dispatcher's spans;
-        # one heap over every slot without one) + occupancy mask: the packed
-        # step never walks all n_slots in Python — refill pops the routed
-        # replica's heap (``_route``), harvest walks only the occupied
-        # lanes, bucket membership is maintained incrementally
+        # occupancy mask + free-slot count per replica (the bound
+        # dispatcher's spans; one span of every slot without one): a burst
+        # takes the lowest free lanes of the replica ``_route`` picks, and
+        # the packed step never walks all n_slots in Python
         self._span = dispatcher.span if dispatcher is not None else n_slots
-        self._free: list[list[int]] = [
-            list(range(lo, lo + self._span))
-            for lo in range(0, n_slots, self._span)]
-        self._route_last = len(self._free) - 1
+        self._n_free = [self._span] * (n_slots // self._span)
+        self._route_last = len(self._n_free) - 1
         self._occ_mask = np.zeros((n_slots,), bool)
         self._n_active = 0
-        self._bucket_lanes: dict[tuple, set[int]] = {}
-        self._lane_key: list[tuple | None] = [None] * n_slots
+        # packed path, per lane: tokens left to generate, serving bucket
+        # id (-1 free), tier code, (tenant, version) code, and whether the
+        # request carries no policy of its own (restamped on rung moves);
+        # each written once per admission group
+        self._lane_left = np.zeros((n_slots,), np.int64)
+        self._lane_bucket = np.full((n_slots,), -1, np.int32)
+        self._lane_tier = np.zeros((n_slots,), np.int32)
+        self._lane_mv = np.zeros((n_slots,), np.int32)
+        self._lane_default = np.zeros((n_slots,), bool)
+        # codes of (model, version, precision) buckets, tier labels,
+        # (model, version) pairs and tenants
+        self._buckets, self._tiers = _Codes(), _Codes()
+        self._mvs, self._tenants = _Codes(), _Codes()
+        # buckets holding lanes, in the order their first lane was admitted
+        # (the dispatch order); an emptied bucket leaves at its next dispatch
+        self._live: dict[tuple, int] = {}
+        # policies submit() has validated, by identity (FogPolicy is frozen,
+        # so a verdict cannot go stale; the value keeps the id unique)
+        self._valid_policies: dict[int, FogPolicy] = {}
         self._inflight = False
         self._inflight_occ: np.ndarray | None = None
         self._tel_buf: list[tuple] = []
@@ -442,10 +503,11 @@ class ContinuousBatcher:
              "dispatch", *PHASES, "gc"), 0)
         if dispatcher is not None:
             dispatcher.phase_ns = self.phase_ns
-        self._routed = [f"routed_{d}" for d in range(len(self._free))]
+        self._routed = [f"routed_{d}" for d in range(len(self._n_free))]
         self.counts = dict.fromkeys(
             ("lane_hops_walked", "lane_hops_served", "host_copies",
-             "host_copies_prefetched", *self._routed), 0)
+             "host_copies_prefetched", *self._routed, "policy_checks",
+             "admit_groups"), 0)
         self._trace = False
         self._gc_open: tuple | None = None
         self.n_steps = 0
@@ -489,6 +551,13 @@ class ContinuousBatcher:
             self._gc_open = None
             self.phase_ns["gc"] += time.perf_counter_ns() - t0
             s.__exit__(None, None, None)
+
+    @property
+    def slots(self) -> list[SlotState]:
+        """A read view of every slot: its request (None = free) and the
+        tokens in its cache."""
+        return [SlotState(r, n) for r, n in zip(self._lane_req.tolist(),
+                                                 self._lengths.tolist())]
 
     @property
     def meter(self):
@@ -551,26 +620,18 @@ class ContinuousBatcher:
             # ladder rung inherited from the fleet default (backend,
             # max_hops, ...) stay with the fleet default — they select the
             # compiled program and would otherwise trip the static-knob
-            # rejection below
+            # rejection below.  A fresh policy per request: not cached.
             req.policy = FogPolicy(threshold=pol.threshold,
                                    hop_budget=pol.hop_budget,
                                    precision=pol.precision)
-        if req.policy is not None:
-            if req.policy.per_lane:
-                raise ValueError(
-                    f"request {req.rid}: per-request policies are scalar "
-                    "contracts; the batcher assembles the per-lane vectors")
-            # precision is static too, but the batcher handles it by
-            # dispatching one program per precision group (see step())
-            rejected = tuple(k for k in req.policy.static_overrides
-                             if k != "precision")
-            if rejected:
-                raise ValueError(
-                    f"request {req.rid}: policy sets static knobs "
-                    f"{rejected} — those select the "
-                    "compiled program and cannot vary per request; set "
-                    "them on the batcher's default_policy (per-request "
-                    "knobs are threshold, hop_budget and precision)")
+            self._check_policy(req.rid, req.policy)
+        elif (req.policy is not None and self._valid_policies.get(
+                id(req.policy)) is not req.policy):
+            self._check_policy(req.rid, req.policy)
+            cache = self._valid_policies
+            if len(cache) >= 64:
+                del cache[next(iter(cache))]
+            cache[id(req.policy)] = req.policy
         self.stats.n_offered += 1
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             if self.shed_policy == "reject":
@@ -580,6 +641,27 @@ class ContinuousBatcher:
             self._shed(self.queue.popleft())
         self.queue.append(req)
         return True
+
+    def _check_policy(self, rid, pol: FogPolicy) -> None:
+        """Raise unless ``pol`` is a valid per-request contract: scalar
+        knobs, and no static knob but precision."""
+        if self._trace:
+            self.counts["policy_checks"] += 1
+        if pol.per_lane:
+            raise ValueError(
+                f"request {rid}: per-request policies are scalar "
+                "contracts; the batcher assembles the per-lane vectors")
+        # precision is static too, but the batcher handles it by
+        # dispatching one program per precision group (see step())
+        rejected = tuple(k for k in pol.static_overrides
+                         if k != "precision")
+        if rejected:
+            raise ValueError(
+                f"request {rid}: policy sets static knobs "
+                f"{rejected} — those select the "
+                "compiled program and cannot vary per request; set "
+                "them on the batcher's default_policy (per-request "
+                "knobs are threshold, hop_budget and precision)")
 
     def _shed(self, req: Request) -> None:
         req.shed = True
@@ -592,11 +674,11 @@ class ContinuousBatcher:
         last), then the others in turn after it.  The burst takes the
         lowest free slots of the first and spills into the next only when
         that one is full, so a step touches one replica while it has room
-        and the replicas take turns.  One replica: always itself, which is
-        the single heap's order.  Times itself into ``phase_ns["route"]``;
+        and the replicas take turns.  One replica: always itself, lowest
+        free slot first.  Times itself into ``phase_ns["route"]``;
         with ``trace`` on, one ``serve.route`` span (``replica``,
         ``admitted``, ``spilled``)."""
-        frees = self._free
+        frees = self._n_free
         n = len(frees)
         with (span("serve.route") if self._trace else NO_SPAN) as s:
             t0 = time.perf_counter_ns()
@@ -606,40 +688,60 @@ class ContinuousBatcher:
                 last, best, most = self._route_last, 0, -1
                 for k in range(1, n + 1):
                     d = (last + k) % n
-                    if len(frees[d]) > most:
-                        best, most = d, len(frees[d])
+                    if frees[d] > most:
+                        best, most = d, frees[d]
                 order = [(best + k) % n for k in range(n)]
             self.phase_ns["route"] += time.perf_counter_ns() - t0
             if s is not None:
-                admitted = min(n_queued, sum(map(len, frees)))
+                admitted = min(n_queued, sum(frees))
                 s.set_metadata(replica=order[0], admitted=admitted,
-                               spilled=max(0, admitted - len(frees[order[0]])))
+                               spilled=max(0, admitted - frees[order[0]]))
         return order
 
-    def _take_slots(self):
-        """Yield ``(slot, request)`` for a refill burst: queued requests in
-        order, each popped onto the lowest free slot of the replicas
-        ``_route`` gives, until the queue or every replica is empty.  Notes
-        the replica used last and, with ``trace`` on, ``routed_<d>``."""
-        q = self.queue
-        heappop, popleft = heapq.heappop, q.popleft
-        for d in self._route(len(q)):
-            free, n0 = self._free[d], len(q)
-            while q and free:
-                yield heappop(free), popleft()
-            if n0 > len(q):
+    def _take_lanes(self, k: int) -> np.ndarray:
+        """The lanes of a refill burst of ``k`` queued requests (``k`` at
+        most the free slots), in admission order: the lowest free lanes of
+        the replicas ``_route`` gives, spilling into the next only when one
+        is full.  Marks them occupied; notes the replica used last and,
+        with ``trace`` on, ``routed_<d>``."""
+        occ, width, n_free = self._occ_mask, self._span, self._n_free
+        parts = []
+        for d in self._route(k):
+            m = min(k, n_free[d])
+            if m:
+                lo = d * width
+                parts.append(np.flatnonzero(~occ[lo:lo + width])[:m] + lo)
+                n_free[d] -= m
+                k -= m
                 self._route_last = d
                 if self._trace:
-                    self.counts[self._routed[d]] += n0 - len(q)
-            if not q:
-                return
+                    self.counts[self._routed[d]] += m
+                if not k:
+                    break
+        lanes = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        occ[lanes] = True
+        self._n_active += len(lanes)
+        return lanes
+
+    def _free_lanes(self, lanes: np.ndarray) -> None:
+        """Return ``lanes`` to their replicas' free slots."""
+        self._occ_mask[lanes] = False
+        self._lane_req[lanes] = None
+        self._lane_bucket[lanes] = -1
+        self._lengths[lanes] = 0
+        self._n_active -= len(lanes)
+        per = np.bincount(lanes // self._span, minlength=len(self._n_free))
+        for d in np.flatnonzero(per).tolist():
+            self._n_free[d] += int(per[d])
 
     def _refill(self) -> None:
-        if not self.queue:
+        q = self.queue
+        k = min(len(q), self._n_slots - self._n_active)
+        if not k:
             return
         now = time.perf_counter()
-        for i, req in self._take_slots():
-            slot = self.slots[i]
+        for i in self._take_lanes(k).tolist():
+            req = q.popleft()
             req.t_admit = now
             if (req.model is not None and req.version is None
                     and self.registry is not None):
@@ -647,23 +749,20 @@ class ContinuousBatcher:
                 # rides this version to completion even if a publish
                 # hot-swaps the tenant's live version mid-decode
                 req.version = self.registry.route(req.model, req.rid)
-            slot.request = req
-            slot.length = self.prefill_fn(i, req.prompt)
+            self._lane_req[i] = req
+            self._lengths[i] = self.prefill_fn(i, req.prompt)
             self._tokens[i] = req.prompt[-1]
-            self._lengths[i] = slot.length
 
     @property
     def active(self) -> int:
-        if self._packed:
-            return self._n_active
-        return sum(1 for s in self.slots if s.request is not None)
+        return self._n_active
 
-    def _tenant_rung(self, req: Request) -> FogPolicy | None:
-        """The ledgered rung policy billing this request's tenant (None
-        when no ledger, or the ledger knows neither tenant nor default)."""
+    def _tenant_rung(self, tenant: str | None) -> FogPolicy | None:
+        """The ledgered rung policy billing ``tenant`` (None when no
+        ledger, or the ledger knows neither tenant nor default)."""
         if self.ledger is None:
             return None
-        gov = self.ledger.governor_for(req.tenant)
+        gov = self.ledger.governor_for(tenant)
         return None if gov is None else gov.current
 
     def lane_policy(self) -> FogPolicy:
@@ -674,11 +773,11 @@ class ContinuousBatcher:
         default = (self.governor.current if self.governor is not None
                    else self.default_policy)
         pols: list[FogPolicy | None] = []
-        for s in self.slots:
-            if s.request is None or s.request.policy is not None:
-                pols.append(s.request.policy if s.request else None)
+        for req in self._lane_req.tolist():
+            if req is None or req.policy is not None:
+                pols.append(req.policy if req else None)
             else:
-                pols.append(self._tenant_rung(s.request))
+                pols.append(self._tenant_rung(req.tenant))
         return assemble(pols, default=default)
 
     def _bucket_groups(self) -> dict:
@@ -688,15 +787,15 @@ class ContinuousBatcher:
         (precision None = the default program), so a homogeneous batch
         still costs exactly one dispatch."""
         groups: dict[tuple, list[int]] = {}
-        for i, s in enumerate(self.slots):
-            if s.request is None:
+        reqs = self._lane_req.tolist()
+        for i, req in enumerate(reqs):
+            if req is None:
                 key = (None, None, None)
             else:
-                req = s.request
                 prec = (req.policy.precision if req.policy is not None
                         else None)
                 if prec is None:
-                    rung = self._tenant_rung(req)
+                    rung = self._tenant_rung(req.tenant)
                     if rung is not None:
                         prec = rung.precision
                 key = (req.model, req.version, prec)
@@ -704,7 +803,7 @@ class ContinuousBatcher:
         none_key = (None, None, None)
         none_idxs = groups.get(none_key)
         if none_idxs is not None and len(groups) > 1 and all(
-                self.slots[i].request is None for i in none_idxs):
+                reqs[i] is None for i in none_idxs):
             # lanes in the default group are all empty: don't spend a
             # dispatch on them, fold into an arbitrary real group (outputs
             # discarded)
@@ -740,7 +839,7 @@ class ContinuousBatcher:
             if self._inflight:
                 self._process_harvest()
             with (span("serve.refill", admitted=min(
-                    len(self.queue), len(self.slots) - self._n_active))
+                    len(self.queue), self._n_slots - self._n_active))
                   if tr else NO_SPAN):
                 t1 = pc()
                 self._refill_packed()
@@ -757,71 +856,85 @@ class ContinuousBatcher:
         return self._n_active
 
     def _refill_packed(self) -> None:
-        if not self.queue or self._n_active == len(self.slots):
+        q = self.queue
+        k = min(len(q), self._n_slots - self._n_active)
+        if not k:
             return
-        slots, occ_mask = self.slots, self._occ_mask
-        registry, ledger = self.registry, self.ledger
-        lane_key, bucket_lanes = self._lane_key, self._bucket_lanes
-        lanes, rows, thrs, buds = [], [], [], []
         now = time.perf_counter()
-        for i, req in self._take_slots():
-            req.t_admit = now
-            if (req.model is not None and req.version is None
-                    and registry is not None):
-                # pin the serving version at slot assignment, exactly like
-                # the legacy refill (hot-swap never migrates in-flight work)
-                req.version = registry.route(req.model, req.rid)
-            slot = slots[i]
-            slot.request = req
-            slot.length = 1          # one resident feature row per slot
-            occ_mask[i] = True
-            pol = req.policy
-            if pol is not None:
-                thr, bud = lane_knobs(pol)
-                prec = pol.precision
+        lanes = self._take_lanes(k)
+        popleft = q.popleft
+        reqs = [popleft() for _ in range(k)]
+        # what is read or written per request: the admission stamp, the
+        # tokens left to generate and the request's group
+        for r in reqs:
+            r.t_admit = now
+        left = [r.max_new_tokens - len(r.generated) for r in reqs]
+        keys = [(r.model, r.version, id(r.policy), r.tier) for r in reqs]
+        groups = {key: g for g, key in enumerate(dict.fromkeys(keys))}
+        if len(groups) > 1:
+            gid = np.fromiter(map(groups.__getitem__, keys), np.int64, k)
+        # each group's positions in the burst, split by serving version
+        registry = self.registry
+        parts = []
+        for g in range(len(groups)):
+            if len(groups) == 1:
+                pos, greqs = np.arange(k), reqs
             else:
-                rung = (None if ledger is None
-                        else self._tenant_rung(req))
-                if rung is not None:
-                    # tenant-ledger lanes are stamped CONCRETE at their
-                    # tenant's current rung (re-stamped when a flush
-                    # transitions that governor); fleet-default lanes stay
-                    # sentinels and track the rung in-jit every dispatch
-                    thr, bud = lane_knobs(rung)
-                    prec = rung.precision
-                else:
-                    thr, bud = THRESH_DEFAULT, BUDGET_DEFAULT
-                    prec = None
-            lanes.append(i)
-            rows.append(req.prompt)
-            thrs.append(thr)
-            buds.append(bud)
-            key = (req.model, req.version, prec)
-            lane_key[i] = key
-            bucket = bucket_lanes.get(key)
-            if bucket is None:
-                bucket = bucket_lanes[key] = set()
-            bucket.add(i)
-        self._n_active += len(lanes)
-        if lanes:
-            # one vectorized staging write per replica for the whole burst
-            self.dispatcher.admit_lanes(
-                np.asarray(lanes, np.int64),
-                np.asarray(rows, np.float32), thrs, buds)
+                pos = np.flatnonzero(gid == g)
+                greqs = [reqs[j] for j in pos.tolist()]
+            r0 = greqs[0]
+            if (r0.model is None or r0.version is not None
+                    or registry is None):
+                parts.append((pos, r0, r0.version))
+            elif registry.canary(r0.model) is None:
+                # pin the serving version at slot assignment (a hot-swap
+                # never migrates in-flight work): one live version for the
+                # whole group while the tenant runs no canary split
+                v = registry.live_version(r0.model)
+                for r in greqs:
+                    r.version = v
+                parts.append((pos, r0, v))
+            else:
+                vs = np.asarray([registry.route(r0.model, r.rid)
+                                 for r in greqs])
+                for r, v in zip(greqs, vs.tolist()):
+                    r.version = v
+                for v, idx in _runs(vs):
+                    parts.append((pos[idx], r0, v))
+        # resolve each part once, in order of its first request, so serving
+        # buckets join the dispatch order as they did lane by lane
+        parts.sort(key=lambda p: p[0][0])
+        thr = np.empty((k,), np.float32)
+        bud = np.empty((k,), np.int32)
+        for pos, r0, version in parts:
+            pol = r0.policy
+            if pol is None:
+                # tenant-ledger lanes are stamped CONCRETE at their tenant's
+                # current rung (re-stamped when a flush transitions that
+                # governor); fleet-default lanes stay sentinels and track
+                # the rung in-jit every dispatch
+                pol = self._tenant_rung(r0.tenant)
+            thr[pos], bud[pos] = lane_knobs(pol)
+            prec = None if pol is None else pol.precision
+            glanes = lanes[pos]
+            self._lane_bucket[glanes] = self._bucket_of(
+                (r0.model, version, prec))
+            self._lane_tier[glanes] = self._tiers[r0.tier]
+            self._lane_mv[glanes] = self._mvs[r0.model, version]
+            self._lane_default[glanes] = r0.policy is None
+        if self._trace:
+            self.counts["admit_groups"] += len(parts)
+        self._lane_req[lanes] = np.fromiter(reqs, object, k)
+        self._lane_left[lanes] = left
+        self._lengths[lanes] = 1        # one resident feature row per slot
+        # one vectorized staging write per replica for the whole burst
+        self.dispatcher.admit_lanes(
+            lanes, np.asarray([r.prompt for r in reqs], np.float32), thr, bud)
 
-    def _retire_lane(self, i: int) -> None:
-        """Host-side slot bookkeeping of one freed lane (the device-side
-        dead-stamp is batched by the caller via ``retire_lanes``)."""
-        s = self.slots[i]
-        s.request = None
-        s.length = 0
-        self._occ_mask[i] = False
-        self._n_active -= 1
-        heapq.heappush(self._free[i // self._span], i)
-        key = self._lane_key[i]
-        if key is not None:
-            self._bucket_lanes[key].discard(i)
-            self._lane_key[i] = None
+    def _bucket_of(self, key: tuple) -> int:
+        """The id of serving bucket ``key`` (model, version, precision);
+        a bucket that holds no lanes joins the end of the dispatch order."""
+        return self._live.setdefault(key, self._buckets[key])
 
     def _dispatch_packed(self) -> None:
         default = (self.governor.current if self.governor is not None
@@ -829,10 +942,10 @@ class ContinuousBatcher:
         def_thr = float(np.asarray(default.threshold))
         def_bud = (int(np.asarray(default.hop_budget))
                    if default.hop_budget is not None else NO_BUDGET)
-        for key in list(self._bucket_lanes):
-            lanes = self._bucket_lanes[key]
-            if not lanes:
-                del self._bucket_lanes[key]
+        for key, bid in list(self._live.items()):
+            lanes = np.flatnonzero(self._lane_bucket == bid)
+            if not lanes.size:
+                del self._live[key]
                 continue
             model, version, prec = key
             eff_prec = prec if prec is not None else default.precision
@@ -848,114 +961,109 @@ class ContinuousBatcher:
         with (span("serve.harvest") if tr else NO_SPAN):
             t0 = pc()
             nxt, hops, energy, pend = self.dispatcher.harvest_packed(
-                len(self.slots), counts=self.counts if tr else None)
+                self._n_slots, counts=self.counts if tr else None)
             self.last_dispatches = pend
             self._inflight = False
             occ = self._inflight_occ
             t1 = pc()
         self.phase_ns["harvest"] += t1 - t0
         with (span("serve.bookkeep") if tr else NO_SPAN):
-            reqs = self._bookkeep(occ, nxt, hops)
+            # the lanes' codes, read before their retirement clears them
+            hops, tiers = hops[occ], self._lane_tier[occ]
+            mvs = self._lane_mv[occ]
+            self._bookkeep(occ, nxt[occ], hops, tiers)
             t2 = pc()
         self.phase_ns["bookkeep"] += t2 - t1
         with (span("serve.telemetry") if tr else NO_SPAN):
             if occ.size:
-                self._tel_buf.append((hops[occ], energy[occ], reqs, occ))
+                self._tel_buf.append((hops, energy[occ], tiers, mvs, occ))
                 self._steps_since_flush += 1
                 if self._steps_since_flush >= self.telemetry_every:
                     self._flush_telemetry()
             t3 = pc()
         self.phase_ns["telemetry"] += t3 - t2
 
-    def _bookkeep(self, occ, nxt, hops) -> list:
-        """Fold one harvest into the occupied lanes' requests, complete and
-        retire the finished ones; returns the lanes' requests in order."""
-        occ_l = occ.tolist()
-        nxt_l = nxt[occ].tolist()
-        hops_l = hops[occ].tolist()
+    def _bookkeep(self, occ, nxt, hops, tiers) -> None:
+        """Fold one harvest (``nxt``, ``hops`` and tier codes of the
+        occupied lanes ``occ``, ascending) into their requests; complete
+        the finished ones in lane order and retire their lanes in bulk."""
         now = time.perf_counter()
-        reqs = []
-        retired = []
-        done_tiers: dict[str, int] = {}
-        slots, eos = self.slots, self.eos_id
-        completed_append = self.completed.append
-        retire = self._retire_lane
-        for j, i in enumerate(occ_l):
-            s = slots[i]
-            req = s.request
-            reqs.append(req)
-            tok = nxt_l[j]
-            gen = req.generated
-            gen.append(tok)
-            req.hops.append(hops_l[j])
-            s.length += 1
-            if tok == eos or len(gen) >= req.max_new_tokens:
-                req.done = True
-                if req.t_submit is not None:
-                    req.t_done = now
-                tier = req.tier
-                done_tiers[tier] = done_tiers.get(tier, 0) + 1
-                completed_append(req)
-                retire(i)
-                retired.append(i)
-        if done_tiers:
-            self.stats.note_done_many(done_tiers)
-        if retired:
-            # one bulk dead-stamp per replica (an admit in the same step
-            # simply overwrites the staged entry)
-            self.dispatcher.retire_lanes(retired)
-        return reqs
+        reqs = self._lane_req[occ].tolist()
+        left = self._lane_left[occ] - 1
+        self._lane_left[occ] = left
+        self._lengths[occ] += 1
+        for r, tok, h in zip(reqs, nxt.tolist(), hops.tolist()):
+            r.generated.append(tok)
+            r.hops.append(h)
+        done = (nxt == self.eos_id) | (left <= 0)
+        if not done.any():
+            return
+        if done.all():
+            fin, gone, codes = reqs, occ, tiers
+        else:
+            idx = np.flatnonzero(done)
+            fin = [reqs[j] for j in idx.tolist()]
+            gone, codes = occ[idx], tiers[idx]
+        for r in fin:
+            r.done = True
+            if r.t_submit is not None:
+                r.t_done = now
+        self.completed.extend(fin)
+        names = self._tiers.names
+        n = np.bincount(codes, minlength=len(names))
+        self.stats.note_done_many({names[c]: int(n[c])
+                                   for c in np.flatnonzero(n).tolist()})
+        self._free_lanes(gone)
+        # one bulk dead-stamp per replica (an admit in the same step
+        # simply overwrites the staged entry)
+        self.dispatcher.retire_lanes(gone)
 
     def _flush_telemetry(self) -> None:
         """Replay the buffered per-step telemetry batches IN ORDER: the
         governor/ledger observe+step per batch exactly as the inline path
         would have, the fleet stats and registry per-version stats fold in
         the same events — deferral changes when the consumers see the
-        telemetry (flush boundaries), never what they see."""
+        telemetry (flush boundaries), never what they see.  Each batch
+        is folded per tier, tenant and (tenant, version) code."""
         buf, self._tel_buf = self._tel_buf, []
         self._steps_since_flush = 0
         if not buf:
             return
+        ledger, registry = self.ledger, self.registry
+        names, mv_keys = self._tiers.names, self._mvs.names
         ledger_trans = None
-        if self.ledger is not None:
-            govs = [g for _, g in self.ledger.items()]
-            if self.ledger.default is not None:
-                govs.append(self.ledger.default)
+        if ledger is not None:
+            govs = [g for _, g in ledger.items()]
+            if ledger.default is not None:
+                govs.append(ledger.default)
             ledger_trans = [(g, len(g.transitions)) for g in govs]
+            tenant_of = np.asarray([self._tenants[m] for m, _ in mv_keys],
+                                   np.int64)
         fleet_batches = []
-        for hops, energy, reqs, lanes in buf:
-            tiers = [r.tier for r in reqs]
-            devices = (self.dispatcher.lane_devices(lanes)
-                       if self.dispatcher is not None else None)
+        for hops, energy, tiers, mvs, lanes in buf:
             e = energy
             if self.governor is not None:
-                fleet_batches.append((e, devices))
-            elif self.ledger is not None:
+                fleet_batches.append(
+                    (e, self.dispatcher.lane_devices(lanes)))
+            elif ledger is not None:
                 # per-tenant governance, NaN for lanes no governor bills —
                 # identical grouping to the legacy inline _account
+                devices = self.dispatcher.lane_devices(lanes)
                 e = energy.copy()
-                by_tenant: dict[str | None, list[int]] = {}
-                for i, r in enumerate(reqs):
-                    by_tenant.setdefault(r.tenant, []).append(i)
-                for tenant, idxs in by_tenant.items():
-                    gov = self.ledger.governor_for(tenant)
+                for c, idx in _runs(tenant_of[mvs]):
+                    gov = ledger.governor_for(self._tenants.names[c])
                     if gov is None:
-                        e[idxs] = np.nan
+                        e[idx] = np.nan
                         continue
-                    gov.ingest([(e[idxs],
-                                 None if devices is None
-                                 else devices[idxs])])
-            self.stats.update(hops, e, tiers=tiers)
-            if self.registry is not None:
-                by_version: dict[tuple, list[int]] = {}
-                for i, r in enumerate(reqs):
-                    if r.model is not None and r.version is not None:
-                        by_version.setdefault(
-                            (r.model, r.version), []).append(i)
-                for (tenant, version), idxs in by_version.items():
-                    self.registry.stats_for(tenant, version).update(
-                        hops[idxs], e[idxs],
-                        tiers=[tiers[i] for i in idxs])
+                    gov.ingest([(e[idx], devices[idx])])
+            self.stats.update(hops, e, tiers=tiers, names=names)
+            if registry is not None:
+                for c, idx in _runs(mvs):
+                    model, version = mv_keys[c]
+                    if model is not None and version is not None:
+                        registry.stats_for(model, version).update(
+                            hops[idx], e[idx], tiers=tiers[idx],
+                            names=names)
         if self.governor is not None and fleet_batches:
             self.governor.ingest(fleet_batches)
         if ledger_trans is not None and any(
@@ -966,25 +1074,24 @@ class ContinuousBatcher:
             self._restamp_default_lanes()
 
     def _restamp_default_lanes(self) -> None:
-        lanes, thrs, buds = [], [], []
-        for i in np.flatnonzero(self._occ_mask).tolist():
-            req = self.slots[i].request
-            if req is None or req.policy is not None:
-                continue
-            rung = self._tenant_rung(req)
+        """Stamp the occupied lanes without a policy of their own at their
+        tenant's current rung, one (tenant, version) group at a time."""
+        lanes = np.flatnonzero(self._occ_mask & self._lane_default)
+        thr = np.empty((len(lanes),), np.float32)
+        bud = np.empty((len(lanes),), np.int32)
+        keep = np.zeros((len(lanes),), bool)
+        for c, idx in _runs(self._lane_mv[lanes]):
+            model, version = self._mvs.names[c]
+            rung = self._tenant_rung(model)
             if rung is None:
                 continue
-            thr, bud = lane_knobs(rung)
-            lanes.append(i)
-            thrs.append(thr)
-            buds.append(bud)
-            key = (req.model, req.version, rung.precision)
-            if key != self._lane_key[i]:
-                self._bucket_lanes[self._lane_key[i]].discard(i)
-                self._lane_key[i] = key
-                self._bucket_lanes.setdefault(key, set()).add(i)
-        if lanes:
-            self.dispatcher.admit_lanes(lanes, None, thrs, buds)
+            thr[idx], bud[idx] = lane_knobs(rung)
+            keep[idx] = True
+            self._lane_bucket[lanes[idx]] = self._bucket_of(
+                (model, version, rung.precision))
+        if keep.any():
+            self.dispatcher.admit_lanes(lanes[keep], None, thr[keep],
+                                        bud[keep])
 
     def flush(self) -> None:
         """Drain the pipelined loop: harvest any in-flight dispatch, then
@@ -1009,7 +1116,7 @@ class ContinuousBatcher:
         if self._packed:
             return self._step_packed()
         self._refill()
-        occ = [i for i, s in enumerate(self.slots) if s.request is not None]
+        occ = np.flatnonzero(self._occ_mask).tolist()
         if not occ:
             return 0
         tokens = self._tokens
@@ -1026,11 +1133,11 @@ class ContinuousBatcher:
                 self.dispatcher.dispatch(tokens, lengths, pol, idxs,
                                          bucket=bucket)
             logits, hops, self.last_dispatches = self.dispatcher.harvest(
-                len(self.slots))
+                self._n_slots)
         elif self._policy_aware:
             base = self.lane_policy()
             groups = self._bucket_groups()
-            n = len(self.slots)
+            n = self._n_slots
             logits, hops = None, None
             for (model, version, prec), idxs in groups.items():
                 pol = base if prec is None else base.replace(precision=prec)
@@ -1067,28 +1174,27 @@ class ContinuousBatcher:
         # cost of one tolist() at serving-scale slot counts
         nxt_l = nxt.tolist()
         hops_l = hops.tolist() if hops is not None else None
-        step_hops = []
+        step_hops, gone = [], []
         now = time.perf_counter()
         for i in occ:
-            s = self.slots[i]
-            req = s.request
+            req = self._lane_req[i]
             tok = nxt_l[i]
             req.generated.append(tok)
             if hops_l is not None:
                 h = hops_l[i]
                 req.hops.append(h)
                 step_hops.append((h, req, i))
-            s.length += 1
             if tok == self.eos_id or len(req.generated) >= req.max_new_tokens:
                 req.done = True
                 if req.t_submit is not None:
                     req.t_done = now
                 self.stats.note_done(req.tier)
                 self.completed.append(req)
-                self.slots[i] = SlotState()
-                heapq.heappush(self._free[i // self._span], i)
-                self._tokens[i] = 0
-                self._lengths[i] = 0
+                gone.append(i)
+        if gone:
+            gone = np.asarray(gone, np.int64)
+            self._free_lanes(gone)
+            self._tokens[gone] = 0
         if step_hops:
             self._account(step_hops)
         return self.active

@@ -160,6 +160,60 @@ def test_static_knobs_on_request_policy_rejected():
                                policy=FogPolicy(backend="pallas")))
 
 
+
+@pytest.mark.parametrize("bad", [
+    FogPolicy(threshold=jnp.asarray([0.1, 0.2])),
+    FogPolicy(threshold=0.1, max_hops=2),
+    FogPolicy(backend="pallas")], ids=["per_lane", "max_hops", "backend"])
+def test_invalid_policy_raises_on_every_submit(bad):
+    """submit() validates each distinct policy object once and remembers
+    only valid ones: an invalid policy raises on every submit, before and
+    after a valid policy has been validated and cached."""
+    n = 2
+    decode_fn, _ = _mock_policy_decode(n)
+    batcher = ContinuousBatcher(n, decode_fn,
+                                lambda slot, prompt: len(prompt))
+    good = FogPolicy(threshold=0.5, precision="int8")
+    batcher.trace = True
+    try:
+        for rid in range(3):
+            with pytest.raises(ValueError):
+                batcher.submit(Request(rid=rid, prompt=np.asarray([0]),
+                                       policy=bad))
+            assert batcher.submit(Request(rid=10 + rid,
+                                          prompt=np.asarray([0]),
+                                          policy=good))
+    finally:
+        batcher.trace = False
+    assert [r.rid for r in batcher.queue] == [10, 11, 12]
+    assert batcher.stats.n_offered == 3
+    # three refusals of the bad policy, one validation of the good one
+    assert batcher.counts["policy_checks"] == 4
+
+
+def test_energy_budget_requests_resolve_past_the_policy_cache():
+    """A request with an energy budget gets a fresh policy from the
+    governor on every submit (never a cached one), and each resolves to
+    the rung its budget buys."""
+    n = 2
+    gov = _governor(budget_nj=2.0)
+    batcher = ContinuousBatcher(n, _threshold_driven_decode(n),
+                                lambda slot, prompt: len(prompt), eos_id=-1,
+                                governor=gov)
+    batcher.trace = True
+    try:
+        reqs = [Request(rid=rid, prompt=np.asarray([0]), max_new_tokens=1,
+                        energy_budget_nj=0.4) for rid in range(3)]
+        for r in reqs:
+            batcher.submit(r)
+    finally:
+        batcher.trace = False
+    assert len({id(r.policy) for r in reqs}) == 3
+    assert all(int(np.asarray(r.policy.hop_budget)) == 1 for r in reqs)
+    assert batcher.counts["policy_checks"] == 3
+    assert all(h == [1] for h in (r.hops for r in batcher.run()))
+
+
 def _mock_precision_decode(n_slots, vocab=16):
     """Precision-aware mock: records each dispatch's precision and encodes
     it into hops (fp32 -> 32, int8 -> 8, default/None -> 1), so tests can

@@ -12,19 +12,25 @@ Everything runs in ONE process: ``make_dataset`` is process-seeded, so
 cross-process runs see different data, but within a process each mode
 rebuilds an identical plane from the same seed.
 """
+import heapq
+import time
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import FogPolicy, split
 from repro.core.engine import splice_lanes, splice_slot_state
+from repro.core.energy import EnergyModel
 from repro.core.policy import (BUDGET_DEFAULT, DEAD_BUDGET, DEAD_THRESH,
-                               LanePolicies, THRESH_DEFAULT)
+                               NO_BUDGET, LanePolicies, THRESH_DEFAULT,
+                               lane_knobs)
 from repro.forest import ForestPack
 from repro.launch.mesh import serve_devices
 from repro.registry import ModelRegistry, PackCache
 from repro.serve.dispatch import DeviceDispatcher, ForestReplicaServer
-from repro.serve.governor import EnergyGovernor, default_ladder
+from repro.serve.governor import (EnergyGovernor, TenantLedger,
+                                  default_ladder)
 from repro.serve.scheduler import ContinuousBatcher, Request
 
 
@@ -270,3 +276,407 @@ def test_flush_is_idempotent_and_drains_inflight(trained):
     before = (b.stats.n_events, b.stats.total_hops, len(b.completed))
     b.flush()
     assert (b.stats.n_events, b.stats.total_hops, len(b.completed)) == before
+
+
+# --------------------------------------------------------------------------
+# admission groups against the per-request rule
+# --------------------------------------------------------------------------
+
+def _old_update(st, hops, e, tiers):
+    """``ServeStats.update`` as it was: the per-tier fold one event at a
+    time."""
+    st.total_hops += int(hops.sum())
+    st.n_events += int(hops.size)
+    priced = np.isfinite(e)
+    st.total_pj += float(e[priced].sum())
+    st.n_priced += int(priced.sum())
+    st.has_energy = st.has_energy or bool(priced.any())
+    for i, tier in enumerate(tiers):
+        t = st._tier(tier)
+        t["n_events"] += 1
+        if priced[i]:
+            t["total_pj"] += float(e[i])
+            t["n_priced"] += 1
+
+
+class _PerRequest(ContinuousBatcher):
+    """The packed path's admission, retirement and telemetry as they were
+    before admission groups: one Python pass per request, a free-slot heap
+    per replica, a lane set per serving bucket (a copy of the old rule, the
+    oracle the grouped path is held to)."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.heaps = [list(range(lo, lo + self._span))
+                      for lo in range(0, self._n_slots, self._span)]
+        self.last = len(self.heaps) - 1
+        self.key = [None] * self._n_slots
+        self.buckets = {}
+
+    def _take(self):
+        q, heaps = self.queue, self.heaps
+        n = len(heaps)
+        best, most = 0, -1
+        for k in range(1, n + 1):
+            d = (self.last + k) % n
+            if len(heaps[d]) > most:
+                best, most = d, len(heaps[d])
+        for d in [(best + k) % n for k in range(n)]:
+            n0 = len(q)
+            while q and heaps[d]:
+                yield heapq.heappop(heaps[d]), q.popleft()
+            if n0 > len(q):
+                self.last = d
+            if not q:
+                return
+
+    def _refill_packed(self):
+        if not self.queue or self._n_active == self._n_slots:
+            return
+        lanes, rows, thrs, buds = [], [], [], []
+        now = time.perf_counter()
+        for i, req in self._take():
+            req.t_admit = now
+            if (req.model is not None and req.version is None
+                    and self.registry is not None):
+                req.version = self.registry.route(req.model, req.rid)
+            self._lane_req[i] = req
+            self._occ_mask[i] = True
+            pol = req.policy
+            if pol is None:
+                pol = self._tenant_rung(req.tenant)
+            thr, bud = lane_knobs(pol)
+            lanes.append(i)
+            rows.append(req.prompt)
+            thrs.append(thr)
+            buds.append(bud)
+            key = (req.model, req.version,
+                   None if pol is None else pol.precision)
+            self.key[i] = key
+            self.buckets.setdefault(key, set()).add(i)
+        self._n_active += len(lanes)
+        self.dispatcher.admit_lanes(np.asarray(lanes, np.int64),
+                                    np.asarray(rows, np.float32), thrs, buds)
+
+    def _dispatch_packed(self):
+        default = (self.governor.current if self.governor is not None
+                   else self.default_policy)
+        def_thr = float(np.asarray(default.threshold))
+        def_bud = (int(np.asarray(default.hop_budget))
+                   if default.hop_budget is not None else NO_BUDGET)
+        for key in list(self.buckets):
+            lanes = self.buckets[key]
+            if not lanes:
+                del self.buckets[key]
+                continue
+            model, version, prec = key
+            self.dispatcher.dispatch_packed(
+                lanes, def_thr, def_bud,
+                precision=prec if prec is not None else default.precision,
+                bucket=None if model is None else (model, version))
+        self._inflight = True
+        self._inflight_occ = np.flatnonzero(self._occ_mask)
+
+    def _process_harvest(self):
+        nxt, hops, energy, pend = self.dispatcher.harvest_packed(
+            self._n_slots)
+        self.last_dispatches = pend
+        self._inflight = False
+        occ = self._inflight_occ
+        reqs, retired = [], []
+        now = time.perf_counter()
+        for i in occ.tolist():
+            req = self._lane_req[i]
+            reqs.append(req)
+            req.generated.append(int(nxt[i]))
+            req.hops.append(int(hops[i]))
+            if (int(nxt[i]) == self.eos_id
+                    or len(req.generated) >= req.max_new_tokens):
+                req.done = True
+                req.t_done = now
+                self.stats.note_done(req.tier)
+                self.completed.append(req)
+                self._lane_req[i] = None
+                self._occ_mask[i] = False
+                self._n_active -= 1
+                heapq.heappush(self.heaps[i // self._span], i)
+                self.buckets[self.key[i]].discard(i)
+                self.key[i] = None
+                retired.append(i)
+        if retired:
+            self.dispatcher.retire_lanes(retired)
+        if occ.size:
+            self._tel_buf.append((hops[occ], energy[occ], reqs, occ))
+            self._steps_since_flush += 1
+            if self._steps_since_flush >= self.telemetry_every:
+                self._flush_telemetry()
+
+    def _flush_telemetry(self):
+        buf, self._tel_buf = self._tel_buf, []
+        self._steps_since_flush = 0
+        ledger = self.ledger
+        trans = None
+        if ledger is not None:
+            govs = [g for _, g in ledger.items()]
+            trans = [(g, len(g.transitions)) for g in govs]
+        fleet = []
+        for hops, energy, reqs, lanes in buf:
+            tiers = [r.tier for r in reqs]
+            devices = self.dispatcher.lane_devices(lanes)
+            e = energy
+            if self.governor is not None:
+                fleet.append((e, devices))
+            elif ledger is not None:
+                e = energy.copy()
+                by_tenant = {}
+                for i, r in enumerate(reqs):
+                    by_tenant.setdefault(r.tenant, []).append(i)
+                for tenant, idxs in by_tenant.items():
+                    gov = ledger.governor_for(tenant)
+                    if gov is None:
+                        e[idxs] = np.nan
+                        continue
+                    gov.ingest([(e[idxs], devices[idxs])])
+            _old_update(self.stats, hops, e, tiers)
+            if self.registry is not None:
+                by_version = {}
+                for i, r in enumerate(reqs):
+                    if r.model is not None and r.version is not None:
+                        by_version.setdefault((r.model, r.version),
+                                              []).append(i)
+                for (tenant, version), idxs in by_version.items():
+                    _old_update(self.registry.stats_for(tenant, version),
+                                hops[idxs], e[idxs], [tiers[i] for i in idxs])
+        if self.governor is not None and fleet:
+            self.governor.ingest(fleet)
+        if trans is not None and any(len(g.transitions) != n
+                                     for g, n in trans):
+            lanes, thrs, buds = [], [], []
+            for i in np.flatnonzero(self._occ_mask).tolist():
+                req = self._lane_req[i]
+                rung = (None if req.policy is not None
+                        else self._tenant_rung(req.tenant))
+                if rung is None:
+                    continue
+                thr, bud = lane_knobs(rung)
+                lanes.append(i)
+                thrs.append(thr)
+                buds.append(bud)
+                key = (req.model, req.version, rung.precision)
+                if key != self.key[i]:
+                    self.buckets[self.key[i]].discard(i)
+                    self.key[i] = key
+                    self.buckets.setdefault(key, set()).add(i)
+            if lanes:
+                self.dispatcher.admit_lanes(lanes, None, thrs, buds)
+
+
+CASES = ("two_tenants", "canary", "governor", "ledger", "four_replicas")
+
+
+def _plane_for(case, trained, tmp_path, cls, pipeline):
+    """A fresh packed plane for one case (same seeds every call) and the
+    request maker of its traffic."""
+    ds, rf = trained
+    gc = split(rf, 2)
+    F = ds.x_test.shape[1]
+    base = FogPolicy(threshold=0.7, precision="fp32")
+    devices = serve_devices(1)
+    reg = gov = None
+    if case in ("two_tenants", "canary", "ledger"):
+        reg = ModelRegistry(tmp_path / f"reg-{cls.__name__}-{pipeline}")
+        for name, prec in (("a", "fp32"), ("b", "int8"), ("c", "fp32")):
+            reg.publish(name, ForestPack.from_groves(gc, prec))
+        if case == "canary":
+            reg.publish("a", ForestPack.from_groves(gc, "fp32"), canary=0.5)
+        server = ForestReplicaServer(
+            None, F, backend="fused", registry=reg, seed=0,
+            cache=PackCache(reg, budget_bytes=64 * 2**20))
+    else:
+        server = ForestReplicaServer(gc, F, backend="fused",
+                                     precisions=("fp32", "int8"), seed=0)
+        if case == "four_replicas":
+            devices = devices * 4
+    model = EnergyModel.from_pack(ForestPack.from_groves(gc, "fp32"), F)
+    # about 1.6 hops a request: the base rung breaches and the ladder walks
+    tight = float(np.asarray(model.lane_pj(np.asarray([2]))[0])) * 1e-3 * 0.8
+
+    def mk_gov(budget):
+        return EnergyGovernor(default_ladder(base, model, budget), budget,
+                              model=model, window=16, patience=2,
+                              cooldown=10_000, warmup=4)
+
+    if case == "governor":
+        gov = mk_gov(tight)
+    elif case == "ledger":
+        # tenant a walks its ladder mid-run (its lanes are restamped), b
+        # holds, c is billed by no governor (NaN energy)
+        gov = TenantLedger()
+        gov.add("a", mk_gov(tight))
+        gov.add("b", mk_gov(tight * 100))
+    n_slots = 32 if case == "four_replicas" else N_SLOTS
+    b = cls(n_slots, None, server.prefill, eos_id=-1, default_policy=base,
+            governor=gov, dispatcher=DeviceDispatcher(
+                server.packed_factory, devices),
+            registry=reg, pipeline=pipeline,
+            telemetry_every=1 if gov is not None else 3)
+    pols = {"fp32": FogPolicy(threshold=0.55, precision="fp32"),
+            "int8": FogPolicy(threshold=0.6, precision="int8")}
+
+    def make(rid):
+        tier = ("gold", "std", "bulk")[rid % 3]
+        x = ds.x_test[rid % len(ds.x_test)]
+        if reg is None:
+            pol = (pols["int8"] if rid % 3 == 0 else None)
+            return Request(rid=rid, prompt=x, max_new_tokens=1 + rid % 2,
+                           policy=pol, tier=tier)
+        tenant = ("a", "b", "c")[rid % 3] if case == "ledger" else (
+            "a" if case == "canary" else ("a", "b")[rid % 2])
+        pol = None
+        if case != "ledger" or tenant == "c":
+            pol = pols["int8" if tenant == "b" else "fp32"]
+        return Request(rid=rid, prompt=x, max_new_tokens=1 + rid % 2,
+                       policy=pol, model=tenant, tier=tier)
+
+    return b, make, reg, gov
+
+
+def _serve_recorded(case, trained, tmp_path, cls, pipeline):
+    """Serve ``case``'s schedule and record every admission burst, knob
+    restamp and dispatch the plane saw, and each harvest's dispatches."""
+    b, make, reg, gov = _plane_for(case, trained, tmp_path, cls, pipeline)
+    disp = b.dispatcher
+    seen = {"bursts": [], "restamps": [], "dispatches": [], "harvests": []}
+    admit, dispatch = disp.admit_lanes, disp.dispatch_packed
+
+    def admit_noting(lanes, rows, thr, bud):
+        lanes = [int(i) for i in lanes]
+        # the default sentinel (NaN) written as a label, so it compares
+        knobs = [("default" if t != t else t, u) for t, u in zip(
+            np.asarray(thr, np.float32).tolist(),
+            np.asarray(bud, np.int32).tolist())]
+        if rows is None:
+            seen["restamps"].append(sorted(zip(lanes, knobs)))
+        else:
+            seen["bursts"].append([(b.slots[i].request.rid, i, k)
+                                   for i, k in zip(lanes, knobs)])
+        return admit(lanes, rows, thr, bud)
+
+    def dispatch_noting(lanes, *a, **kw):
+        seen["dispatches"].append((sorted(int(i) for i in lanes), a,
+                                   sorted(kw.items())))
+        return dispatch(lanes, *a, **kw)
+
+    disp.admit_lanes, disp.dispatch_packed = admit_noting, dispatch_noting
+    last = b.last_dispatches
+
+    def step():
+        nonlocal last
+        n0 = len(b.completed)
+        b.step()
+        if b.last_dispatches is not last:
+            last = b.last_dispatches
+            seen["harvests"].append((n0, len(b.completed), last))
+
+    wave = 12 if case == "four_replicas" else 7
+    rid = 0
+    for w in range(8):
+        for _ in range(wave + w % 3):
+            b.submit(make(rid))
+            rid += 1
+        for _ in range(1 + w % 2):
+            step()
+    while b.queue or b.active:
+        step()
+    n0 = len(b.completed)
+    b.flush()
+    if b.last_dispatches is not last:
+        seen["harvests"].append((n0, len(b.completed), b.last_dispatches))
+    return b, seen, reg, gov
+
+
+def _stats(st):
+    return ((st.total_hops, st.n_events, st.n_priced, st.n_offered,
+             st.n_shed, st.has_energy),
+            {k: (t["n_done"], t["n_shed"], t["n_events"], t["n_priced"])
+             for k, t in st.tiers.items()},
+            st.total_pj, {k: t["total_pj"] for k, t in st.tiers.items()})
+
+
+def _assert_stats_match(new, old):
+    counts_n, tiers_n, pj_n, tier_pj_n = _stats(new)
+    counts_o, tiers_o, pj_o, tier_pj_o = _stats(old)
+    assert counts_n == counts_o
+    assert tiers_n == tiers_o
+    assert pj_n == pytest.approx(pj_o, rel=1e-12)
+    assert tier_pj_n.keys() == tier_pj_o.keys()
+    for k in tier_pj_n:
+        assert tier_pj_n[k] == pytest.approx(tier_pj_o[k], rel=1e-12)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_admission_groups_serve_as_the_per_request_rule(trained, tmp_path,
+                                                        case):
+    """The grouped packed path, pipelined, against the per-request rule it
+    replaced, under one seed and schedule: the same lanes and knobs for
+    every request in the same bursts, one admission stamp per burst, the
+    same dispatches in the same order (so the same start groves), the
+    same (generated, hops) and versions, completions appended in ascending
+    lane order per harvest, and the same telemetry (energy to 1e-12) in
+    the fleet stats, the registry's per-version stats and the governors;
+    the synchronous grouped path ends with the same telemetry."""
+    new, seen, reg, gov = _serve_recorded(case, trained, tmp_path,
+                                          ContinuousBatcher, True)
+    old, seen_old, reg_old, gov_old = _serve_recorded(
+        case, trained, tmp_path, _PerRequest, True)
+    sync, _, reg_sync, _ = _serve_recorded(case, trained, tmp_path,
+                                           ContinuousBatcher, False)
+    for key in ("bursts", "restamps", "dispatches"):
+        assert seen[key] == seen_old[key], key
+    by_rid = {r.rid: r for r in new.completed}
+    for burst in seen["bursts"]:
+        assert len({by_rid[rid].t_admit for rid, _, _ in burst}) == 1
+    assert ([(r.rid, r.version, r.generated, r.hops) for r in new.completed]
+            == [(r.rid, r.version, r.generated, r.hops)
+                for r in old.completed])
+    assert len(new.completed) == new.stats.n_offered
+    lane_of = {rid: i for burst in seen["bursts"] for rid, i, _ in burst}
+    for lo, hi, pend in seen["harvests"]:
+        done = [lane_of[r.rid] for r in new.completed[lo:hi]]
+        assert done == sorted(done)
+        assert set(done) <= {int(i) for p in pend for i in p.lanes}
+    _assert_stats_match(new.stats, old.stats)
+    _assert_stats_match(new.stats, sync.stats)
+    assert new.stats.tier_summary() == sync.stats.tier_summary()
+    if reg is not None:
+        for key in {(r.model, r.version) for r in new.completed}:
+            _assert_stats_match(reg.stats_for(*key), reg_old.stats_for(*key))
+            _assert_stats_match(reg.stats_for(*key),
+                                reg_sync.stats_for(*key))
+            assert (reg.stats_for(*key).tier_summary()
+                    == reg_sync.stats_for(*key).tier_summary())
+        assert all(r.version == reg.route(r.model, r.rid)
+                   for r in new.completed)
+    if case in ("governor", "ledger"):
+        pairs = ([(gov, gov_old)] if case == "governor" else
+                 [(gov.governor_for(t), gov_old.governor_for(t))
+                  for t in ("a", "b")])
+        for g, g_old in pairs:
+            assert g.transitions == g_old.transitions
+            assert g.rung == g_old.rung
+            assert g.rolling_nj == pytest.approx(g_old.rolling_nj,
+                                                 rel=1e-12)
+    # the case's mechanism ran
+    if case == "governor":
+        assert gov.transitions
+    elif case == "ledger":
+        assert gov.governor_for("a").transitions and seen["restamps"]
+        assert new.stats.n_priced < new.stats.n_events    # tenant c: NaN
+    elif case == "canary":
+        assert {r.version for r in new.completed} == {1, 2}
+    elif case == "four_replicas":
+        assert any(len({i // 8 for _, i, _ in burst}) > 1
+                   for burst in seen["bursts"])
+    else:
+        assert any(len({p.precision for p in pend}) == 2
+                   for _, _, pend in seen["harvests"])

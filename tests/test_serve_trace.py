@@ -98,7 +98,8 @@ def test_trace_off_builds_no_span(trained, monkeypatch):
     assert b._on_gc not in gc.callbacks
     assert b.counts == dict.fromkeys(
         ("lane_hops_walked", "lane_hops_served", "host_copies",
-         "host_copies_prefetched", "routed_0"), 0)
+         "host_copies_prefetched", "routed_0", "policy_checks",
+         "admit_groups"), 0)
     assert b.phase_ns["gc"] == 0
     # the same run with tracing on does build them (the stand-in works)
     b.trace = True
@@ -252,6 +253,50 @@ def test_admission_stamp_lies_between_submit_and_done(trained, path):
     assert len(b.completed) > len(b.slots)   # some requests queued
     for r in b.completed:
         assert r.t_submit <= r.t_admit <= r.t_done
+
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_policy_check_and_admit_group_counters(trained, trace):
+    """Two tenants sharing one policy object each: with ``trace`` on,
+    ``policy_checks`` reads 2 (one validation per distinct policy) and
+    ``admit_groups`` one per tenant present in each refill burst, at most
+    2 a burst; with it off both stay 0."""
+    b = _plane(trained, 8)
+    x = trained[0].x_test
+    pols = [FogPolicy(threshold=0.7, precision=p) for p in ("fp32", "int8")]
+    tenants_per_burst = []
+    admit = b.dispatcher.admit_lanes
+
+    def noting(lanes, rows, thr, bud):
+        if rows is not None:
+            tenants_per_burst.append(
+                len({b.slots[i].request.rid % 2 for i in lanes}))
+        return admit(lanes, rows, thr, bud)
+
+    b.dispatcher.admit_lanes = noting
+    b.trace = trace
+    try:
+        rid = 0
+        for wave in (12, 3, 7):
+            for _ in range(wave):
+                b.submit(Request(rid=rid, prompt=x[rid % len(x)],
+                                 max_new_tokens=1, policy=pols[rid % 2]))
+                rid += 1
+            b.step()
+        while b.queue or b.active:
+            b.step()
+        b.flush()
+    finally:
+        b.trace = False
+    assert len(b.completed) == 22
+    assert len(tenants_per_burst) >= 3
+    if not trace:
+        assert b.counts["policy_checks"] == b.counts["admit_groups"] == 0
+        return
+    assert b.counts["policy_checks"] == 2
+    assert b.counts["admit_groups"] == sum(tenants_per_burst)
+    assert b.counts["admit_groups"] <= 2 * len(tenants_per_burst)
 
 
 def _recount_walked(h, block):
