@@ -4,7 +4,7 @@
 
 Drives the REAL multi-tenant stack — a ModelRegistry of versioned forest
 artifacts, a VMEM-budgeted PackCache, bucket-aware ForestReplicaServer
-replicas behind a DeviceDispatcher behind a ContinuousBatcher, and a
+packed replicas behind a DeviceDispatcher behind a ContinuousBatcher, and a
 TenantLedger of per-tenant EnergyGovernors — under Zipf-skewed open-loop
 tenant traffic with mixed QoS tiers and mixed precisions, and emits
 ``BENCH_registry.json``:
@@ -115,7 +115,7 @@ def bench(smoke: bool, seed: int = 0, workdir: str | None = None) -> dict:
     cache = PackCache(registry, budget_bytes=budget_bytes)
     server = ForestReplicaServer(None, n_features, backend="fused",
                                  registry=registry, cache=cache, seed=seed)
-    dispatcher = DeviceDispatcher(server.factory, jax.devices()[:1])
+    dispatcher = DeviceDispatcher(server.packed_factory, jax.devices()[:1])
 
     tenant_names = [t for t, _ in TENANTS]
     tenant_share = np.asarray([s for _, s in TENANTS])
@@ -264,6 +264,8 @@ def bench(smoke: bool, seed: int = 0, workdir: str | None = None) -> dict:
         for r in b.completed[n_done_seen:]:
             done_vtime[r.rid] = vnow
         n_done_seen = len(b.completed)
+
+    b.flush()               # replay any telemetry the loop left buffered
 
     # -- metrics ----------------------------------------------------------
     completed = {r.rid: r for r in b.completed}

@@ -4,8 +4,11 @@
 
 Demonstrates the continuous-batching scheduler driving decode_step_fog
 with MIXED-QOS traffic: every request carries its own FogPolicy (threshold
-+ hop budget), the batcher assembles them into per-lane vectors, and one
-compiled decode step serves the whole batch.  Per-request grove usage
++ hop budget), the host replica resolves them into per-lane vectors, and
+one compiled decode step serves the whole batch.  The decode step runs
+behind the serving plane's replica protocol
+(``repro.serve.dispatch.host_replica_factory``), the same dispatch /
+harvest cycle the forest's replicas serve through.  Per-request grove usage
 (hops) is the LM analogue of the paper's energy meter — easy tokens exit
 after 1 grove, hard tokens use the full stack, and budget-capped requests
 never exceed their energy contract.
@@ -21,6 +24,7 @@ from repro.core.policy import FogPolicy
 from repro.data.lm_data import DataConfig, batch_at_step
 from repro.models import transformer as T
 from repro.models.fog_exit import decode_step_fog, grove_boundaries
+from repro.serve.dispatch import DeviceDispatcher, host_replica_factory
 from repro.serve.scheduler import ContinuousBatcher, Request
 
 cfg = smoke_config("tinyllama-1.1b").scaled(n_layers=4, fog_groups=4)
@@ -67,7 +71,7 @@ def _splice_cache(batch_leaf, row_leaf, slot):
 def decode_fn(tokens, lengths, policy):
     global caches
     # the batch shares one position counter in this demo: use max length;
-    # policy carries the per-lane thresholds/budgets the batcher assembled
+    # policy carries the per-lane thresholds/budgets of the lanes' requests
     length = jnp.int32(int(lengths.max()))
     logits, caches, hops = decode_step_fog(params, cfg, tokens, caches,
                                            length, policy)
@@ -81,8 +85,12 @@ TIERS = {
     "standard": FogPolicy(threshold=THRESH),
     "budget": FogPolicy(threshold=0.05, hop_budget=2),
 }
-batcher = ContinuousBatcher(N_SLOTS, decode_fn, prefill_fn, eos_id=-1,
-                            default_policy=TIERS["standard"])
+dispatcher = DeviceDispatcher(
+    host_replica_factory(decode_fn, prefill_fn, policy=TIERS["standard"]),
+    jax.devices()[:1])
+batcher = ContinuousBatcher(N_SLOTS, None, prefill_fn, eos_id=-1,
+                            default_policy=TIERS["standard"],
+                            dispatcher=dispatcher)
 rng = np.random.default_rng(0)
 dcfg = DataConfig(cfg.vocab_size, 32, 8, seed=7)
 tier_of = {}

@@ -7,12 +7,13 @@
 production mesh path goes through serve/decode.make_serve_step (the same
 functions the dry-run lowers).
 
-``--devices N`` serves data-parallel: the params are replicated per device,
-each device owns the KV caches for a fixed span of slots, the batcher's
-router places each refill burst on the replica with the most free slots,
-and each step fans out through a
-:class:`~repro.serve.dispatch.DeviceDispatcher` (on CPU, export
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first).
+Each device holds one replica (:class:`~repro.serve.dispatch.HostReplica`):
+its own params copy and the KV caches for a fixed span of slots.
+``--devices N`` serves data-parallel over N of them: the batcher's router
+places each refill burst on the replica with the most free slots, and each
+step fans out through a :class:`~repro.serve.dispatch.DeviceDispatcher`
+(on CPU, export ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+first).
 ``--max-queue`` / ``--shed-policy`` expose the admission-control knobs.
 """
 from __future__ import annotations
@@ -29,7 +30,7 @@ from repro.core.policy import BACKENDS, PRECISIONS, FogPolicy
 from repro.data.lm_data import DataConfig, batch_at_step
 from repro.models import transformer as T
 from repro.models.fog_exit import decode_step_fog, grove_boundaries, lm_hop_energy
-from repro.serve.governor import EnergyGovernor
+from repro.serve.governor import EnergyGovernor, lane_pricer
 from repro.serve.scheduler import ContinuousBatcher, Request
 
 
@@ -98,10 +99,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "of the default double-buffered pipeline (host "
                          "bookkeeping overlapped with device compute)")
     ap.add_argument("--telemetry-every", type=int, default=None,
-                    help="registry mode: replay buffered telemetry every "
-                         "k harvests instead of per step (default 8; the "
-                         "LM decode path accounts inline and only "
-                         "accepts 1)")
+                    help="replay buffered telemetry every k harvests "
+                         "instead of per step (default 8 with --registry, "
+                         "else 1)")
     ap.add_argument("--seed", type=int, default=0)
     return ap
 
@@ -200,9 +200,9 @@ def _serve_registry(args) -> None:
         print(f"[serve] ledger\n{ledger.summary()}")
 
 
-def main() -> None:
+def main(argv=None) -> None:
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     from repro.launch.compile_cache import use_repo_compile_cache
     use_repo_compile_cache()
     if args.registry is not None:
@@ -219,9 +219,16 @@ def main() -> None:
     if args.devices > 1 and args.slots % args.devices:
         ap.error(f"--slots {args.slots} must divide evenly over "
                  f"--devices {args.devices} (fixed per-device spans)")
-    if args.telemetry_every is not None and args.telemetry_every != 1:
-        ap.error("--telemetry-every > 1 needs the packed registry plane "
-                 "(--registry DIR); the LM decode path accounts inline")
+    _serve_lm(args)
+
+
+def _serve_lm(args) -> ContinuousBatcher:
+    """LM serving: one :class:`~repro.serve.dispatch.HostReplica` per
+    device, each with its own params copy and the KV caches for its span
+    of slots (lanes never migrate, so a prefill touches exactly one
+    device's cache).  Returns the drained batcher."""
+    from repro.launch.mesh import serve_devices
+    from repro.serve.dispatch import DeviceDispatcher, HostReplica
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     if cfg.frontend:
@@ -233,76 +240,6 @@ def main() -> None:
                                hop_budget=args.hop_budget,
                                backend=args.fog_backend,
                                precision=args.fog_precision)
-
-    dispatcher = None
-    if args.devices > 1:
-        from repro.launch.mesh import serve_devices
-        from repro.serve.dispatch import DeviceDispatcher
-
-        devices = serve_devices(args.devices)
-        # one replica per device: its own committed params copy and the KV
-        # caches for its span of slots — lanes never migrate, so a prefill
-        # touches exactly one device's cache
-        states: dict[int, dict] = {}
-
-        def factory(index, device, span):
-            params_d = jax.device_put(params, device)
-            caches_d = jax.device_put(
-                T.cache_init(cfg, span, args.max_seq, jnp.float32), device)
-            states[index] = {"caches": caches_d, "device": device}
-
-            def decode(tokens, lengths, policy):
-                length = jnp.int32(int(np.asarray(lengths).max()))
-                tk = jax.device_put(jnp.asarray(tokens), device)
-                if args.fog:
-                    logits, states[index]["caches"], hops = decode_step_fog(
-                        params_d, cfg, tk, states[index]["caches"], length,
-                        policy)
-                    return logits, hops
-                logits, states[index]["caches"] = T.decode_step(
-                    params_d, cfg, tk, states[index]["caches"], length)
-                return logits, None
-
-            return decode
-
-        dispatcher = DeviceDispatcher(factory, devices)
-        span = args.slots // args.devices
-
-        def prefill_fn(slot: int, prompt: np.ndarray) -> int:
-            _, c = T.prefill(params, cfg,
-                             tokens=jnp.asarray(prompt)[None, :],
-                             max_seq=args.max_seq)
-            st = states[slot // span]
-            st["caches"] = jax.tree.map(
-                lambda b, r: _splice_row(b, r, slot % span, span),
-                st["caches"], jax.device_put(c, st["device"]))
-            return len(prompt)
-
-        decode_fn = None
-    else:
-        caches = T.cache_init(cfg, args.slots, args.max_seq, jnp.float32)
-        state = {"caches": caches}
-
-        def prefill_fn(slot: int, prompt: np.ndarray) -> int:
-            _, c = T.prefill(params, cfg,
-                             tokens=jnp.asarray(prompt)[None, :],
-                             max_seq=args.max_seq)
-            state["caches"] = jax.tree.map(
-                lambda b, r: _splice_row(b, r, slot, args.slots),
-                state["caches"], c)
-            return len(prompt)
-
-        def decode_fn(tokens, lengths, policy):
-            # policy: the batcher's per-lane assembly of the slots' QoS
-            # contracts
-            length = jnp.int32(int(np.asarray(lengths).max()))
-            if args.fog:
-                logits, state["caches"], hops = decode_step_fog(
-                    params, cfg, tokens, state["caches"], length, policy)
-                return logits, hops
-            logits, state["caches"] = T.decode_step(params, cfg, tokens,
-                                                    state["caches"], length)
-            return logits, None
 
     governor = None
     if args.energy_budget_nj is not None:
@@ -323,11 +260,51 @@ def main() -> None:
                                          hop_budget=cap)]
         governor = EnergyGovernor(ladder, args.energy_budget_nj,
                                   model=model, window=max(args.slots * 4, 16))
-    batcher = ContinuousBatcher(args.slots, decode_fn, prefill_fn, eos_id=-1,
+    price = lane_pricer(governor)
+    devices = serve_devices(args.devices)
+    span = args.slots // args.devices
+    n_groves = len(grove_boundaries(cfg))
+    states: dict[int, dict] = {}
+
+    def prefill_fn(slot: int, prompt: np.ndarray) -> int:
+        _, c = T.prefill(params, cfg,
+                         tokens=jnp.asarray(prompt)[None, :],
+                         max_seq=args.max_seq)
+        st = states[slot // span]
+        st["caches"] = jax.tree.map(
+            lambda b, r: _splice_row(b, r, slot % span, span),
+            st["caches"], jax.device_put(c, st["device"]))
+        return len(prompt)
+
+    def factory(index, device, span):
+        params_d = jax.device_put(params, device)
+        st = states[index] = {"device": device, "caches": jax.device_put(
+            T.cache_init(cfg, span, args.max_seq, jnp.float32), device)}
+
+        def decode(tokens, lengths, policy):
+            # the span shares one position counter: its longest lane
+            length = jnp.int32(int(np.asarray(lengths).max()))
+            if args.fog:
+                logits, st["caches"], hops = decode_step_fog(
+                    params_d, cfg, tokens, st["caches"], length, policy)
+                return logits, hops
+            logits, st["caches"] = T.decode_step(params_d, cfg, tokens,
+                                                 st["caches"], length)
+            return logits, None     # dense: every grove, every token
+
+        return HostReplica(decode, prefill_fn, span, index=index,
+                           device=device, price=price,
+                           policy=default_policy, n_groves=n_groves)
+
+    batcher = ContinuousBatcher(args.slots, None, prefill_fn, eos_id=-1,
                                 default_policy=default_policy,
-                                governor=governor, dispatcher=dispatcher,
+                                governor=governor,
+                                dispatcher=DeviceDispatcher(factory, devices),
                                 max_queue=args.max_queue,
-                                shed_policy=args.shed_policy)
+                                shed_policy=args.shed_policy,
+                                telemetry_every=(args.telemetry_every
+                                                 if args.telemetry_every
+                                                 is not None else 1))
     dcfg = DataConfig(cfg.vocab_size, 32, 8, seed=args.seed + 7)
     admitted = 0
     for rid in range(args.requests):
@@ -346,14 +323,14 @@ def main() -> None:
               f"{batcher.stats.n_offered} "
               f"({100 * batcher.stats.shed_rate:.1f}%)")
     if args.fog:
-        g = len(grove_boundaries(cfg))
         for r in sorted(done, key=lambda r: r.rid):
             h = np.asarray(r.hops, np.float64)
             print(f"  req {r.rid}: groves/token {h.mean():.2f} "
-                  f"(flops frac {h.mean() / g:.2f})")
-        print(f"[serve] fleet {batcher.stats.summary(g)}")
+                  f"(flops frac {h.mean() / n_groves:.2f})")
+        print(f"[serve] fleet {batcher.stats.summary(n_groves)}")
         if governor is not None:
             print(f"[serve] governor {governor.summary()}")
+    return batcher
 
 
 if __name__ == "__main__":

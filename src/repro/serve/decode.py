@@ -57,9 +57,9 @@ def make_serve_step(cfg: ArchConfig, mesh, shape: str, *, fog: bool = False,
     logit_m = "model" if cfg.vocab_size % mesh.shape["model"] == 0 else None
     if fog:
         def step(params, cache, token, length, thresh, budget, embeds=None):
-            lane_policy = policy.replace(threshold=thresh, hop_budget=budget)
+            lanes = policy.replace(threshold=thresh, hop_budget=budget)
             logits, cache, hops = decode_step_fog(
-                params, cfg, token, cache, length, lane_policy,
+                params, cfg, token, cache, length, lanes,
                 embeds=embeds, backend=gate_backend)
             return logits, cache, hops
         out_specs = (P(bdp, logit_m), c_specs, P(bdp))

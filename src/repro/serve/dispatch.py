@@ -11,19 +11,17 @@ refill burst the slots of one replica, the one with the most free slots,
 in turn (:meth:`~repro.serve.scheduler.ContinuousBatcher._route`), so a
 step's dispatch touches one replica while that replica has room.
 
-Dispatch is asynchronous: each step the
+Every replica speaks one protocol, the packed one: slot state stays with
+the replica, updated by :meth:`DeviceDispatcher.admit_lanes` /
+:meth:`~DeviceDispatcher.retire_lanes`, and each step the
 :class:`~repro.serve.scheduler.ContinuousBatcher` calls
-:meth:`DeviceDispatcher.dispatch` once per precision group; the dispatcher
-slices the group's span inputs, enqueues one decode call per (device,
-precision) on that device's dispatch queue, and returns WITHOUT blocking —
-JAX's async dispatch lets every replica compute concurrently.
-:meth:`harvest` drains the queues with a single deferred
-``jax.block_until_ready`` over everything in flight, then scatters the
-per-span outputs back into full ``[n_slots]`` arrays.  A precision group
-that touches a span dispatches the FULL span (fixed shape, no recompile
-churn — the per-lane threshold/budget vectors are traced inputs) and only
-the group's lanes are harvested from it, mirroring the single-device
-bucketed dispatch in ``scheduler.step``.
+:meth:`DeviceDispatcher.dispatch_packed` once per serving bucket.  The
+dispatcher enqueues one call per intersecting device, which returns
+``(next, hops, energy)`` for its whole span WITHOUT blocking (JAX's async
+dispatch lets every replica compute concurrently) and starts their copies
+to the host; :meth:`~DeviceDispatcher.harvest_packed` waits once for
+everything in flight and scatters each bucket's lanes into full
+``[n_slots]`` host arrays.
 
 Replication is plain device placement: :func:`replicate` ``device_put``\\ s
 a pytree (e.g. a :class:`~repro.forest.pack.ForestPack`) onto each serve
@@ -32,14 +30,18 @@ device.  On CPU-only hosts (CI), force a mesh with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — see
 :func:`repro.launch.mesh.serve_devices`.
 
-:class:`ForestReplicaServer` is the canonical factory for the paper's
-workload: forest classification serving, one pending feature row per slot,
-a ForestPack replica (per precision) per device.
+Two replica kinds: :meth:`ForestReplicaServer.packed_factory`, the paper's
+workload (resident feature rows and knobs, one fused launch per call), and
+:class:`HostReplica` (:func:`host_replica_factory`), which runs a host
+``decode_fn(tokens, lengths, policy)`` — an LM decode step, a test mock —
+behind the same protocol.
 """
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import time
+import types
 from contextlib import nullcontext
 from functools import partial
 from typing import Callable, Sequence
@@ -48,7 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.policy import NO_BUDGET, FogPolicy, LanePolicies
+from repro.core.policy import (BUDGET_DEFAULT, NO_BUDGET, THRESH_DEFAULT,
+                               FogPolicy, LanePolicies)
 from repro.kernels.fused_fog import walked_lane_hops
 from repro.kernels.tree_traverse import lane_block
 
@@ -75,35 +78,38 @@ def replicate(tree, devices: Sequence) -> list:
 
 @dataclasses.dataclass
 class Pending:
-    """One in-flight decode call on one device's dispatch queue."""
+    """One in-flight packed call on one device's dispatch queue: argmax
+    labels, hops and per-lane modeled pJ over the replica's whole span."""
 
     device: int                  # dispatcher device index
     precision: str | None        # the precision group this call serves
     lanes: np.ndarray            # global lane indices to harvest from it
     local: np.ndarray            # those lanes' offsets inside the span
-    logits: object               # [span, C] device array (not yet ready)
-    hops: object                 # [span] device array | None
+    nxt: object                  # [span] int32 device array
+    hops: object                 # [span] device array
+    energy: object               # [span] float32 device array
     # the (model, version) registry bucket this call serves (None = the
     # single built-in model)
     bucket: tuple | None = None
-    # packed-protocol outputs (the resident fast path): argmax labels and
-    # per-lane modeled pJ computed inside the dispatch, so harvest never
-    # downloads [span, C] logits or re-prices hops on the host
-    nxt: object | None = None    # [span] int32 device array
-    energy: object | None = None  # [span] float32 device array
     # outputs whose device-to-host copy was started at enqueue
     prefetched: int = 0
 
 
 class DeviceDispatcher:
-    """Fan one continuous batch out over per-device decode replicas.
+    """Fan one continuous batch out over per-device replicas.
 
-    decode_factory(index, device, span) -> decode_fn(tokens [span],
-        lengths [span], policy with [span] lane vectors) -> (logits, hops)
-        The factory builds ONE replica: it places that replica's state on
-        ``device`` and must return without blocking on results (outputs are
-        harvested later).  ``tokens``/``lengths`` arrive as numpy slices;
-        the replica is responsible for ``jax.device_put`` onto its device.
+    decode_factory(index, device, span) -> replica
+        The factory builds ONE replica of the packed protocol, placing its
+        state on ``device``.  A replica has ``admit_many(local, rows, thr,
+        bud)`` and ``retire_many(local)`` (staged slot updates; ``rows``
+        None re-stamps knobs only), ``splice()`` (apply what is staged),
+        ``enqueue(def_thresh, def_budget, precision, bucket=, local=)``
+        (one call over the whole span, returning ``(next, hops, energy)``
+        device arrays without blocking; ``local`` are the lanes harvested
+        from it), ``block_b`` / ``compact`` (the kernel walk the
+        lane-hop counters follow) and ``bucketed`` (whether it serves
+        registry (model, version) buckets).  See
+        :meth:`ForestReplicaServer.packed_factory` and :class:`HostReplica`.
     devices: the serve devices (default: every local device — force >1 on
         CPU via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 
@@ -113,9 +119,9 @@ class DeviceDispatcher:
     routes each refill burst to one of them; the dispatcher calls only the
     replicas whose spans a dispatch's lanes touch.
 
-    ``phase_ns`` accumulates the packed cycle's host sub-phases (``PHASES``;
-    a batcher shares its own dict here) and ``trace`` (set by the batcher)
-    wraps each in a ``serve.dispatch.*`` / ``serve.harvest.*`` span.
+    ``phase_ns`` accumulates the host sub-phases (``PHASES``; a batcher
+    shares its own dict here) and ``trace`` (set by the batcher) wraps each
+    in a ``serve.dispatch.*`` / ``serve.harvest.*`` span.
     """
 
     def __init__(self, decode_factory: Callable, devices: Sequence | None = None):
@@ -126,7 +132,7 @@ class DeviceDispatcher:
         self.devices = list(devices)
         self.decode_factory = decode_factory
         self.span: int | None = None
-        self._fns: list[Callable] | None = None
+        self._fns: list | None = None
         # per-device dispatch queues, drained at harvest time
         self._queues: list[list[Pending]] = [[] for _ in self.devices]
         self.phase_ns = dict.fromkeys(PHASES, 0)
@@ -153,25 +159,14 @@ class DeviceDispatcher:
         self.span = n_slots // self.n_devices
         self._fns = [self.decode_factory(i, d, self.span)
                      for i, d in enumerate(self.devices)]
-        from repro.serve.scheduler import _takes_bucket
-        self._fn_buckets = [_takes_bucket(fn) for fn in self._fns]
 
     @property
     def replicas(self) -> list:
-        """The bound per-device decode callables, in device order."""
+        """The bound per-device replicas, in device order."""
         if self._fns is None:
             raise ValueError("dispatcher not bound; construct the batcher "
                              "with it first")
         return list(self._fns)
-
-    @property
-    def bucket_aware(self) -> bool:
-        """Can the replicas route (model, version) buckets?  True only
-        when EVERY replica decode fn takes a ``bucket`` keyword."""
-        if self._fns is None:
-            raise ValueError("dispatcher not bound; construct the batcher "
-                             "(or call bind) first")
-        return all(self._fn_buckets)
 
     def device_of(self, lane: int) -> int:
         """Which device serves a global lane index."""
@@ -185,93 +180,29 @@ class DeviceDispatcher:
         return np.asarray(lanes, np.int64) // self.span
 
     # -- the dispatch/harvest cycle ---------------------------------------
-    def dispatch(self, tokens: np.ndarray, lengths: np.ndarray,
-                 policy: FogPolicy, lanes,
-                 bucket: tuple | None = None) -> list[Pending]:
-        """Enqueue one bucket's lanes, without blocking.
-
-        ``policy`` carries the group's static knobs and the FULL-batch
-        per-lane vectors; ``lanes`` are the global lane indices belonging
-        to this group; ``bucket`` is the (model, version) registry bucket
-        (None = the single built-in model).  Every device whose span
-        intersects ``lanes`` gets one decode call over its whole span.
-        """
-        if self._fns is None:
-            self.bind(len(tokens))
-        lanes = np.asarray(lanes, np.int64)
-        thr = np.asarray(policy.threshold)
-        bud = (np.asarray(policy.hop_budget)
-               if policy.hop_budget is not None else None)
-        out = []
-        for d in np.unique(lanes // self.span):
-            d = int(d)
-            lo, hi = d * self.span, (d + 1) * self.span
-            sl = slice(lo, hi)
-            span_pol = policy.replace(
-                threshold=thr[sl] if thr.ndim else policy.threshold,
-                hop_budget=(bud[sl] if bud is not None and bud.ndim
-                            else policy.hop_budget))
-            mine = lanes[(lanes >= lo) & (lanes < hi)]
-            if self._fn_buckets[d]:
-                logits, hops = self._fns[d](tokens[sl], lengths[sl],
-                                            span_pol, bucket=bucket)
-            elif bucket is not None:
-                raise ValueError(
-                    f"device {d}'s decode replica is not bucket-aware "
-                    "(no bucket= parameter) but the batch carries "
-                    f"registry bucket {bucket!r}")
-            else:
-                logits, hops = self._fns[d](tokens[sl], lengths[sl],
-                                            span_pol)
-            p = Pending(device=d, precision=policy.precision, lanes=mine,
-                        local=mine - lo, logits=logits, hops=hops,
-                        bucket=bucket)
-            self._queues[d].append(p)
-            out.append(p)
-        return out
-
-    # -- the packed (device-resident) cycle -------------------------------
-    @property
-    def packed(self) -> bool:
-        """True when every replica speaks the packed protocol: resident
-        slot state updated via :meth:`admit_lane` / :meth:`retire_lane`
-        splices, dispatches that take only the step's default knobs, and
-        ``(next, hops, energy)`` outputs (see
-        :meth:`ForestReplicaServer.packed_factory`)."""
-        if self._fns is None:
-            raise ValueError("dispatcher not bound; construct the batcher "
-                             "(or call bind) first")
-        return all(getattr(fn, "packed", False) for fn in self._fns)
-
-    def admit_lane(self, lane: int, row, thr: float, bud: int) -> None:
-        """Stage one lane's feature row + resolved policy knobs onto its
-        replica (applied as a donated device splice at the next dispatch).
-        ``row=None`` re-stamps the policy knobs only (rung re-stamps after
-        a deferred-telemetry flush)."""
-        self._fns[lane // self.span].admit(lane % self.span, row, thr, bud)
-
     def admit_lanes(self, lanes, rows, thr, bud) -> None:
-        """Bulk :meth:`admit_lane`: one vectorized staging write per
-        intersecting replica instead of a Python call per lane.  ``rows``
-        is ``[k, n_features]`` aligned with ``lanes`` (or None for a
-        knob-only re-stamp); ``thr`` / ``bud`` are ``[k]``."""
+        """Stage lanes' prompts and resolved policy knobs onto their
+        replicas (applied at the next dispatch): one staging write per
+        intersecting replica.  ``rows`` holds one prompt per lane, in lane
+        order, as the requests gave it (None re-stamps the knobs only: rung
+        re-stamps after a deferred-telemetry flush); ``thr`` / ``bud`` are
+        ``[k]``."""
         lanes = np.asarray(lanes, np.int64)
         thr = np.asarray(thr, np.float32)
         bud = np.asarray(bud, np.int32)
         devs = lanes // self.span
-        for d in np.unique(devs):
+        for d in np.unique(devs).tolist():
             m = devs == d
-            self._fns[int(d)].admit_many(
-                lanes[m] - int(d) * self.span,
-                None if rows is None else rows[m], thr[m], bud[m])
-
-    def retire_lane(self, lane: int) -> None:
-        """Stage one lane DEAD on its replica (freed slot: exits on hop 1
-        until re-admitted; an admit in the same step overrides it)."""
-        self._fns[lane // self.span].retire(lane % self.span)
+            part = rows
+            if rows is not None and not m.all():     # a spilled burst
+                part = list(map(rows.__getitem__, np.flatnonzero(m).tolist()))
+            self._fns[d].admit_many(lanes[m] - d * self.span, part, thr[m],
+                                    bud[m])
 
     def retire_lanes(self, lanes) -> None:
-        """Bulk :meth:`retire_lane` (one staging write per replica)."""
+        """Stage lanes DEAD on their replicas (freed slots: a forest lane
+        exits on hop 1 until re-admitted; an admit in the same step
+        overrides it), one staging write per replica."""
         lanes = np.asarray(lanes, np.int64)
         devs = lanes // self.span
         for d in np.unique(devs):
@@ -281,12 +212,11 @@ class DeviceDispatcher:
     def dispatch_packed(self, lanes, default_thresh: float,
                         default_budget: int, precision: str | None = None,
                         bucket: tuple | None = None) -> list[Pending]:
-        """Enqueue one bucket's lanes on the packed protocol, without
-        blocking: every intersecting device runs its whole span from
-        RESIDENT state — the only per-dispatch traced inputs are the step's
-        default threshold/budget scalars (lanes without explicit policies
-        resolve against them in-jit, so a governor rung change costs no
-        re-splice)."""
+        """Enqueue one bucket's lanes, without blocking: every intersecting
+        device runs its whole span from the replica's own state — the only
+        per-dispatch inputs are the step's default threshold/budget
+        scalars (lanes without explicit policies resolve against them, so
+        a governor rung change costs no re-splice)."""
         lanes = np.fromiter(lanes, np.int64, len(lanes)) \
             if not isinstance(lanes, np.ndarray) else lanes.astype(np.int64)
         pc, ph, tr = time.perf_counter_ns, self.phase_ns, self.trace
@@ -295,6 +225,7 @@ class DeviceDispatcher:
             d = int(d)
             lo = d * self.span
             mine = lanes[(lanes >= lo) & (lanes < lo + self.span)]
+            local = mine - lo
             fn = self._fns[d]
             with (span("serve.dispatch.splice", replica=d) if tr
                   else NO_SPAN):
@@ -306,7 +237,7 @@ class DeviceDispatcher:
                   else NO_SPAN):
                 outs = fn.enqueue(
                     np.float32(default_thresh), np.int32(default_budget),
-                    precision, bucket=bucket)
+                    precision, bucket=bucket, local=local)
                 # every packed output goes to the host at harvest: start
                 # its copy now, queued on the device behind the kernel, so
                 # the harvest finds the bytes there instead of paying one
@@ -320,9 +251,8 @@ class DeviceDispatcher:
             ph["enqueue"] += t2 - t1
             nxt, hops, energy = outs
             p = Pending(device=d, precision=precision, lanes=mine,
-                        local=mine - lo, logits=None, hops=hops,
-                        bucket=bucket, nxt=nxt, energy=energy,
-                        prefetched=len(outs))
+                        local=local, nxt=nxt, hops=hops, energy=energy,
+                        bucket=bucket, prefetched=len(outs))
             self._queues[d].append(p)
             out.append(p)
         return out
@@ -377,63 +307,136 @@ class DeviceDispatcher:
                 counts["host_copies_prefetched"] += p.prefetched
         return nxt, hops, energy, pending
 
-    def harvest(self, n_slots: int):
-        """Drain every device queue: ONE deferred ``block_until_ready``
-        over all in-flight outputs, then scatter the group lanes back into
-        full-batch arrays.
 
-        Returns ``(logits [n_slots, C], hops [n_slots] | None,
-        dispatches)`` — logits/hops as HOST numpy arrays — where
-        ``dispatches`` is the drained :class:`Pending` list (device /
-        precision / lane bookkeeping for telemetry and the load harness's
-        per-device accounting).
-        """
-        pending = [p for q in self._queues for p in q]
-        for q in self._queues:
-            q.clear()
-        if not pending:
-            raise ValueError("harvest() with nothing dispatched")
-        # the single deferred synchronization point of the whole step
-        jax.block_until_ready([(p.logits, p.hops) for p in pending])
-        hops_present = [p.hops is not None for p in pending]
-        if any(hops_present) != all(hops_present):
-            raise ValueError(
-                "inconsistent decode replicas: some returned hop telemetry "
-                "and some returned hops=None")
-        logits = None
-        hops = None
-        for p in pending:
-            lg = np.asarray(p.logits)
-            if logits is None:
-                logits = np.zeros((n_slots,) + lg.shape[1:], lg.dtype)
-                if p.hops is not None:
-                    hops = np.zeros((n_slots,), np.int64)
-            logits[p.lanes] = lg[p.local]
-            if p.hops is not None:
-                hops[p.lanes] = np.asarray(p.hops)[p.local]
-        # numpy on purpose: the scheduler's post-step bookkeeping (argmax,
-        # per-lane harvesting) is host-side serial work — handing back
-        # device arrays would buy nothing but re-dispatch latency
-        return logits, hops, pending
+def _takes_bucket(fn: Callable) -> bool:
+    """Does ``fn`` accept a ``bucket`` keyword ((model, version) routing
+    for registry-backed multi-tenant serving)?"""
+    try:
+        p = inspect.signature(fn).parameters.get("bucket")
+    except (TypeError, ValueError):
+        return False
+    return p is not None and p.kind in (p.KEYWORD_ONLY,
+                                        p.POSITIONAL_OR_KEYWORD)
 
 
-@partial(jax.jit,
-         static_argnames=("max_hops", "backend", "block_b"))
-def _serve_eval(pack, x, key, step, thresh, budget, max_hops: int,
-                backend: str, block_b: int):
-    """One span's decode as ONE jitted program: start-grove draw +
-    Algorithm-2 evaluation fused into a single dispatch.  The serving loop
-    is latency-bound on per-dispatch Python/runtime overhead, so the
-    un-jitted conveniences of ``FogEngine.eval`` (policy resolution, report
-    pricing, a separate ``sample_starts`` dispatch) are deliberately
-    bypassed — ``_eval_core`` is the same conformance-tested state machine
-    every backend shares."""
-    from repro.core.engine import _eval_core
-    start = jax.random.randint(jax.random.fold_in(key, step),
-                               (x.shape[0],), 0, pack.n_groves)
-    res = _eval_core(pack, x, start, thresh, budget, max_hops, backend,
-                     block_b, False)
-    return res.proba, res.hops
+class HostReplica:
+    """One span of the packed protocol over host callables (an LM decode
+    step, a test mock): each lane's token, cache length and knobs live on
+    the host.
+
+    ``decode_fn(tokens [span], lengths [span], policy) -> (logits [span,
+    V], hops [span] | None)`` gets ``policy`` with the span's resolved
+    per-lane threshold / hop-budget vectors, the dispatch's precision and
+    the static knobs of the ``policy`` given here; a dispatch that carries
+    a (model, version) bucket passes it as ``bucket=``, which only a
+    ``decode_fn`` with a ``bucket`` parameter takes (``bucketed``; the
+    batcher refuses a request with a model at submit when its replicas
+    are not).  ``prefill_fn(lane, prompt) -> length`` gets the GLOBAL
+    lane.  ``price(hops, precision, bucket) -> pJ`` prices each lane (e.g.
+    :func:`~repro.serve.governor.lane_pricer`); lanes it returns None for,
+    and every lane without it, are unpriced (NaN).  A ``decode_fn`` that
+    returns ``hops=None`` (a dense step) reports ``n_groves`` hops for
+    every lane, and fails without ``n_groves``.
+
+    :meth:`enqueue` does not block: the argmax and the pricing are queued
+    on the replica's device behind the decode, so the replicas of a
+    dispatch compute concurrently.  A lane's next input is the token its
+    own bucket's call produced, and every call of one step sees the same
+    inputs: a call's outputs are staged and land on the host at the next
+    step's first admit, retire or call that revisits a lane.  No kernel
+    walks a host replica: ``block_b`` / ``compact`` describe a dense walk
+    over the span."""
+
+    def __init__(self, decode_fn: Callable, prefill_fn: Callable, span: int,
+                 *, index: int = 0, device=None, price: Callable | None = None,
+                 policy: FogPolicy | None = None, n_groves: int | None = None):
+        self.decode_fn = decode_fn
+        self.prefill_fn = prefill_fn
+        self.price = price
+        self.policy = policy if policy is not None else FogPolicy()
+        self.n_groves = n_groves
+        self.device = device            # None: the default device
+        self.lo = index * span
+        self.block_b, self.compact = span, False
+        self.bucketed = _takes_bucket(decode_fn)
+        self.tokens = np.zeros((span,), np.int32)
+        self.lengths = np.zeros((span,), np.int32)
+        self.thresh = np.full((span,), THRESH_DEFAULT, np.float32)
+        self.budget = np.full((span,), BUDGET_DEFAULT, np.int32)
+        self._staged = np.zeros((span,), bool)
+        self._outs: list = []           # (local, next device array) staged
+
+    def _settle(self) -> None:
+        """Land the last step's staged outputs as the lanes' inputs."""
+        if self._outs:
+            for local, nxt in self._outs:
+                self.tokens[local] = np.asarray(nxt)[local]
+                self.lengths[local] += 1
+            self._outs.clear()
+            self._staged[:] = False
+
+    def admit_many(self, local, prompts, thr, bud) -> None:
+        self._settle()
+        if prompts is not None:
+            for i, prompt in zip(local.tolist(), prompts):
+                self.lengths[i] = self.prefill_fn(self.lo + i, prompt)
+                self.tokens[i] = prompt[-1]
+        self.thresh[local] = thr
+        self.budget[local] = bud
+
+    def retire_many(self, local) -> None:
+        self._settle()
+        self.tokens[local] = 0
+        self.lengths[local] = 0
+        self.thresh[local] = THRESH_DEFAULT
+        self.budget[local] = BUDGET_DEFAULT
+
+    def splice(self) -> None:
+        """Nothing to apply: admits and retires write the host state."""
+
+    def enqueue(self, def_thresh, def_budget, precision=None, bucket=None,
+                local=None):
+        if self._staged[local].any():
+            self._settle()
+        thr = np.where(np.isnan(self.thresh), def_thresh, self.thresh)
+        bud = np.where(self.budget < 0, def_budget, self.budget)
+        policy = self.policy.replace(
+            threshold=thr, precision=precision,
+            hop_budget=None if (bud == NO_BUDGET).all() else bud)
+        kw = {} if bucket is None else {"bucket": bucket}
+        put = partial(jax.device_put, device=self.device)
+        # copies: the call may still be reading its inputs when the next
+        # step's admits rewrite the lanes
+        logits, hops = self.decode_fn(put(self.tokens.copy()),
+                                      put(self.lengths.copy()), policy, **kw)
+        nxt = put(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        if hops is None:
+            if self.n_groves is None:
+                raise ValueError(
+                    "decode_fn returned hops=None and the replica has no "
+                    "n_groves: the serving step needs hop telemetry")
+            hops = jnp.full(nxt.shape, self.n_groves, jnp.int32)
+        hops = put(hops)
+        energy = None if self.price is None else self.price(hops, precision,
+                                                            bucket)
+        energy = put(jnp.full(hops.shape, jnp.nan, jnp.float32)
+                     if energy is None else energy)
+        self._outs.append((local, nxt))
+        self._staged[local] = True
+        return nxt, hops, energy
+
+
+def host_replica_factory(decode_fn: Callable, prefill_fn: Callable,
+                         price: Callable | None = None, *,
+                         policy: FogPolicy | None = None,
+                         n_groves: int | None = None) -> Callable:
+    """A :class:`DeviceDispatcher` factory whose replicas are
+    :class:`HostReplica` s over the same host callables."""
+    def build(index: int, device, span: int) -> HostReplica:
+        return HostReplica(decode_fn, prefill_fn, span, index=index,
+                           device=device, price=price, policy=policy,
+                           n_groves=n_groves)
+    return build
 
 
 @partial(jax.jit,
@@ -478,15 +481,16 @@ class ForestReplicaServer:
     ``fold_in(key(seed + index), k)``.
 
         server = ForestReplicaServer(gc, n_features=16)
-        disp = DeviceDispatcher(server.factory, devices=serve_devices(4))
+        disp = DeviceDispatcher(server.packed_factory,
+                                devices=serve_devices(4))
         batcher = ContinuousBatcher(128, None, server.prefill,
-                                    dispatcher=disp)
+                                    dispatcher=disp, pipeline=True)
         batcher.submit(Request(rid=0, prompt=x_row, max_new_tokens=1))
 
     ``Request.prompt`` is the feature row (float, ``[n_features]``); the
-    decode "logits" are the forest's class probabilities and ``hops`` is
-    the paper's per-example energy quantity, so the whole mixed-QoS /
-    governor / admission-control machinery applies unchanged.
+    "next token" is the forest's argmax class and ``hops`` is the paper's
+    per-example energy quantity, so the whole mixed-QoS / governor /
+    admission-control machinery applies unchanged.
 
     Multi-tenant mode: pass ``registry=`` (a
     :class:`~repro.registry.ModelRegistry`) and ``cache=`` (a
@@ -528,9 +532,7 @@ class ForestReplicaServer:
         self.seed = seed
         self._buffers: dict[int, np.ndarray] = {}
         self._span: int | None = None
-        self._steps: dict[int, int] = {}
         self._energy_models: dict[tuple, object] = {}
-        self._devices: dict[int, object] = {}
         # (precision, bucket) -> (per_hop_pj, transfer_pj) float32 scalars
         # traced into the packed dispatch (in-jit affine pricing)
         self._hop_costs: dict[tuple, tuple] = {}
@@ -572,59 +574,6 @@ class ForestReplicaServer:
             self._energy_models[key] = m
         return m
 
-    def factory(self, index: int, device, span: int):
-        """The :class:`DeviceDispatcher` ``decode_factory`` contract."""
-        self._span = span
-        buf = np.zeros((span, self.n_features), np.float32)
-        self._buffers[index] = buf
-        self._devices[index] = device
-        packs = {p: jax.device_put(pack, device)
-                 for p, pack in self._packs.items()}
-        key = jax.device_put(jax.random.key(self.seed + index), device)
-        self._steps[index] = 0
-        backend = self.backend
-        block_b = min(256, span)
-
-        def decode(tokens, lengths, policy, bucket=None):
-            # tokens/lengths are the slot-model plumbing; the forest serves
-            # the span's feature rows.  A fresh start-grove draw per step
-            # keeps the rotation-start randomization honest under
-            # continuous refill.  Per-lane knobs are shaped as numpy — the
-            # jit call places them beside the committed pack/x, so the
-            # whole evaluation runs on THIS replica's device.
-            step = self._steps[index] = self._steps[index] + 1
-            thr = np.broadcast_to(
-                np.asarray(policy.threshold, np.float32), (span,))
-            bud = (np.broadcast_to(
-                       np.asarray(policy.hop_budget, np.int32), (span,))
-                   if policy.hop_budget is not None
-                   else np.full((span,), NO_BUDGET, np.int32))
-            prec = policy.precision or self.default_precision
-            if bucket is not None:
-                # registry bucket: this replica's committed copy of the
-                # (tenant, version) pack at the group's precision, through
-                # the VMEM-budgeted cache (lazy reload after eviction)
-                if self.cache is None:
-                    raise ValueError(
-                        f"replica {index} got bucket {bucket!r} but the "
-                        "server has no registry/cache (single-model mode)")
-                tenant, version = bucket
-                pack = self.cache.device_pack(tenant, version, prec,
-                                              index, device)
-            elif packs:
-                pack = packs[prec]
-            else:
-                raise ValueError(
-                    "registry-mode server got a bucketless dispatch; "
-                    "requests must carry Request.model (no built-in "
-                    "default model was constructed)")
-            x = jax.device_put(buf, device)
-            return _serve_eval(pack, x, key, np.int32(step),
-                               thr, bud, max_hops=pack.n_groves,
-                               backend=backend, block_b=block_b)
-
-        return decode
-
     def _hop_cost(self, prec: str, bucket, pack):
         """Cached (per_hop_pj, transfer_pj) host floats for one pack's
         topology at one precision — the traced inputs of the in-jit affine
@@ -648,19 +597,17 @@ class ForestReplicaServer:
         (:func:`~repro.core.engine.splice_slot_state`), and each dispatch
         runs
         :func:`_serve_eval_packed` — start draw, default resolution,
-        evaluation, argmax and energy pricing in one launch.  ``step()``
-        therefore stops paying per-step row uploads, policy re-assembly and
-        logits downloads; only three [span] vectors come back per dispatch.
+        evaluation, argmax and energy pricing in one launch: a step uploads
+        no rows or policy vectors, and only three [span] vectors come back
+        per dispatch.
         The splice is compiled here, on ``device``, at every padded width a
         burst can take, so no replica compiles one while serving.
         """
         from repro.core.engine import splice_slot_state
         self._span = span
-        self._devices[index] = device
         packs = {p: jax.device_put(pack, device)
                  for p, pack in self._packs.items()}
         key = jax.device_put(jax.random.key(self.seed + index), device)
-        self._steps[index] = 0
         backend = self.backend
         block_b = min(256, span)
         lp = LanePolicies(span)
@@ -707,17 +654,8 @@ class ForestReplicaServer:
                 mirror[locals_] = rows
             lp.stamp_many(locals_, thr, bud)
 
-        def admit(local: int, row, thr: float, bud: int) -> None:
-            admit_many(np.asarray([local]),
-                       None if row is None
-                       else np.asarray(row, np.float32).reshape(1, -1),
-                       thr, bud)
-
         def retire_many(locals_) -> None:
             lp.retire_many(locals_)
-
-        def retire(local: int) -> None:
-            retire_many(np.asarray([local]))
 
         def _apply_staged() -> None:
             # one FUSED splice over all three buffers, driven by the knob
@@ -733,7 +671,9 @@ class ForestReplicaServer:
                     state["x"], state["thr"], state["bud"],
                     idx, mirror[idx], thr, bud, donate=False)
 
-        def enqueue(def_thresh, def_budget, precision=None, bucket=None):
+        def enqueue(def_thresh, def_budget, precision=None, bucket=None,
+                    local=None):
+            # every lane of the span is evaluated; ``local`` is unused
             prec = precision or self.default_precision
             if bucket is not None:
                 if self.cache is None:
@@ -770,26 +710,18 @@ class ForestReplicaServer:
                 max_hops=pack.n_groves, backend=backend, block_b=block_b)
             return nxt, hops, energy
 
-        def decode(def_thresh, def_budget, precision=None, bucket=None):
-            _apply_staged()
-            return enqueue(def_thresh, def_budget, precision, bucket)
-
-        decode.packed = True
-        # the two halves of a dispatch, which the dispatcher times apart
-        decode.splice = _apply_staged
-        decode.enqueue = enqueue
-        # the fused kernel's walk: lane block and compaction
-        decode.block_b = lane_block(block_b, span)
-        decode.compact = SERVE_COMPACT
-        decode.state = state            # resident device buffers (inspection)
-        decode.admit = admit
-        decode.admit_many = admit_many
-        decode.retire = retire
-        decode.retire_many = retire_many
-        return decode
+        return types.SimpleNamespace(
+            splice=_apply_staged, enqueue=enqueue,
+            admit_many=admit_many, retire_many=retire_many,
+            # the fused kernel's walk: lane block and compaction
+            block_b=lane_block(block_b, span), compact=SERVE_COMPACT,
+            bucketed=True,              # serves registry buckets
+            state=state)                # resident device buffers (inspection)
 
     def prefill(self, slot: int, prompt: np.ndarray) -> int:
-        """Store the request's feature row in its slot's device buffer."""
+        """Write the request's feature row into its slot's host mirror
+        (the batcher's ``prefill_fn`` argument; with a dispatcher the
+        batcher stages rows through ``admit_lanes`` and never calls it)."""
         if self._span is None:
             raise ValueError("server not bound; construct the batcher "
                              "with its DeviceDispatcher first")

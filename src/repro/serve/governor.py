@@ -26,12 +26,16 @@ Step-down is immediate (an SLO breach must not persist); step-up requires
 """
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Callable, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.frontier import Frontier
 from repro.core.policy import FogPolicy
+
+# EnergyGovernor.price's default precision: the active rung's
+_ACTIVE = object()
 
 
 class EnergyGovernor:
@@ -149,16 +153,19 @@ class EnergyGovernor:
             self._models[precision] = cached
         return cached
 
-    def price(self, hops) -> np.ndarray:
-        """Per-example pJ for raw hop telemetry, priced at the ACTIVE
-        rung's precision (what the serving batcher feeds the stats)."""
+    def price(self, hops, precision=_ACTIVE):
+        """Per-example pJ for raw hop telemetry at ``precision`` (default:
+        the ACTIVE rung's, what :meth:`observe` prices at).  Device hops
+        are priced on their device, without a host round trip."""
         if self.model is None:
             raise ValueError(
                 "pricing raw hop counts needs an energy model; "
                 "construct EnergyGovernor(..., model=...)")
-        return np.asarray(
-            self.model_for(self.current.precision).lane_pj(
-                np.asarray(hops)))
+        if precision is _ACTIVE:
+            precision = self.current.precision
+        if not isinstance(hops, jax.Array):
+            hops = np.asarray(hops)
+        return self.model_for(precision).lane_pj(hops)
 
     def observe(self, hops=None, energy_pj=None, devices=None) -> float:
         """Fold one batch of telemetry into the rolling estimate.
@@ -404,6 +411,21 @@ class TenantLedger:
     def summary(self) -> str:
         lines = [f"{t}: {g.summary()}" for t, g in self.items()]
         return "\n".join(lines) if lines else "no ledgered tenants"
+
+
+def lane_pricer(governor) -> Callable | None:
+    """A host replica's ``price(hops, precision, bucket)`` over an
+    :class:`EnergyGovernor`, or over a :class:`TenantLedger`, which prices
+    each bucket at its tenant's governor and leaves the buckets no
+    governor bills unpriced (None)."""
+    if governor is None:
+        return None
+    if isinstance(governor, TenantLedger):
+        def price(hops, precision, bucket):
+            gov = governor.governor_for(None if bucket is None else bucket[0])
+            return None if gov is None else gov.price(hops, precision)
+        return price
+    return lambda hops, precision, bucket: governor.price(hops, precision)
 
 
 def default_ladder(base: FogPolicy, model=None,

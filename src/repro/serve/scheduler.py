@@ -1,39 +1,42 @@
 """Batched request scheduler for serving (continuous batching + FoG).
 
-A slot-based continuous-batching scheduler: a fixed decode batch of
-``n_slots`` lanes; finished/empty lanes are refilled from the request queue
-each step (the standard vLLM-style slot model, minus paged KV — caches here
-are dense per-slot rings).  With FoG decode enabled, per-step grove usage
-(hops) is accumulated per request, giving the per-request energy/FLOP
-accounting that mirrors the paper's per-input hop counter.
+A slot-based continuous-batching scheduler: a fixed batch of ``n_slots``
+lanes; finished/empty lanes are refilled from the request queue each step
+(the standard vLLM-style slot model, minus paged KV).  Per-step grove
+usage (hops) is accumulated per request, giving the per-request
+energy/FLOP accounting that mirrors the paper's per-input hop counter.
+
+One serving step.  The batcher drives a
+:class:`~repro.serve.dispatch.DeviceDispatcher` whose replicas keep each
+lane's state (feature row or token, cache length, knobs): a refill stages
+the admitted lanes' prompts and knobs on their replica, each step
+dispatches once per serving bucket, and a harvest folds the returned
+``(next, hops, energy)`` into the requests.  The forest's replicas
+(:meth:`~repro.serve.dispatch.ForestReplicaServer.packed_factory`) run the
+whole step in one fused launch per bucket; a host
+``decode_fn(tokens, lengths, policy)`` passed in place of a dispatcher
+runs behind the same protocol on a one-replica dispatcher
+(:class:`~repro.serve.dispatch.HostReplica`).
 
 Mixed-QoS serving: every :class:`Request` may carry its own
-:class:`~repro.core.policy.FogPolicy` (threshold / hop budget).  Each step
-the scheduler assembles the slots' scalar policies into one per-lane batch
-policy (:func:`repro.core.policy.assemble`) and hands it to a policy-aware
-``decode_fn(tokens, lengths, policy)`` — one continuous batch, one compiled
-program, every lane buying its own accuracy/energy point.  Legacy two-arg
-``decode_fn(tokens, lengths)`` callables keep working unchanged.
+:class:`~repro.core.policy.FogPolicy` (threshold / hop budget), stamped
+on its lane at admission; lanes without one take the step's default
+policy — one continuous batch, one compiled program, every lane buying its
+own accuracy/energy point.
 
 Mixed-precision serving: a request's policy may additionally set
 ``precision`` ("fp32" | "bf16" | "int8" packed tables).  Precision selects
 a compiled program, so it cannot ride the per-lane vectors; instead the
-scheduler buckets the step's slots by precision and dispatches ``decode_fn``
-once per distinct precision present (each call still carries the full
-per-lane threshold/budget vectors; each slot's outputs are harvested from
-its own precision's call).  A homogeneous batch — the common case — still
-costs exactly one dispatch.
+scheduler buckets lanes by (model, version, precision) and dispatches once
+per bucket present, each lane harvested from its own bucket's call.  A
+homogeneous batch — the common case — costs exactly one dispatch.
 
-Data-parallel serving: hand the batcher a
-:class:`~repro.serve.dispatch.DeviceDispatcher` instead of a ``decode_fn``
-and each precision group's dispatch fans out across the dispatcher's device
-replicas (fixed per-device slot spans, per-device dispatch queues, one
-deferred ``jax.block_until_ready`` at harvest) — the slot model, policy
-assembly and telemetry are unchanged; only the execution plane widens.
-The refill routes each burst of admitted requests to ONE replica, the one
-with the most free slots (ties in turn after the replica used last), and
-spills into the next in turn only when it is full: a step's dispatch then
-touches one replica and the replicas take turns (``_route``).
+Data-parallel serving: with several replicas each bucket's dispatch fans
+out across the devices its lanes touch.  The refill routes each burst of
+admitted requests to ONE replica, the one with the most free slots (ties
+in turn after the replica used last), and spills into the next in turn
+only when it is full: a step's dispatch then touches one replica and the
+replicas take turns (``_route``).
 
 Admission control: ``max_queue`` bounds the request queue.  When it is
 full, ``shed_policy`` decides who pays: ``"reject"`` sheds the incoming
@@ -51,26 +54,23 @@ threshold -> int8 -> cut hop budget) on a breach, back up when headroom
 returns.  A request may carry ``energy_budget_nj`` instead of an explicit
 policy: the governor resolves it against the calibrated frontier into the
 highest-accuracy rung fitting that budget (hop budget clamped so the
-contract is hard).  Telemetry lives in :class:`ServeStats` — the old
-``HopMeter`` plumbing survives only as a deprecation shim.
+contract is hard).  Telemetry lives in :class:`ServeStats`.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
-import inspect
 import time
-import warnings
 from collections import deque
 from typing import Callable
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import HopMeter
-from repro.core.policy import NO_BUDGET, FogPolicy, assemble, lane_knobs
-from repro.serve.dispatch import NO_SPAN, PHASES, span
+from repro.core.policy import NO_BUDGET, FogPolicy, lane_knobs
+from repro.serve.dispatch import (NO_SPAN, PHASES, DeviceDispatcher,
+                                  host_replica_factory, span)
+from repro.serve.governor import lane_pricer
 
 
 @dataclasses.dataclass
@@ -117,8 +117,7 @@ class Request:
 
 @dataclasses.dataclass
 class ServeStats:
-    """Fleet-level serving telemetry (replaces the deprecated HopMeter):
-    hop counts of every decoded event, plus modeled pJ when a governor (or
+    """Fleet-level serving telemetry: hop counts of every decoded event, plus modeled pJ when a governor (or
     its energy model) is installed to price them."""
 
     total_hops: int = 0
@@ -245,7 +244,6 @@ class ServeStats:
 class SlotState:
     """A read view of one slot (``ContinuousBatcher.slots``)."""
     request: Request | None = None
-    length: int = 0               # tokens already in this slot's cache
 
 
 def _runs(codes: np.ndarray) -> list:
@@ -274,97 +272,63 @@ class _Codes(dict):
         return c
 
 
-def _policy_mode(decode_fn: Callable) -> str:
-    """How decode_fn accepts the batch policy.
-
-    ``"positional"``  three-plus positional params (or ``*args``): called
-                      ``decode_fn(tokens, lengths, policy)``
-    ``"keyword"``     a KEYWORD_ONLY ``policy`` param (also reachable
-                      through ``functools.partial`` / ``jax.jit`` wrappers,
-                      whose signatures follow ``__wrapped__``): called
-                      ``decode_fn(tokens, lengths, policy=policy)``
-    ``"legacy"``      two-arg decode; never sees a policy
-    """
-    try:
-        params = inspect.signature(decode_fn).parameters.values()
-    except (TypeError, ValueError):   # builtins / C callables: assume legacy
-        return "legacy"
-    positional = [p for p in params
-                  if p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD)]
-    if (len(positional) >= 3
-            or any(p.kind == p.VAR_POSITIONAL for p in params)):
-        return "positional"
-    if any(p.kind == p.KEYWORD_ONLY and p.name == "policy" for p in params):
-        return "keyword"
-    return "legacy"
-
-
-def _takes_policy(decode_fn: Callable) -> bool:
-    """Does decode_fn accept a policy argument (positional or kw-only)?"""
-    return _policy_mode(decode_fn) != "legacy"
-
-
-def _takes_bucket(decode_fn: Callable) -> bool:
-    """Does decode_fn accept a ``bucket`` keyword ((model, version)
-    routing for registry-backed multi-tenant serving)?"""
-    try:
-        params = inspect.signature(decode_fn).parameters
-    except (TypeError, ValueError):
-        return False
-    p = params.get("bucket")
-    return p is not None and p.kind in (p.KEYWORD_ONLY,
-                                        p.POSITIONAL_OR_KEYWORD)
-
-
 class ContinuousBatcher:
-    """Drives decode_fn over a fixed slot batch, refilling as lanes finish.
+    """Drives a dispatcher's replicas over a fixed slot batch, refilling
+    as lanes finish.
 
-    decode_fn(tokens [n_slots] int32, lengths [n_slots] int32
-              [, policy: FogPolicy with per-lane [n_slots] knobs])
-        -> (logits [n_slots, V], hops [n_slots] | None)
-        (the policy param may be positional or KEYWORD_ONLY ``*, policy``)
-    prefill_fn(slot, prompt) -> int  (returns prompt length in cache)
-    default_policy: applied to slots whose request carries no policy (and
-        to empty lanes); its static knobs select the compiled program.
+    decode_fn: a host ``decode_fn(tokens [n_slots] int32, lengths
+        [n_slots] int32, policy with per-lane [n_slots] knobs) ->
+        (logits [n_slots, V], hops [n_slots])``, with ``bucket=`` as a
+        keyword when requests name registry tenants (without it such
+        requests are refused at submit); the batcher runs it
+        behind a one-replica dispatcher
+        (:func:`~repro.serve.dispatch.host_replica_factory`), priced by
+        the governor or ledger.  Pass None with ``dispatcher``.
+    prefill_fn(slot, prompt) -> int  (returns prompt length in cache);
+        called by the host replica that ``decode_fn`` builds, unused with a
+        ``dispatcher``.
+    default_policy: applied to slots whose request carries no policy; its
+        static knobs select the compiled program.
     governor: optional EnergyGovernor — when set, the *governor's active
         rung* replaces default_policy each step, per-step hop telemetry
         feeds its rolling estimate, and requests may carry
-        ``energy_budget_nj`` contracts.
-    dispatcher: optional :class:`~repro.serve.dispatch.DeviceDispatcher` —
-        the data-parallel execution plane.  Mutually exclusive with
-        ``decode_fn`` (pass ``decode_fn=None``); always policy-aware.
+        ``energy_budget_nj`` contracts.  A TenantLedger instead governs
+        each tenant apart.
+    dispatcher: a :class:`~repro.serve.dispatch.DeviceDispatcher` — the
+        execution plane.  Mutually exclusive with ``decode_fn``.
     max_queue: admission-control bound on the request queue (None =
-        unbounded, the pre-existing behavior).
+        unbounded).
     shed_policy: who is shed when the queue is full — ``"reject"`` the
         incoming request (submit returns False) or evict the ``"oldest"``
         queued request.
-    meter: DEPRECATED — pass nothing and read ``batcher.stats`` instead.
+    pipeline: harvest step t's dispatch at the start of step t+1, so host
+        bookkeeping overlaps device compute (see :meth:`step`).
+    telemetry_every: replay buffered telemetry every k harvests.
 
-    Host instrumentation of the packed step: ``phase_ns`` (always on)
-    times its phases and the dispatcher's sub-phases in ns; ``route``, the
-    refill's choice of replica, is part of ``refill``.  Setting
-    ``trace = True`` wraps each in a ``serve.*`` profiler span, times
-    Python's garbage-collector pauses into ``phase_ns["gc"]`` (one
-    ``serve.gc`` span each) and sums into ``counts`` the fused kernel's
-    walked and served lane-hops, the packed outputs harvested to the
-    host, all and those whose copy started at enqueue, the requests
+    Host instrumentation: ``phase_ns`` (always on) times the step's phases
+    and the dispatcher's sub-phases in ns; ``route``, the refill's choice
+    of replica, is part of ``refill``.  Setting ``trace = True`` wraps each
+    in a ``serve.*`` profiler span, times Python's garbage-collector pauses
+    into ``phase_ns["gc"]`` (one ``serve.gc`` span each) and sums into
+    ``counts`` the walked and served lane-hops, the outputs harvested to
+    the host, all and those whose copy started at enqueue, the requests
     admitted to each replica ``d`` (``routed_<d>``), the policy
     validations ``submit`` ran (``policy_checks``: once per distinct
     policy object) and the admission groups refill bursts resolved
     (``admit_groups``); set it back to False to uninstall the collector
     callback.
 
-    The packed path keeps per-lane state in arrays indexed by lane: the
-    request, tokens left, serving bucket, tier and (tenant, version)
-    codes.  Work that depends only on a request's (tenant, version,
-    policy, tier) group — routing, knob and bucket resolution, telemetry
-    folds — runs once per group per burst or harvest; only the
-    ``Request`` fields the API returns are written per request.
+    Per-lane state lives in arrays indexed by lane: the request, tokens
+    left, serving bucket, tier and (tenant, version) codes.  Work that
+    depends only on a request's (tenant, version, policy, tier) group —
+    routing, knob and bucket resolution, telemetry folds — runs once per
+    group per burst or harvest; only the ``Request`` fields the API
+    returns are written per request.
     """
 
     def __init__(self, n_slots: int, decode_fn: Callable | None,
                  prefill_fn: Callable, eos_id: int = 1,
-                 meter=None, default_policy: FogPolicy | None = None,
+                 default_policy: FogPolicy | None = None,
                  governor=None, dispatcher=None,
                  max_queue: int | None = None, shed_policy: str = "reject",
                  registry=None, pipeline: bool = False,
@@ -373,8 +337,6 @@ class ContinuousBatcher:
         # the request in each lane (None = free)
         self._lane_req = np.full((n_slots,), None, object)
         self.queue: deque[Request] = deque()
-        self.decode_fn = decode_fn
-        self.prefill_fn = prefill_fn
         self.eos_id = eos_id
         self.completed: list[Request] = []
         self.default_policy = (default_policy if default_policy is not None
@@ -388,25 +350,32 @@ class ContinuousBatcher:
         self.ledger = None
         if governor is not None and hasattr(governor, "governor_for"):
             self.ledger = governor
-            governor = None
-        self.governor = governor
-        self.dispatcher = dispatcher
-        self.registry = registry
-        self._packed = False
-        if dispatcher is not None:
-            if decode_fn is not None:
-                raise ValueError(
-                    "pass either decode_fn or dispatcher, not both (the "
-                    "dispatcher owns the per-device decode replicas)")
-            dispatcher.bind(n_slots)
-            self._packed = dispatcher.packed
-            self._policy_mode = "dispatch"
-        else:
+        elif governor is not None and governor.model is None:
+            # a governor that can never act must be rejected loudly — a
+            # silently unenforced SLO is worse than no governor at all
+            raise ValueError(
+                "the batcher's governor needs an energy model to price "
+                "hop telemetry; construct EnergyGovernor(..., model=...)")
+        self.governor = governor if self.ledger is None else None
+        if dispatcher is None:
             if decode_fn is None:
                 raise ValueError(
                     "decode_fn is required when no dispatcher is given")
-            self._policy_mode = _policy_mode(decode_fn)
-        self._policy_aware = self._policy_mode != "legacy"
+            dispatcher = DeviceDispatcher(host_replica_factory(
+                decode_fn, prefill_fn,
+                price=lane_pricer(governor),
+                policy=(self.governor.current if self.governor is not None
+                        else self.default_policy)), jax.devices()[:1])
+        elif decode_fn is not None:
+            raise ValueError(
+                "pass either decode_fn or dispatcher, not both (the "
+                "dispatcher owns the per-device decode replicas)")
+        dispatcher.bind(n_slots)
+        self.dispatcher = dispatcher
+        # can every replica serve (model, version) buckets?  A request
+        # naming a model is refused at submit otherwise
+        self._bucketed = all(r.bucketed for r in dispatcher.replicas)
+        self.registry = registry
         if shed_policy not in ("reject", "oldest"):
             raise ValueError(f"unknown shed_policy {shed_policy!r}; "
                              "pick 'reject' or 'oldest'")
@@ -418,64 +387,23 @@ class ContinuousBatcher:
         # the dispatcher's drained Pending list from the last step (device /
         # precision / lane bookkeeping for the load harness)
         self.last_dispatches: list = []
-        # maintained per-lane decode inputs (empty lanes stay 0): rebuilding
-        # these with a per-slot Python loop every step is measurable serial
-        # time at serving-scale slot counts
-        self._tokens = np.zeros((n_slots,), np.int32)
-        self._lengths = np.zeros((n_slots,), np.int32)
-        if governor is not None:
-            # a governor that can never act must be rejected loudly — a
-            # silently unenforced SLO is worse than no governor at all
-            if governor.model is None:
-                raise ValueError(
-                    "the batcher's governor needs an energy model to price "
-                    "hop telemetry; construct EnergyGovernor(..., "
-                    "model=...)")
-            if not self._policy_aware:
-                raise ValueError(
-                    "a governor needs a policy-aware decode_fn(tokens, "
-                    "lengths, policy) — a legacy two-arg decode_fn would "
-                    "never serve the governor's rung policy")
-        if self.ledger is not None and not self._policy_aware:
-            raise ValueError(
-                "a tenant ledger needs a policy-aware decode path — a "
-                "legacy two-arg decode_fn would never serve any tenant's "
-                "rung policy")
-        # can this execution plane route (model, version) buckets?  The
-        # dispatcher introspects its replicas at bind; a plain decode_fn
-        # must take a ``bucket`` keyword itself.
-        if dispatcher is not None:
-            self._bucket_aware = dispatcher.bucket_aware
-        else:
-            self._bucket_aware = _takes_bucket(decode_fn)
-        # -- packed fast path (device-resident slot state) ----------------
-        if pipeline and not self._packed:
-            raise ValueError(
-                "pipeline=True needs a packed dispatcher (replicas built "
-                "from ForestReplicaServer.packed_factory — resident slot "
-                "state is what makes overlapping dispatch with host "
-                "bookkeeping safe)")
         if telemetry_every < 1:
             raise ValueError("telemetry_every must be >= 1")
-        if telemetry_every > 1 and not self._packed:
-            raise ValueError(
-                "telemetry_every > 1 needs the packed dispatch path "
-                "(the legacy step accounts inline)")
         self.pipeline = bool(pipeline)
         self.telemetry_every = int(telemetry_every)
         # occupancy mask + free-slot count per replica (the bound
-        # dispatcher's spans; one span of every slot without one): a burst
-        # takes the lowest free lanes of the replica ``_route`` picks, and
-        # the packed step never walks all n_slots in Python
-        self._span = dispatcher.span if dispatcher is not None else n_slots
+        # dispatcher's spans): a burst takes the lowest free lanes of the
+        # replica ``_route`` picks, and the step never walks all n_slots in
+        # Python
+        self._span = dispatcher.span
         self._n_free = [self._span] * (n_slots // self._span)
         self._route_last = len(self._n_free) - 1
         self._occ_mask = np.zeros((n_slots,), bool)
         self._n_active = 0
-        # packed path, per lane: tokens left to generate, serving bucket
-        # id (-1 free), tier code, (tenant, version) code, and whether the
-        # request carries no policy of its own (restamped on rung moves);
-        # each written once per admission group
+        # per lane: tokens left to generate, serving bucket id (-1 free),
+        # tier code, (tenant, version) code, and whether the request
+        # carries no policy of its own (restamped on rung moves); each
+        # written once per admission group
         self._lane_left = np.zeros((n_slots,), np.int64)
         self._lane_bucket = np.full((n_slots,), -1, np.int32)
         self._lane_tier = np.zeros((n_slots,), np.int32)
@@ -495,14 +423,13 @@ class ContinuousBatcher:
         self._inflight_occ: np.ndarray | None = None
         self._tel_buf: list[tuple] = []
         self._steps_since_flush = 0
-        # per-phase host-time accumulators (ns) for the packed step, the
+        # per-phase host-time accumulators (ns) for the step, the
         # dispatcher's sub-phases (shared dict) and, while tracing, the
         # collector's pauses
         self.phase_ns = dict.fromkeys(
             ("harvest", "bookkeep", "telemetry", "refill", "route",
              "dispatch", *PHASES, "gc"), 0)
-        if dispatcher is not None:
-            dispatcher.phase_ns = self.phase_ns
+        dispatcher.phase_ns = self.phase_ns
         self._routed = [f"routed_{d}" for d in range(len(self._n_free))]
         self.counts = dict.fromkeys(
             ("lane_hops_walked", "lane_hops_served", "host_copies",
@@ -514,12 +441,6 @@ class ContinuousBatcher:
         # fleet-level FoG accounting: hop counts (and, with a governor's
         # energy model, modeled pJ) of every decoded token
         self.stats = ServeStats()
-        if meter is not None:
-            warnings.warn(
-                "ContinuousBatcher(meter=...) is deprecated; per-step "
-                "telemetry lives in batcher.stats (and the governor's "
-                "rolling estimate)", DeprecationWarning, stacklevel=2)
-        self._meter = meter
 
     @property
     def trace(self) -> bool:
@@ -533,8 +454,7 @@ class ContinuousBatcher:
         if on == self._trace:
             return
         self._trace = on
-        if self.dispatcher is not None:
-            self.dispatcher.trace = on
+        self.dispatcher.trace = on
         if on:
             gc.callbacks.append(self._on_gc)
         else:
@@ -554,25 +474,8 @@ class ContinuousBatcher:
 
     @property
     def slots(self) -> list[SlotState]:
-        """A read view of every slot: its request (None = free) and the
-        tokens in its cache."""
-        return [SlotState(r, n) for r, n in zip(self._lane_req.tolist(),
-                                                 self._lengths.tolist())]
-
-    @property
-    def meter(self):
-        """DEPRECATED — legacy readers of the always-present HopMeter get
-        a shim seeded from ``stats`` (same totals), plus the warning."""
-        if self._meter is None:
-            warnings.warn(
-                "ContinuousBatcher.meter is deprecated; read "
-                "batcher.stats (ServeStats) instead",
-                DeprecationWarning, stacklevel=2)
-            m = HopMeter.__new__(HopMeter)   # we already warned just above
-            m.total_hops = self.stats.total_hops
-            m.n_events = self.stats.n_events
-            self._meter = m
-        return self._meter
+        """A read view of every slot: its request (None = free)."""
+        return [SlotState(r) for r in self._lane_req.tolist()]
 
     def submit(self, req: Request) -> bool:
         """Validate, resolve energy contracts, then admit or shed.
@@ -594,11 +497,11 @@ class ContinuousBatcher:
                     "a registry to resolve the serving version (construct "
                     "ContinuousBatcher(..., registry=ModelRegistry(dir)), "
                     "or pre-set Request.version)")
-            if not self._bucket_aware:
+            if not self._bucketed:
                 raise ValueError(
                     f"request {req.rid}: Request.model={req.model!r} needs "
-                    "a bucket-aware decode path (a decode_fn/replica "
-                    "taking bucket=) to route (model, version) buckets")
+                    "a bucket-aware decode path (a decode_fn taking "
+                    "bucket=) to route (model, version) buckets")
         if req.energy_budget_nj is not None:
             if req.policy is not None:
                 raise ValueError(
@@ -728,30 +631,10 @@ class ContinuousBatcher:
         self._occ_mask[lanes] = False
         self._lane_req[lanes] = None
         self._lane_bucket[lanes] = -1
-        self._lengths[lanes] = 0
         self._n_active -= len(lanes)
         per = np.bincount(lanes // self._span, minlength=len(self._n_free))
         for d in np.flatnonzero(per).tolist():
             self._n_free[d] += int(per[d])
-
-    def _refill(self) -> None:
-        q = self.queue
-        k = min(len(q), self._n_slots - self._n_active)
-        if not k:
-            return
-        now = time.perf_counter()
-        for i in self._take_lanes(k).tolist():
-            req = q.popleft()
-            req.t_admit = now
-            if (req.model is not None and req.version is None
-                    and self.registry is not None):
-                # resolve the serving version HERE, once: the request
-                # rides this version to completion even if a publish
-                # hot-swaps the tenant's live version mid-decode
-                req.version = self.registry.route(req.model, req.rid)
-            self._lane_req[i] = req
-            self._lengths[i] = self.prefill_fn(i, req.prompt)
-            self._tokens[i] = req.prompt[-1]
 
     @property
     def active(self) -> int:
@@ -765,61 +648,13 @@ class ContinuousBatcher:
         gov = self.ledger.governor_for(tenant)
         return None if gov is None else gov.current
 
-    def lane_policy(self) -> FogPolicy:
-        """The current batch policy: slot policies stacked into per-lane
-        threshold / hop-budget vectors.  A slot without its own policy gets
-        its tenant's ledgered rung (ledger mode), else the default — the
-        fleet governor's active ladder rung when one is installed."""
-        default = (self.governor.current if self.governor is not None
-                   else self.default_policy)
-        pols: list[FogPolicy | None] = []
-        for req in self._lane_req.tolist():
-            if req is None or req.policy is not None:
-                pols.append(req.policy if req else None)
-            else:
-                pols.append(self._tenant_rung(req.tenant))
-        return assemble(pols, default=default)
-
-    def _bucket_groups(self) -> dict:
-        """Slot indices keyed by ``(model, version, precision)`` — the
-        serving bucket.  One decode dispatch per key; the legacy
-        single-model batch degenerates to ``(None, None, precision)`` keys
-        (precision None = the default program), so a homogeneous batch
-        still costs exactly one dispatch."""
-        groups: dict[tuple, list[int]] = {}
-        reqs = self._lane_req.tolist()
-        for i, req in enumerate(reqs):
-            if req is None:
-                key = (None, None, None)
-            else:
-                prec = (req.policy.precision if req.policy is not None
-                        else None)
-                if prec is None:
-                    rung = self._tenant_rung(req.tenant)
-                    if rung is not None:
-                        prec = rung.precision
-                key = (req.model, req.version, prec)
-            groups.setdefault(key, []).append(i)
-        none_key = (None, None, None)
-        none_idxs = groups.get(none_key)
-        if none_idxs is not None and len(groups) > 1 and all(
-                reqs[i] is None for i in none_idxs):
-            # lanes in the default group are all empty: don't spend a
-            # dispatch on them, fold into an arbitrary real group (outputs
-            # discarded)
-            groups.pop(none_key)
-            next(iter(groups.values())).extend(none_idxs)
-        return groups
-
-    # -- the packed fast path ---------------------------------------------
+    # -- the serving step ---------------------------------------------------
     #
-    # With a packed dispatcher (ForestReplicaServer.packed_factory) the hot
-    # loop stops re-assembling and re-uploading per-step state: feature
-    # rows and per-lane policy vectors are PERSISTENT device buffers,
-    # admits/retires stage donated splices, each bucket dispatch traces
-    # only the step's default-rung scalars, and one launch returns packed
-    # (next, hops, energy) per span — no logits download, no host argmax,
-    # no host pricing.  ``pipeline=True`` double-buffers the loop: step t's
+    # Per-slot state stays with the replicas: feature rows (or tokens and
+    # cache lengths) and per-lane policy knobs are staged at admission and
+    # retirement, each bucket dispatch carries only the step's default-rung
+    # scalars, and one call per replica returns (next, hops, energy) for
+    # its span.  ``pipeline=True`` double-buffers the loop: step t's
     # dispatch is harvested at the START of step t+1, so the host's
     # refill/splice/bookkeeping for t+1 overlaps device compute of t.  The
     # request -> (slot, dispatch) mapping is IDENTICAL to the synchronous
@@ -832,7 +667,13 @@ class ContinuousBatcher:
     # exactly the per-step batches they would have seen live, just later —
     # rung transitions therefore take effect at flush boundaries.
 
-    def _step_packed(self) -> int:
+    def step(self) -> int:
+        """One decode step across all active slots.  Returns #active.
+
+        With ``pipeline=True`` this harvests the PREVIOUS step's dispatch
+        and issues this step's — completions surface one ``step()`` call
+        later; :meth:`flush` drains the tail.
+        """
         pc = time.perf_counter_ns
         tr = self._trace
         with (span("serve.step", step=self.n_steps) if tr else NO_SPAN):
@@ -842,12 +683,12 @@ class ContinuousBatcher:
                     len(self.queue), self._n_slots - self._n_active))
                   if tr else NO_SPAN):
                 t1 = pc()
-                self._refill_packed()
+                self._refill()
                 t2 = pc()
             self.phase_ns["refill"] += t2 - t1
             if self._n_active:
                 with (span("serve.dispatch") if tr else NO_SPAN):
-                    self._dispatch_packed()
+                    self._dispatch()
                     t3 = pc()
                 self.phase_ns["dispatch"] += t3 - t2
                 if not self.pipeline:
@@ -855,7 +696,7 @@ class ContinuousBatcher:
         self.n_steps += 1
         return self._n_active
 
-    def _refill_packed(self) -> None:
+    def _refill(self) -> None:
         q = self.queue
         k = min(len(q), self._n_slots - self._n_active)
         if not k:
@@ -926,17 +767,16 @@ class ContinuousBatcher:
             self.counts["admit_groups"] += len(parts)
         self._lane_req[lanes] = np.fromiter(reqs, object, k)
         self._lane_left[lanes] = left
-        self._lengths[lanes] = 1        # one resident feature row per slot
-        # one vectorized staging write per replica for the whole burst
-        self.dispatcher.admit_lanes(
-            lanes, np.asarray([r.prompt for r in reqs], np.float32), thr, bud)
+        # one staging write per replica for the whole burst
+        self.dispatcher.admit_lanes(lanes, [r.prompt for r in reqs], thr,
+                                    bud)
 
     def _bucket_of(self, key: tuple) -> int:
         """The id of serving bucket ``key`` (model, version, precision);
         a bucket that holds no lanes joins the end of the dispatch order."""
         return self._live.setdefault(key, self._buckets[key])
 
-    def _dispatch_packed(self) -> None:
+    def _dispatch(self) -> None:
         default = (self.governor.current if self.governor is not None
                    else self.default_policy)
         def_thr = float(np.asarray(default.threshold))
@@ -991,7 +831,6 @@ class ContinuousBatcher:
         reqs = self._lane_req[occ].tolist()
         left = self._lane_left[occ] - 1
         self._lane_left[occ] = left
-        self._lengths[occ] += 1
         for r, tok, h in zip(reqs, nxt.tolist(), hops.tolist()):
             r.generated.append(tok)
             r.hops.append(h)
@@ -1046,8 +885,7 @@ class ContinuousBatcher:
                 fleet_batches.append(
                     (e, self.dispatcher.lane_devices(lanes)))
             elif ledger is not None:
-                # per-tenant governance, NaN for lanes no governor bills —
-                # identical grouping to the legacy inline _account
+                # per-tenant governance, NaN for lanes no governor bills
                 devices = self.dispatcher.lane_devices(lanes)
                 e = energy.copy()
                 for c, idx in _runs(tenant_of[mvs]):
@@ -1098,194 +936,10 @@ class ContinuousBatcher:
         replay ALL buffered telemetry.  After flush() the governor/ledger/
         registry/stats state is exactly what the synchronous per-step loop
         would hold — call it before reading telemetry mid-run and once
-        after the last step.  A no-op on the legacy (non-packed) path,
-        which accounts inline."""
-        if not self._packed:
-            return
+        after the last step."""
         if self._inflight:
             self._process_harvest()
         self._flush_telemetry()
-
-    def step(self) -> int:
-        """One decode step across all active slots.  Returns #active.
-
-        On the packed path with ``pipeline=True`` this harvests the
-        PREVIOUS step's dispatch and issues this step's — completions
-        surface one ``step()`` call later; :meth:`flush` drains the tail.
-        """
-        if self._packed:
-            return self._step_packed()
-        self._refill()
-        occ = np.flatnonzero(self._occ_mask).tolist()
-        if not occ:
-            return 0
-        tokens = self._tokens
-        lengths = self._lengths
-        if self._policy_mode == "dispatch":
-            # data-parallel plane: enqueue every (model, version,
-            # precision) bucket without blocking (per-device async
-            # dispatch), then harvest everything behind ONE deferred
-            # block_until_ready
-            base = self.lane_policy()
-            for (model, version, prec), idxs in self._bucket_groups().items():
-                pol = base if prec is None else base.replace(precision=prec)
-                bucket = None if model is None else (model, version)
-                self.dispatcher.dispatch(tokens, lengths, pol, idxs,
-                                         bucket=bucket)
-            logits, hops, self.last_dispatches = self.dispatcher.harvest(
-                self._n_slots)
-        elif self._policy_aware:
-            base = self.lane_policy()
-            groups = self._bucket_groups()
-            n = self._n_slots
-            logits, hops = None, None
-            for (model, version, prec), idxs in groups.items():
-                pol = base if prec is None else base.replace(precision=prec)
-                bucket = None if model is None else (model, version)
-                lg, hp = self._call_decode(tokens, lengths, pol,
-                                           bucket=bucket)
-                if len(groups) == 1:
-                    logits, hops = lg, hp
-                    break
-                if logits is None:
-                    logits = np.zeros(np.shape(lg), np.float32)
-                    hops = None if hp is None else np.zeros((n,), np.int64)
-                idxs = np.asarray(idxs)
-                logits[idxs] = np.asarray(lg)[idxs]
-                if hp is not None:
-                    hops[idxs] = np.asarray(hp)[idxs]
-        else:
-            logits, hops = self.decode_fn(jnp.asarray(tokens),
-                                          jnp.asarray(lengths))
-        if isinstance(logits, np.ndarray):
-            # dispatcher harvests host-side; keep the argmax off-device too
-            nxt = np.argmax(logits, axis=-1)
-        else:
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        hops = np.asarray(hops) if hops is not None else None
-        if hops is None and self.governor is not None:
-            raise ValueError(
-                "the governor needs hop telemetry but decode_fn returned "
-                "hops=None; the energy SLO cannot be enforced")
-        occa = np.asarray(occ, np.int64)
-        self._tokens[occa] = nxt[occa]
-        self._lengths[occa] += 1
-        # bulk host conversion: per-item ``int(arr[i])`` reads are ~10x the
-        # cost of one tolist() at serving-scale slot counts
-        nxt_l = nxt.tolist()
-        hops_l = hops.tolist() if hops is not None else None
-        step_hops, gone = [], []
-        now = time.perf_counter()
-        for i in occ:
-            req = self._lane_req[i]
-            tok = nxt_l[i]
-            req.generated.append(tok)
-            if hops_l is not None:
-                h = hops_l[i]
-                req.hops.append(h)
-                step_hops.append((h, req, i))
-            if tok == self.eos_id or len(req.generated) >= req.max_new_tokens:
-                req.done = True
-                if req.t_submit is not None:
-                    req.t_done = now
-                self.stats.note_done(req.tier)
-                self.completed.append(req)
-                gone.append(i)
-        if gone:
-            gone = np.asarray(gone, np.int64)
-            self._free_lanes(gone)
-            self._tokens[gone] = 0
-        if step_hops:
-            self._account(step_hops)
-        return self.active
-
-    def _call_decode(self, tokens, lengths, pol, bucket=None):
-        """One decode dispatch, honoring the fn's policy calling convention
-        (positional third arg vs KEYWORD_ONLY ``policy``) and passing the
-        (model, version) bucket only to bucket-aware fns."""
-        kw = {}
-        if bucket is not None:
-            kw["bucket"] = bucket
-        if self._policy_mode == "keyword":
-            return self.decode_fn(jnp.asarray(tokens), jnp.asarray(lengths),
-                                  policy=pol, **kw)
-        return self.decode_fn(jnp.asarray(tokens), jnp.asarray(lengths),
-                              pol, **kw)
-
-    def _account(self, step_hops: list) -> None:
-        """Fold one step's active-lane (hops, request, lane) tuples into
-        the fleet telemetry and let the governance plane react.  Each lane
-        is priced at ITS OWN effective precision — the request policy's,
-        falling back to its billing governor's active rung — so
-        mixed-precision batches are billed at the byte widths they
-        actually dispatched and an int8 step-down shows up as a measured
-        saving.  With a TenantLedger the telemetry is grouped by tenant
-        first: each tenant's governor sees only its own traffic, so one
-        tenant's expensive burst can never walk another tenant's ladder.
-        On the data-parallel plane each sample is additionally labeled
-        with its serving device for per-device rolling estimates; with a
-        registry, each (tenant, version) group also feeds its per-version
-        ServeStats (the canary-judging evidence)."""
-        hops = np.asarray([h for h, _, _ in step_hops])
-        tiers = [req.tier for _, req, _ in step_hops]
-        lanes = [lane for _, _, lane in step_hops]
-        devices = (self.dispatcher.lane_devices(lanes)
-                   if self.dispatcher is not None else None)
-        energy_pj = None
-
-        def price_into(out, gov, entries):
-            """Price ``entries`` (index, req) with one governor, grouping
-            by effective precision (one lane_pj call per precision)."""
-            rung_prec = gov.current.precision
-            groups: dict[str | None, list[int]] = {}
-            for i, req in entries:
-                prec = (req.policy.precision if req is not None
-                        and req.policy is not None else None)
-                groups.setdefault(
-                    prec if prec is not None else rung_prec, []).append(i)
-            for prec, idxs in groups.items():
-                out[idxs] = np.asarray(
-                    gov.model_for(prec).lane_pj(hops[idxs]))
-
-        if self.governor is not None:
-            energy_pj = np.empty(len(step_hops), np.float64)
-            price_into(energy_pj, self.governor,
-                       [(i, req) for i, (_, req, _) in enumerate(step_hops)])
-            self.governor.observe(energy_pj=energy_pj, devices=devices)
-            self.governor.step()
-        elif self.ledger is not None:
-            # per-tenant governance: group by tenant, price each group at
-            # its own governor's models, observe/step each independently.
-            # NaN marks lanes no governor bills (unledgered tenant, no
-            # default) — counted as events, excluded from energy means.
-            energy_pj = np.full(len(step_hops), np.nan)
-            by_tenant: dict[str | None, list[int]] = {}
-            for i, (_, req, _) in enumerate(step_hops):
-                by_tenant.setdefault(req.tenant, []).append(i)
-            for tenant, idxs in by_tenant.items():
-                gov = self.ledger.governor_for(tenant)
-                if gov is None:
-                    continue
-                price_into(energy_pj, gov,
-                           [(i, step_hops[i][1]) for i in idxs])
-                gov.observe(energy_pj=energy_pj[idxs],
-                            devices=None if devices is None
-                            else devices[idxs])
-                gov.step()
-        self.stats.update(hops, energy_pj, tiers=tiers)
-        if self._meter is not None:      # deprecated shim path
-            self._meter.update(hops)
-        if self.registry is not None:
-            by_version: dict[tuple, list[int]] = {}
-            for i, (_, req, _) in enumerate(step_hops):
-                if req.model is not None and req.version is not None:
-                    by_version.setdefault(
-                        (req.model, req.version), []).append(i)
-            for (tenant, version), idxs in by_version.items():
-                self.registry.stats_for(tenant, version).update(
-                    hops[idxs],
-                    None if energy_pj is None else energy_pj[idxs],
-                    tiers=[tiers[i] for i in idxs])
 
     def run(self, max_steps: int = 10000) -> list[Request]:
         steps = 0

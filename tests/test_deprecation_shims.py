@@ -3,9 +3,9 @@
 canonical ``FogEngine.eval(x, key, policy=FogPolicy(...))`` call.
 
 One test per shim — `fog_eval`, `fog_eval_multioutput`, `fog_eval_lazy`,
-`fog_ring_eval`, the positional ``eval(x, key, thresh, max_hops)`` form,
-`HopMeter`, and the batcher's ``meter=`` kwarg — so a future cleanup that
-drops a shim (or silences its warning) fails loudly.
+`fog_ring_eval`, the positional ``eval(x, key, thresh, max_hops)`` form
+and `HopMeter` — so a future cleanup that drops a shim (or silences its
+warning) fails loudly.
 """
 import warnings
 
@@ -106,48 +106,6 @@ def test_hop_meter_shim_warns_and_matches(gc, x128):
     assert meter.mean_hops == float(np.asarray(res.hops).mean())
 
 
-def test_batcher_meter_kwarg_warns_and_still_feeds(gc, x128):
-    from repro.serve.scheduler import ContinuousBatcher, Request
-
-    def decode_fn(tokens, lengths):
-        n = tokens.shape[0]
-        logits = np.zeros((n, 8), np.float32)
-        return jnp.asarray(logits), jnp.asarray(np.full((n,), 2))
-
-    with pytest.warns(DeprecationWarning, match="HopMeter is deprecated"):
-        meter = HopMeter()
-    with pytest.warns(DeprecationWarning, match="meter=.*deprecated"):
-        batcher = ContinuousBatcher(2, decode_fn,
-                                    lambda slot, prompt: len(prompt),
-                                    eos_id=-1, meter=meter)
-    batcher.submit(Request(rid=0, prompt=np.asarray([1]), max_new_tokens=2))
-    batcher.run()
-    # the shimmed meter and the canonical stats agree
-    assert meter.n_events == batcher.stats.n_events == 2
-    assert meter.mean_hops == batcher.stats.mean_hops == 2.0
-
-
-def test_batcher_meter_attribute_read_warns_and_matches():
-    """Legacy READERS of batcher.meter (never passed one in) get a working
-    shim seeded from stats, not an AttributeError."""
-    from repro.serve.scheduler import ContinuousBatcher, Request
-
-    def decode_fn(tokens, lengths):
-        n = tokens.shape[0]
-        return jnp.asarray(np.zeros((n, 8), np.float32)), \
-            jnp.asarray(np.full((n,), 3))
-
-    batcher = ContinuousBatcher(2, decode_fn,
-                                lambda slot, prompt: len(prompt), eos_id=-1)
-    batcher.submit(Request(rid=0, prompt=np.asarray([1]), max_new_tokens=2))
-    batcher.run()
-    with pytest.warns(DeprecationWarning, match="meter is deprecated"):
-        meter = batcher.meter
-    assert meter.n_events == batcher.stats.n_events == 2
-    assert meter.mean_hops == 3.0
-    assert "hops/event" in meter.summary(8)
-
-
 def test_canonical_calls_are_warning_free(gc, x128):
     """The replacement forms must not trip any DeprecationWarning."""
     key = jax.random.key(13)
@@ -158,7 +116,7 @@ def test_canonical_calls_are_warning_free(gc, x128):
             x128, key, policy=FogPolicy(threshold=0.3))
         # the serving path's canonical telemetry is warning-free too
         from repro.serve.scheduler import ContinuousBatcher
-        ContinuousBatcher(2, lambda t, l: (jnp.zeros((2, 8)), None),
+        ContinuousBatcher(2, lambda t, l, p: (jnp.zeros((2, 8)), None),
                           lambda slot, prompt: len(prompt))
 
 

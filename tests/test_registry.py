@@ -10,8 +10,9 @@ import pytest
 from repro.core import EnergyModel, FogPolicy, split
 from repro.forest import ForestPack
 from repro.registry import ModelRegistry, PackCache
-from repro.serve.dispatch import DeviceDispatcher, ForestReplicaServer
-from repro.serve.governor import EnergyGovernor, TenantLedger
+from repro.serve.dispatch import (DeviceDispatcher, ForestReplicaServer,
+                                  host_replica_factory)
+from repro.serve.governor import EnergyGovernor, TenantLedger, lane_pricer
 from repro.serve.scheduler import ContinuousBatcher, Request
 
 
@@ -320,17 +321,19 @@ def test_tenant_governors_independent_behind_one_dispatcher():
     ledger.add("a", gov_a)
     ledger.add("b", gov_b)
 
-    def factory(index, device, span):
-        def decode(tokens, lengths, policy, bucket=None):
-            thr = np.asarray(policy.lane_thresholds(span))
-            nxt = (np.asarray(tokens) + 1) % 16
-            logits = np.zeros((span, 16), np.float32)
-            logits[np.arange(span), nxt] = 1.0
-            hops = np.maximum(1, np.round(thr * 10)).astype(np.int64)
-            return jnp.asarray(logits), jnp.asarray(hops)
-        return decode
+    def decode(tokens, lengths, policy, bucket=None):
+        span = np.asarray(tokens).shape[0]
+        thr = np.asarray(policy.lane_thresholds(span))
+        nxt = (np.asarray(tokens) + 1) % 16
+        logits = np.zeros((span, 16), np.float32)
+        logits[np.arange(span), nxt] = 1.0
+        hops = np.maximum(1, np.round(thr * 10)).astype(np.int64)
+        return jnp.asarray(logits), jnp.asarray(hops)
 
-    disp = DeviceDispatcher(factory, [jax.devices()[0]] * 2)
+    disp = DeviceDispatcher(
+        host_replica_factory(decode, lambda s, p: len(p),
+                             price=lane_pricer(ledger)),
+        [jax.devices()[0]] * 2)
     b = ContinuousBatcher(4, None, lambda s, p: len(p), eos_id=-1,
                           governor=ledger, dispatcher=disp)
     for rid in range(8):
@@ -364,7 +367,7 @@ def test_registry_mode_server_serves_through_cache(trained, tmp_path):
     cache = PackCache(reg, budget_bytes=2 * p.table_bytes)
     server = ForestReplicaServer(None, ds.x_test.shape[1], backend="fused",
                                  registry=reg, cache=cache)
-    disp = DeviceDispatcher(server.factory, [jax.devices()[0]])
+    disp = DeviceDispatcher(server.packed_factory, [jax.devices()[0]])
     b = ContinuousBatcher(8, None, server.prefill, eos_id=-1,
                           default_policy=FogPolicy(threshold=0.7),
                           dispatcher=disp, registry=reg)

@@ -65,7 +65,7 @@ def _old_rule(n_slots, schedule):
     return placed
 
 
-@pytest.mark.parametrize("path", ["packed", "legacy"])
+@pytest.mark.parametrize("path", ["packed", "host"])
 def test_one_replica_places_as_the_old_heap(trained, path):
     ds, rf = trained
     n_slots = 8
@@ -89,7 +89,7 @@ def test_one_replica_places_as_the_old_heap(trained, path):
             placed.append((int(prompt[0]), slot))
             return 1
 
-        def decode(tokens, lengths):
+        def decode(tokens, lengths, policy):
             return (jax.numpy.ones((n_slots, 4), jax.numpy.float32),
                     jax.numpy.ones((n_slots,), jax.numpy.int32))
 

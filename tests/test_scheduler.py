@@ -1,4 +1,9 @@
-"""Continuous-batching scheduler (serve/scheduler.py)."""
+"""Continuous-batching scheduler (serve/scheduler.py).
+
+The mocks are host ``decode_fn(tokens, lengths, policy)`` callables; the
+batcher serves them through a one-replica dispatcher over
+:class:`~repro.serve.dispatch.HostReplica`, the same step, dispatch /
+harvest cycle and telemetry the forest's packed replicas run."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +14,7 @@ from repro.serve.scheduler import ContinuousBatcher, Request
 
 def _mock_decode(n_slots, vocab=16, eos=1):
     """Deterministic mock: token t -> (t+1) % vocab; hops = 1 + slot%3."""
-    def decode_fn(tokens, lengths):
+    def decode_fn(tokens, lengths, policy):
         nxt = (np.asarray(tokens) + 1) % vocab
         logits = np.zeros((n_slots, vocab), np.float32)
         logits[np.arange(n_slots), nxt] = 1.0
@@ -289,17 +294,6 @@ def test_per_lane_default_policy_rejected_at_construction():
                               threshold=jnp.asarray([0.1, 0.2])))
 
 
-def test_legacy_two_arg_decode_fn_still_works():
-    """decode_fn(tokens, lengths) callers predate the policy plumbing."""
-    n = 2
-    batcher = ContinuousBatcher(n, _mock_decode(n),
-                                lambda slot, prompt: len(prompt), eos_id=-1)
-    assert not batcher._policy_aware
-    batcher.submit(Request(rid=0, prompt=np.asarray([2]), max_new_tokens=2))
-    done = batcher.run()
-    assert len(done) == 1 and len(done[0].generated) == 2
-
-
 # ---------------------------------------------------------------------------
 # energy governance (the EnergyGovernor control plane)
 # ---------------------------------------------------------------------------
@@ -357,11 +351,12 @@ def test_governor_steps_down_ladder_and_holds_budget():
 
 def test_governor_rejects_ungovernable_decode_paths():
     """A governor that can never act must fail loudly, not serve at full
-    energy under the illusion of an SLO: legacy two-arg decode_fns are
-    rejected at construction, hop-less telemetry on the first step."""
-    with pytest.raises(ValueError, match="policy-aware"):
+    energy under the illusion of an SLO: a governor without an energy model
+    is rejected at construction, hop-less telemetry on the first step."""
+    from repro.serve.governor import EnergyGovernor
+    with pytest.raises(ValueError, match="energy model"):
         ContinuousBatcher(2, _mock_decode(2), lambda slot, prompt: 1,
-                          governor=_governor(budget_nj=1.0))
+                          governor=EnergyGovernor([FogPolicy()], 1.0))
 
     def no_hops(tokens, lengths, policy):
         n = tokens.shape[0]
@@ -439,57 +434,7 @@ def test_per_request_energy_budget_resolved_via_governor():
                                energy_budget_nj=1.0))
 
 
-# -- serving-layer bug sweep: calling conventions, admission, stats --------
-
-def test_policy_mode_detects_kwonly_partial_and_jit():
-    """The positional-count heuristic must not misclassify the common
-    wrapper shapes: KEYWORD_ONLY ``*, policy``, functools.partial-bound
-    leading args, and jax.jit wrappers (signature follows __wrapped__)."""
-    import functools
-
-    import jax as _jax
-
-    from repro.serve.scheduler import _policy_mode, _takes_policy
-
-    def kwonly(tokens, lengths, *, policy):
-        return None, None
-
-    def positional(state, tokens, lengths, policy):
-        return None, None
-
-    def legacy(tokens, lengths):
-        return None, None
-
-    assert _policy_mode(kwonly) == "keyword"
-    assert _policy_mode(functools.partial(positional, {})) == "positional"
-    assert _policy_mode(_jax.jit(positional, static_argnums=0)) \
-        == "positional"
-    assert _policy_mode(_jax.jit(legacy)) == "legacy"
-    assert _takes_policy(kwonly) and not _takes_policy(legacy)
-
-
-def test_kwonly_policy_decode_fn_served_policy():
-    """A ``decode_fn(tokens, lengths, *, policy)`` must receive the
-    assembled per-lane policy (it used to be silently demoted to the
-    legacy no-policy path by the 3-positional-params check)."""
-    n = 2
-    seen = []
-
-    def decode_fn(tokens, lengths, *, policy):
-        assert policy is not None
-        seen.append(np.asarray(policy.lane_thresholds(n)))
-        logits = np.zeros((n, 8), np.float32)
-        logits[:, 2] = 1.0
-        return jnp.asarray(logits), jnp.ones((n,), jnp.int32)
-
-    b = ContinuousBatcher(n, decode_fn, lambda slot, prompt: len(prompt),
-                          eos_id=-1, default_policy=FogPolicy(threshold=0.3))
-    b.submit(Request(rid=0, prompt=np.asarray([0]), max_new_tokens=1,
-                     policy=FogPolicy(threshold=0.9)))
-    b.step()
-    assert len(b.completed) == 1
-    np.testing.assert_allclose(seen[0], [0.9, 0.3])
-
+# -- serving-layer bug sweep: admission, stats ------------------------------
 
 def test_admission_reject_sheds_incoming():
     n = 1
@@ -587,3 +532,120 @@ def test_mean_energy_nj_divides_by_priced_events_only():
     assert stats.mean_energy_nj == pytest.approx(2.0)
     stats.reset()
     assert stats.n_priced == 0 and stats.mean_energy_nj == 0.0
+
+
+# -- the one step reproduces the retired per-request step ------------------
+#
+# Each scenario below was run once on the scheduler's former second step (a
+# per-request refill, one decode call per bucket with full-batch per-lane
+# vectors, host argmax, host pricing and inline per-request accounting) and
+# its results pinned here.  The one step must reproduce them: the same
+# tokens and hops per request, the same completion order, the same
+# telemetry totals and governor transitions.  Energy is priced in float32
+# on the replica now (as the forest's is), hence its tolerance.
+
+_PIN_VOCAB, _PIN_EOS = 11, 7
+_PIN_CODE = {None: 0, "fp32": 1, "int8": 2}
+_PIN_POLICIES = [FogPolicy(threshold=0.3, precision="int8"), None,
+                 FogPolicy(threshold=0.6, precision="fp32")]
+
+_PINNED = {
+    "governor": {
+        "order": [0, 1, 5, 4, 2, 3, 8, 6, 9, 7, 12, 10, 13, 11],
+        "generated": {0: [4, 6], 1: [5, 7], 5: [7], 4: [5, 9],
+                      2: [9, 9, 10, 3], 3: [2, 0, 6, 3, 6], 8: [5, 8],
+                      6: [0, 5, 10, 4], 9: [9, 10, 3], 7: [3, 3, 4, 8, 10],
+                      12: [7], 10: [1, 8, 8, 9], 13: [10, 2, 1],
+                      11: [3, 2, 0, 6, 3]},
+        "hops": {0: [5, 5], 1: [5, 2], 5: [7], 4: [4, 3], 2: [7, 7, 7, 7],
+                 3: [5, 5, 5, 5, 5], 8: [7, 7], 6: [5, 5, 5, 5],
+                 9: [5, 5, 5], 7: [3, 3, 3, 3, 3], 12: [5],
+                 10: [3, 3, 3, 3], 13: [3, 3, 3], 11: [7, 7, 7, 7, 7]},
+        "stats": (209, 43, 43, 61201.99),
+        "tiers": {"bulk": (7, 24, 1.3760595833333331),
+                  "gold": (7, 19, 1.4829768421052634)},
+        "transitions": {"fleet": [(0, 1), (1, 2), (2, 3)]},
+    },
+    "ledger": {
+        "order": [0, 1, 4, 2, 5, 3, 8, 7, 9, 6, 12, 10, 13, 11],
+        "generated": {0: [5, 10], 1: [6, 0, 7], 4: [4, 5], 2: [0, 6, 3, 6],
+                      5: [8, 7], 3: [4, 8, 10, 6, 6], 8: [7], 7: [4, 7],
+                      9: [0, 7], 6: [1, 9, 1, 0], 12: [8, 8],
+                      10: [0, 4, 6, 2], 13: [0, 6, 3], 11: [4, 6, 2, 2, 3]},
+        "hops": {0: [6, 6], 1: [6, 3, 5], 4: [4, 4], 2: [9, 9, 9, 9],
+                 5: [8, 8], 3: [7, 7, 7, 7, 7], 8: [9], 7: [2, 2],
+                 9: [7, 7], 6: [6, 6, 6, 6], 12: [6, 6], 10: [4, 4, 4, 4],
+                 13: [2, 2, 2], 11: [8, 8, 8, 8, 8]},
+        "stats": (246, 41, 28, 47944.26),
+        "tiers": {"bulk": (7, 22, 1.3340686666666668),
+                  "gold": (7, 19, 2.14871)},
+        "transitions": {"a": [(0, 1), (1, 2), (2, 3)], "bb": []},
+    },
+}
+
+
+def _pin_decode(tokens, lengths, policy, bucket=None):
+    """Tokens depend on each lane's token, length, precision and tenant;
+    hops on its threshold, hop budget, precision and tenant."""
+    n = np.asarray(tokens).shape[0]
+    code = _PIN_CODE[policy.precision] + (0 if bucket is None
+                                          else len(bucket[0]))
+    thr = np.asarray(policy.lane_thresholds(n))
+    bud = np.asarray(policy.lane_budgets(n))
+    hops = np.minimum(np.maximum(1, np.round(thr * 10)).astype(np.int64)
+                      + code, bud)
+    nxt = (np.asarray(tokens) * 3 + np.asarray(lengths) + code) % _PIN_VOCAB
+    logits = np.zeros((n, _PIN_VOCAB), np.float32)
+    logits[np.arange(n), nxt] = 1.0
+    return jnp.asarray(logits), jnp.asarray(hops)
+
+
+def _pin_governor(budget_nj):
+    from repro.core import EnergyModel
+    from repro.serve.governor import EnergyGovernor, default_ladder
+    model = EnergyModel(2, 8, 10, 16)
+    return EnergyGovernor(
+        default_ladder(FogPolicy(threshold=0.5), model, budget_nj),
+        budget_nj, model=model, window=4, patience=2, cooldown=10_000)
+
+
+@pytest.mark.parametrize("kind", ["governor", "ledger"])
+def test_one_step_reproduces_the_retired_step(kind):
+    """Two precisions, EOS and max_new_tokens > 1, under a fleet governor
+    that steps down three rungs, or a ledger of two tenants (one stepping
+    down, rung-stamped lanes moving to its int8 bucket) beside an
+    unledgered third whose lanes go unpriced."""
+    from repro.serve.governor import TenantLedger
+    if kind == "governor":
+        gov = _pin_governor(1.2)
+        govs = {"fleet": gov}
+    else:
+        gov = TenantLedger()
+        govs = {"a": gov.add("a", _pin_governor(0.9)),
+                "bb": gov.add("bb", _pin_governor(50.0))}
+    b = ContinuousBatcher(4, _pin_decode, lambda slot, prompt: len(prompt),
+                          eos_id=_PIN_EOS, governor=gov)
+    for rid in range(14):
+        kw = {}
+        if kind == "ledger":
+            kw = dict(model=("a", "bb", "c")[(rid // 2) % 3], version=1)
+        b.submit(Request(rid=rid, prompt=np.asarray([rid % 5, rid]),
+                         max_new_tokens=2 + rid % 4,
+                         policy=_PIN_POLICIES[rid % 3],
+                         tier=("gold", "bulk")[rid % 2], **kw))
+    done = b.run()
+    want = _PINNED[kind]
+    assert [r.rid for r in done] == want["order"]
+    assert {r.rid: r.generated for r in done} == want["generated"]
+    assert {r.rid: r.hops for r in done} == want["hops"]
+    st = b.stats
+    assert (st.total_hops, st.n_events, st.n_priced) == want["stats"][:3]
+    assert st.total_pj == pytest.approx(want["stats"][3], rel=1e-6)
+    ts = st.tier_summary()
+    assert set(ts) == set(want["tiers"])
+    for tier, (n_done, n_events, nj) in want["tiers"].items():
+        assert (ts[tier]["n_done"], ts[tier]["n_events"]) == (n_done,
+                                                              n_events)
+        assert ts[tier]["mean_energy_nj"] == pytest.approx(nj, rel=1e-6)
+    assert {k: [t[:2] for t in g.transitions]
+            for k, g in govs.items()} == want["transitions"]

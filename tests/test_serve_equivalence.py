@@ -330,7 +330,7 @@ class _PerRequest(ContinuousBatcher):
             if not q:
                 return
 
-    def _refill_packed(self):
+    def _refill(self):
         if not self.queue or self._n_active == self._n_slots:
             return
         lanes, rows, thrs, buds = [], [], [], []
@@ -358,7 +358,7 @@ class _PerRequest(ContinuousBatcher):
         self.dispatcher.admit_lanes(np.asarray(lanes, np.int64),
                                     np.asarray(rows, np.float32), thrs, buds)
 
-    def _dispatch_packed(self):
+    def _dispatch(self):
         default = (self.governor.current if self.governor is not None
                    else self.default_policy)
         def_thr = float(np.asarray(default.threshold))

@@ -231,14 +231,14 @@ def test_sub_phases_fit_inside_their_phase(trained, monkeypatch, trace):
 
 
 def _mock_decode(n_slots):
-    def decode_fn(tokens, lengths):
+    def decode_fn(tokens, lengths, policy):
         logits = np.zeros((n_slots, 4), np.float32)
         logits[:, 2] = 1.0
         return jnp.asarray(logits), jnp.ones((n_slots,), jnp.int32)
     return decode_fn
 
 
-@pytest.mark.parametrize("path", ["packed", "legacy"])
+@pytest.mark.parametrize("path", ["packed", "host"])
 def test_admission_stamp_lies_between_submit_and_done(trained, path):
     if path == "packed":
         b = _plane(trained, 8)
