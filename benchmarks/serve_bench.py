@@ -11,9 +11,9 @@ windows, and admission control, and emits ``BENCH_serve.json`` with one row
 per (n_devices, precision, governor) config: throughput (req/s), p50/p99
 latency, mean nJ/request, shed rate.
 
-The serving hot loop under test is the device-resident packed path: slot
-feature rows and per-lane policy vectors live on the devices and are
-updated by staged splices, each dispatch is ONE jitted program per device
+The serving hot loop under test is the packed path: slot feature rows and
+per-lane policy knobs are staged on the host and uploaded as one span per
+replica when they change, each dispatch is ONE jitted program per device
 returning packed ``(next, hops, energy)`` (argmax + pricing in-jit, no
 logits download), and the batcher runs ``pipeline=True`` — step t's
 dispatch is harvested at the start of step t+1 so host bookkeeping for
@@ -105,7 +105,7 @@ def _percentile(xs, q):
 class _Plane:
     """One (n_devices, n_slots)-keyed serving plane, shared across the grid
     rows so each (span, precision) program compiles exactly once.  Built on
-    the packed (device-resident) replica protocol; per-precision service
+    the packed replica protocol; per-precision service
     times are calibrated LAZILY — a row pays only for the precisions its
     traffic mix can actually dispatch (``ensure_svc``)."""
 
@@ -128,8 +128,8 @@ class _Plane:
 
     def _warm_full_path(self, precisions):
         """Drain one throwaway batcher burst through the REAL pipelined
-        step path (admit splices, per-precision dispatch, harvest, deferred
-        telemetry flush) so the first timed capacity probe pays zero
+        step path (admits, span uploads, per-precision dispatch, harvest,
+        deferred telemetry flush) so the first timed capacity probe pays zero
         first-step costs — every replica's program compiles here."""
         from repro.core.policy import FogPolicy
         from repro.serve.scheduler import ContinuousBatcher, Request
@@ -149,46 +149,12 @@ class _Plane:
         while b.active or b.queue:
             b.step()
         b.flush()
-        self._warm_splice_sizes(precisions[0])
-
-    def _warm_splice_sizes(self, prec):
-        """Compile every staged-splice program the real loop can hit.
-        Admit/retire splices pad their lane index to the next power of
-        two, and the saturated warm burst above only ever refills FULL
-        spans — so the size-1, 2, 4, ... programs would otherwise compile
-        lazily inside the measured window (tens of ms each, per device
-        buffer shape)."""
-        import numpy as np
-        from repro.core.policy import NO_BUDGET
-        span = self.dispatcher.span
-        n_dev = self.dispatcher.n_devices
-        rows = np.resize(self.ds.x_test.astype(np.float32),
-                         (span, self.ds.x_test.shape[1]))
-        all_lanes = np.arange(self.n_slots, dtype=np.int64)
-        size = 1
-        while size <= span:
-            lanes = np.concatenate([d * span + np.arange(size)
-                                    for d in range(n_dev)]).astype(np.int64)
-            k = len(lanes)
-            self.dispatcher.admit_lanes(
-                lanes, np.resize(rows[:size], (k, rows.shape[1])),
-                np.full((k,), BASE_THRESH, np.float32),
-                np.full((k,), NO_BUDGET, np.int32))
-            self.dispatcher.dispatch_packed(all_lanes, BASE_THRESH,
-                                            NO_BUDGET, precision=prec)
-            self.dispatcher.harvest_packed(self.n_slots)
-            size *= 2
-        # retire staging reuses the same per-size policy-splice programs
-        self.dispatcher.retire_lanes(all_lanes)
-        self.dispatcher.dispatch_packed(all_lanes, BASE_THRESH, NO_BUDGET,
-                                        precision=prec)
-        self.dispatcher.harvest_packed(self.n_slots)
 
     def ensure_svc(self, prec: str) -> None:
         """Calibrate one precision's sequential per-dispatch service time
         on demand: admit real feature rows onto device 0's span, warm the
         (already compiled) program, best-of-5 a single dispatch+harvest,
-        then retire the lanes.  Splice application happens on the warmup
+        then retire the lanes.  The span upload happens on the warmup
         dispatches, so the timed number is pure steady-state device
         compute — exactly what the virtual clock must not double-count."""
         if prec in self.svc:

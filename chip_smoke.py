@@ -263,7 +263,7 @@ def replica_placement(dispatcher) -> str:
     parts = []
     for i, fn in enumerate(dispatcher.replicas):
         devs = {k: sorted(d.id for d in v.devices())
-                for k, v in fn.state.items()}
+                for k, v in fn.state.items() if v is not None}
         parts.append(f"r{i}@{dispatcher.devices[i].id}:{devs}")
     return " ".join(parts)
 

@@ -295,7 +295,8 @@ def _eval_core(pack: ForestPack, x, start, thresh, budget, max_hops: int,
 
 
 # --------------------------------------------------------------------------
-# device-resident lane state (the serving plane's donated splice path)
+# device-resident lane state: row splices by lane index (no serving
+# replica calls these; the forest replica uploads its whole span)
 # --------------------------------------------------------------------------
 
 @partial(jax.jit, donate_argnums=(0,))

@@ -189,12 +189,11 @@ class FogPolicy:
 # -- device-resident per-lane policy state (the packed serving path) -------
 #
 # The continuous batcher's packed fast path keeps each span's per-lane
-# threshold / hop-budget vectors RESIDENT on the serving device and splices
-# only the lanes that changed (admit / retire), instead of re-assembling and
-# re-uploading full vectors every step.  Lanes without an explicit
-# per-request policy carry sentinels — NaN threshold / negative budget —
-# that the jitted dispatch resolves against the step's default rung
-# (``jnp.where``), so a governor rung change never forces a re-splice.
+# threshold / hop-budget vectors with the replica, written at admit /
+# retire instead of re-assembled from the requests every step.  Lanes
+# without an explicit per-request policy carry sentinels — NaN threshold /
+# negative budget — that the jitted dispatch resolves against the step's
+# default rung (``jnp.where``), so a governor rung change rewrites no lane.
 # Retired lanes are stamped DEAD: threshold -1 confirms on the first hop
 # (MaxDiff >= 0 > -1 always) and budget 1 hard-caps it, so empty lanes cost
 # one hop and compact away instead of walking the default policy.
@@ -220,10 +219,12 @@ def lane_knobs(policy: "FogPolicy | None") -> tuple[float, int]:
 
 
 class LanePolicies:
-    """Host mirror of one span's resident per-lane policy vectors, with
-    dirty-lane tracking: the serving replica drains :meth:`take_dirty` into
-    a donated device splice right before each dispatch.  All lanes start
-    DEAD (the span serves nothing until admits arrive)."""
+    """One span's per-lane policy vectors, with dirty-lane tracking for a
+    device splice of the lanes staged since the last one
+    (:func:`~repro.core.engine.splice_slot_state`).  No serving replica
+    uses it: the forest replica stages its knobs beside its rows and
+    uploads the whole span.  All lanes start DEAD (the span serves nothing
+    until admits arrive)."""
 
     def __init__(self, n: int):
         self.n = int(n)

@@ -31,7 +31,8 @@ device.  On CPU-only hosts (CI), force a mesh with
 :func:`repro.launch.mesh.serve_devices`.
 
 Two replica kinds: :meth:`ForestReplicaServer.packed_factory`, the paper's
-workload (resident feature rows and knobs, one fused launch per call), and
+workload (feature rows and knobs staged on the host, uploaded as one span
+when they change, one fused launch per call), and
 :class:`HostReplica` (:func:`host_replica_factory`), which runs a host
 ``decode_fn(tokens, lengths, policy)`` — an LM decode step, a test mock —
 behind the same protocol.
@@ -50,8 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.policy import (BUDGET_DEFAULT, NO_BUDGET, THRESH_DEFAULT,
-                               FogPolicy, LanePolicies)
+from repro.core.policy import (BUDGET_DEFAULT, DEAD_BUDGET, DEAD_THRESH,
+                               NO_BUDGET, THRESH_DEFAULT, FogPolicy)
 from repro.kernels.fused_fog import walked_lane_hops
 from repro.kernels.tree_traverse import lane_block
 
@@ -93,6 +94,9 @@ class Pending:
     bucket: tuple | None = None
     # outputs whose device-to-host copy was started at enqueue
     prefetched: int = 0
+    # bytes of slot state the replica uploaded right before this call (0:
+    # the call reuses the upload of an earlier call)
+    uploaded: int = 0
 
 
 class DeviceDispatcher:
@@ -102,7 +106,9 @@ class DeviceDispatcher:
         The factory builds ONE replica of the packed protocol, placing its
         state on ``device``.  A replica has ``admit_many(local, rows, thr,
         bud)`` and ``retire_many(local)`` (staged slot updates; ``rows``
-        None re-stamps knobs only), ``splice()`` (apply what is staged),
+        None re-stamps knobs only), ``splice()`` (make what is staged
+        visible to the next call; returns the bytes it uploaded, 0 when
+        nothing changed since the last upload),
         ``enqueue(def_thresh, def_budget, precision, bucket=, local=)``
         (one call over the whole span, returning ``(next, hops, energy)``
         device arrays without blocking; ``local`` are the lanes harvested
@@ -230,7 +236,7 @@ class DeviceDispatcher:
             with (span("serve.dispatch.splice", replica=d) if tr
                   else NO_SPAN):
                 t0 = pc()
-                fn.splice()
+                uploaded = fn.splice()
                 t1 = pc()
             with (span("serve.dispatch.enqueue", replica=d,
                        lanes=len(mine), precision=precision) if tr
@@ -252,7 +258,8 @@ class DeviceDispatcher:
             nxt, hops, energy = outs
             p = Pending(device=d, precision=precision, lanes=mine,
                         local=local, nxt=nxt, hops=hops, energy=energy,
-                        bucket=bucket, prefetched=len(outs))
+                        bucket=bucket, prefetched=len(outs),
+                        uploaded=uploaded)
             self._queues[d].append(p)
             out.append(p)
         return out
@@ -267,9 +274,11 @@ class DeviceDispatcher:
         host boundary and nothing is re-priced here.  With ``counts``, adds
         the lane-hops the fused kernel walked over each call's whole span
         (``lane_hops_walked``) and those of the lanes harvested from it
-        (``lane_hops_served``), the outputs harvested (``host_copies``) and
+        (``lane_hops_served``), the outputs harvested (``host_copies``),
         those whose copy was started at enqueue
-        (``host_copies_prefetched``)."""
+        (``host_copies_prefetched``), and the slot-state uploads made for
+        the calls (``span_uploads``) with their bytes
+        (``span_upload_bytes``)."""
         pending = [p for q in self._queues for p in q]
         for q in self._queues:
             q.clear()
@@ -305,6 +314,9 @@ class DeviceDispatcher:
                 counts["lane_hops_served"] += int(h[p.local].sum())
                 counts["host_copies"] += 3      # nxt, hops, energy
                 counts["host_copies_prefetched"] += p.prefetched
+                if p.uploaded:
+                    counts["span_uploads"] += 1
+                    counts["span_upload_bytes"] += p.uploaded
         return nxt, hops, energy, pending
 
 
@@ -391,8 +403,9 @@ class HostReplica:
         self.thresh[local] = THRESH_DEFAULT
         self.budget[local] = BUDGET_DEFAULT
 
-    def splice(self) -> None:
-        """Nothing to apply: admits and retires write the host state."""
+    def splice(self) -> int:
+        """Nothing to upload: admits and retires write the host state."""
+        return 0
 
     def enqueue(self, def_thresh, def_budget, precision=None, bucket=None,
                 local=None):
@@ -441,21 +454,26 @@ def host_replica_factory(decode_fn: Callable, prefill_fn: Callable,
 
 @partial(jax.jit,
          static_argnames=("max_hops", "backend", "block_b"))
-def _serve_eval_packed(pack, x, key, step, thresh, budget, def_thresh,
-                       def_budget, per_hop_pj, transfer_pj, max_hops: int,
+def _serve_eval_packed(pack, staged, key, step, def_thresh, def_budget,
+                       per_hop_pj, transfer_pj, max_hops: int,
                        backend: str, block_b: int):
     """The packed protocol's whole decode step as ONE jitted program over
-    RESIDENT span state: start-grove draw, per-lane default resolution
-    (NaN-threshold / negative-budget lanes take the step's default rung
-    scalars), Algorithm-2 evaluation, argmax, and affine energy pricing —
-    so a dispatch uploads nothing (the step counter lives on device and
-    the default knobs are cached device scalars) and downloads three
-    [span] vectors instead of round-tripping rows, policy vectors and
-    [span, C] logits.  Returns ``(next, hops, energy, step + 1)`` — the
-    caller feeds the incremented counter straight back in, keeping the
-    whole dispatch on jax's fast path with zero host->device scalar
-    conversions per call."""
+    the replica's UPLOADED span state: ``staged`` is ``[span, F + 2]``
+    float32, the feature rows in columns ``:F``, the lane thresholds in
+    column ``F`` and the hop budgets' int32 bits in column ``F + 1``.
+    Start-grove draw, per-lane default resolution (NaN-threshold /
+    negative-budget lanes take the step's default rung scalars),
+    Algorithm-2 evaluation, argmax, and affine energy pricing run in one
+    launch, and three [span] vectors come back instead of [span, C]
+    logits.  Returns ``(next, hops, energy, step + 1)`` — the step
+    counter lives on the device and the caller feeds the incremented
+    counter straight back in, so a call converts no host scalar."""
     from repro.core.engine import _eval_core
+    n_features = staged.shape[1] - 2
+    x = staged[:, :n_features]
+    thresh = staged[:, n_features]
+    budget = jax.lax.bitcast_convert_type(staged[:, n_features + 1],
+                                          jnp.int32)
     start = jax.random.randint(jax.random.fold_in(key, step),
                                (x.shape[0],), 0, pack.n_groves)
     thr = jnp.where(jnp.isnan(thresh), def_thresh, thresh)
@@ -530,7 +548,7 @@ class ForestReplicaServer:
         self.n_features = int(n_features)
         self.backend = backend
         self.seed = seed
-        self._buffers: dict[int, np.ndarray] = {}
+        self._replicas: dict[int, types.SimpleNamespace] = {}
         self._span: int | None = None
         self._energy_models: dict[tuple, object] = {}
         # (precision, bucket) -> (per_hop_pj, transfer_pj) float32 scalars
@@ -591,37 +609,37 @@ class ForestReplicaServer:
         return c
 
     def packed_factory(self, index: int, device, span: int):
-        """Packed-protocol replica: per-slot feature rows and policy
-        vectors live as PERSISTENT device buffers, updated in place via
-        donated splices when the batcher admits/retires lanes
-        (:func:`~repro.core.engine.splice_slot_state`), and each dispatch
-        runs
-        :func:`_serve_eval_packed` — start draw, default resolution,
-        evaluation, argmax and energy pricing in one launch: a step uploads
-        no rows or policy vectors, and only three [span] vectors come back
-        per dispatch.
-        The splice is compiled here, on ``device``, at every padded width a
-        burst can take, so no replica compiles one while serving.
+        """Packed-protocol replica: per-slot feature rows and policy knobs
+        live in ONE host staging buffer, ``[span, F + 2]`` float32 (rows,
+        then thresholds, then the hop budgets' int32 bits), which admits,
+        retires and :meth:`prefill` write.  ``splice()`` uploads the whole
+        staged span in one transfer when anything was written since the
+        last upload, and reuses that upload otherwise (a step's second
+        bucket call transfers nothing); each dispatch runs
+        :func:`_serve_eval_packed` over it — start draw, default
+        resolution, evaluation, argmax and energy pricing in one launch —
+        and only three [span] vectors come back per dispatch.  Every
+        program's shapes are fixed by ``span`` and ``F``, so ``bind``
+        compiles nothing here.
         """
-        from repro.core.engine import splice_slot_state
         self._span = span
+        F = self.n_features
         packs = {p: jax.device_put(pack, device)
                  for p, pack in self._packs.items()}
         key = jax.device_put(jax.random.key(self.seed + index), device)
         backend = self.backend
         block_b = min(256, span)
-        lp = LanePolicies(span)
-        # resident state; the splice path DONATES, so references live in one
-        # mutable cell the closures rebind.  The per-replica step counter
-        # is device-resident too: the eval returns step+1 and the closure
-        # feeds it straight back — no host scalar crosses per dispatch.
-        state = {
-            "x": jax.device_put(
-                jnp.zeros((span, self.n_features), jnp.float32), device),
-            "thr": jax.device_put(jnp.asarray(lp.thresh), device),
-            "bud": jax.device_put(jnp.asarray(lp.budget), device),
-            "step": jax.device_put(jnp.int32(1), device),
-        }
+        stage = np.zeros((span, F + 2), np.float32)
+        rows, thresh = stage[:, :F], stage[:, F]
+        budget = stage[:, F + 1].view(np.int32)
+        thresh[:] = DEAD_THRESH             # every lane starts DEAD
+        budget[:] = DEAD_BUDGET
+        # the per-replica step counter is device-resident: the eval
+        # returns step+1 and the closure feeds it straight back, so no
+        # host scalar crosses per dispatch.  ``upload`` is the last upload
+        # of ``stage``, None once a write made it stale
+        state = {"step": jax.device_put(jnp.int32(1), device),
+                 "upload": None}
         # cached device conversions of the step's default knob scalars
         # (governor rungs form a small set; np scalars hash by value)
         knob_cache: dict[tuple, tuple] = {}
@@ -629,47 +647,33 @@ class ForestReplicaServer:
         # committed to another replica's device would be re-transferred on
         # every dispatch, which dwarfs the eval enqueue itself
         hop_cache: dict[tuple, tuple] = {}
-        # host mirror of the resident feature rows: the staging target for
-        # admits (one vectorized write per burst), the row source for the
-        # fused splice, and what prefill()-style callers (calibration) read
-        mirror = np.zeros((span, self.n_features), np.float32)
-        self._buffers[index] = mirror
-        # compile the splice at every padded width it can take, on THIS
-        # device, now: which widths a replica meets depends on where the
-        # router puts each burst, and a splice compiled mid-serving stalls
-        # the step that needs it (outputs discarded, inputs not donated)
-        for width in {min(1 << k, span) for k in range(span.bit_length() + 1)}:
-            idx = np.arange(width)
-            splice_slot_state(state["x"], state["thr"], state["bud"], idx,
-                              mirror[idx], lp.thresh[idx], lp.budget[idx],
-                              donate=False)
 
-        def admit_many(locals_, rows, thr, bud) -> None:
-            if rows is not None:
-                rows = np.asarray(rows, np.float32)
-                if rows.shape[-1] != self.n_features:
+        def admit_many(locals_, prompts, thr, bud) -> None:
+            if prompts is not None:
+                prompts = np.asarray(prompts, np.float32)
+                if prompts.shape[-1] != F:
                     raise ValueError(
-                        f"request feature rows have {rows.shape[-1]} "
-                        f"features, server expects {self.n_features}")
-                mirror[locals_] = rows
-            lp.stamp_many(locals_, thr, bud)
+                        f"request feature rows have {prompts.shape[-1]} "
+                        f"features, server expects {F}")
+                rows[locals_] = prompts
+            thresh[locals_] = thr
+            budget[locals_] = bud
+            state["upload"] = None
 
         def retire_many(locals_) -> None:
-            lp.retire_many(locals_)
+            # the rows stay: a DEAD lane exits on hop 1 whatever it holds
+            thresh[locals_] = DEAD_THRESH
+            budget[locals_] = DEAD_BUDGET
+            state["upload"] = None
 
-        def _apply_staged() -> None:
-            # one FUSED splice over all three buffers, driven by the knob
-            # dirty set (every row admit also stamps knobs, so it covers
-            # the row writes; rows come from the mirror, which is current
-            # for admitted lanes and harmlessly stale for retired ones).
-            # donate=False: the PREVIOUS dispatch may still be reading
-            # these buffers (double-buffered pipeline) — donating would
-            # stall the enqueue until it drains
-            if lp.dirty:
-                idx, thr, bud = lp.take_dirty()
-                state["x"], state["thr"], state["bud"] = splice_slot_state(
-                    state["x"], state["thr"], state["bud"],
-                    idx, mirror[idx], thr, bud, donate=False)
+        def upload() -> int:
+            if state["upload"] is not None:
+                return 0
+            # a copy: the next refill rewrites ``stage`` while a call may
+            # still read this upload, and ``device_put`` may alias a host
+            # array or read it after returning
+            state["upload"] = jax.device_put(stage.copy(), device)
+            return stage.nbytes
 
         def enqueue(def_thresh, def_budget, precision=None, bucket=None,
                     local=None):
@@ -705,23 +709,27 @@ class ForestReplicaServer:
                     jax.device_put(jnp.float32(def_thresh), device),
                     jax.device_put(jnp.int32(def_budget), device))
             nxt, hops, energy, state["step"] = _serve_eval_packed(
-                pack, state["x"], key, state["step"], state["thr"],
-                state["bud"], knobs[0], knobs[1], per_hop, transfer,
-                max_hops=pack.n_groves, backend=backend, block_b=block_b)
+                pack, state["upload"], key, state["step"], knobs[0], knobs[1],
+                per_hop, transfer, max_hops=pack.n_groves, backend=backend,
+                block_b=block_b)
             return nxt, hops, energy
 
-        return types.SimpleNamespace(
-            splice=_apply_staged, enqueue=enqueue,
+        replica = types.SimpleNamespace(
+            splice=upload, enqueue=enqueue,
             admit_many=admit_many, retire_many=retire_many,
             # the fused kernel's walk: lane block and compaction
             block_b=lane_block(block_b, span), compact=SERVE_COMPACT,
             bucketed=True,              # serves registry buckets
-            state=state)                # resident device buffers (inspection)
+            stage=stage,                # host staging buffer (inspection)
+            state=state)                # device step counter, last upload
+        self._replicas[index] = replica
+        return replica
 
     def prefill(self, slot: int, prompt: np.ndarray) -> int:
-        """Write the request's feature row into its slot's host mirror
+        """Write the request's feature row into its slot's staged row
         (the batcher's ``prefill_fn`` argument; with a dispatcher the
-        batcher stages rows through ``admit_lanes`` and never calls it)."""
+        batcher stages rows through ``admit_lanes`` and never calls it);
+        the next dispatch uploads it."""
         if self._span is None:
             raise ValueError("server not bound; construct the batcher "
                              "with its DeviceDispatcher first")
@@ -730,5 +738,7 @@ class ForestReplicaServer:
             raise ValueError(
                 f"request feature row has {row.shape[0]} features, "
                 f"server expects {self.n_features}")
-        self._buffers[slot // self._span][slot % self._span] = row
+        replica = self._replicas[slot // self._span]
+        replica.stage[slot % self._span, :self.n_features] = row
+        replica.state["upload"] = None
         return 1

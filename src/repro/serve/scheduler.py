@@ -314,9 +314,11 @@ class ContinuousBatcher:
     the host, all and those whose copy started at enqueue, the requests
     admitted to each replica ``d`` (``routed_<d>``), the policy
     validations ``submit`` ran (``policy_checks``: once per distinct
-    policy object) and the admission groups refill bursts resolved
-    (``admit_groups``); set it back to False to uninstall the collector
-    callback.
+    policy object), the admission groups refill bursts resolved
+    (``admit_groups``) and the replicas' slot-state uploads and their
+    bytes (``span_uploads``, ``span_upload_bytes``: one per replica a
+    step that admitted or retired there, whatever its bucket calls); set
+    it back to False to uninstall the collector callback.
 
     Per-lane state lives in arrays indexed by lane: the request, tokens
     left, serving bucket, tier and (tenant, version) codes.  Work that
@@ -434,7 +436,7 @@ class ContinuousBatcher:
         self.counts = dict.fromkeys(
             ("lane_hops_walked", "lane_hops_served", "host_copies",
              "host_copies_prefetched", *self._routed, "policy_checks",
-             "admit_groups"), 0)
+             "admit_groups", "span_uploads", "span_upload_bytes"), 0)
         self._trace = False
         self._gc_open: tuple | None = None
         self.n_steps = 0

@@ -150,4 +150,5 @@ def test_cross_chip_phases_on_four_host_devices(tmp_path):
     assert placement
     for i in range(4):
         assert f"r{i}@{i}:" in placement[0]
-        assert f"'x': [{i}]" in placement[0].split(f"r{i}@{i}:")[1]
+        # the step counter the replica's last call returned
+        assert f"'step': [{i}]" in placement[0].split(f"r{i}@{i}:")[1]

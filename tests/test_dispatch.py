@@ -211,6 +211,74 @@ def test_forest_replica_server_validates_rows(trained):
         server.prefill(0, ds.x_test[0][:3])
 
 
+def test_span_upload_is_a_copy_reused_by_the_step_s_second_call(
+        trained, monkeypatch):
+    """The forest replica uploads its staged span once per change: a
+    second bucket call in the same step reuses the upload, and a write
+    to the staging buffer while a call is in flight (an admit, a prefill)
+    reaches neither that call's input nor its outputs.  ``device_put`` is
+    handed a private copy of the staging buffer, so even a backend that
+    aliases host arrays (XLA's CPU client does, for 64-byte aligned ones)
+    cannot see a later write."""
+    ds, rf = trained
+    gc = split(rf, 2)
+    F = ds.x_test.shape[1]
+    n, k = 512, 300
+
+    def plane():
+        server = ForestReplicaServer(gc, F, backend="fused",
+                                     precisions=("fp32", "int8"), seed=2)
+        disp = DeviceDispatcher(server.packed_factory, [jax.devices()[0]])
+        disp.bind(n)
+        return server, disp
+
+    lanes = np.arange(k)
+    knobs = (np.full(k, 0.7, np.float32), np.full(k, NO_BUDGET, np.int32))
+    server, disp = plane()
+    rep = disp.replicas[0]
+    disp.admit_lanes(lanes, list(ds.x_test[:k]), *knobs)
+    handed, put = [], jax.device_put
+
+    def noting_put(x, *a, **kw):
+        handed.append(x)
+        return put(x, *a, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_put", noting_put)
+        first = disp.dispatch_packed(lanes, 0.7, NO_BUDGET, precision="fp32")
+    [host] = [x for x in handed if np.shape(x) == rep.stage.shape]
+    assert not np.shares_memory(host, rep.stage)
+    upload, staged = rep.state["upload"], rep.stage.copy()
+    second = disp.dispatch_packed(lanes, 0.7, NO_BUDGET, precision="int8")
+    assert first[0].uploaded == n * (F + 2) * 4 == staged.nbytes
+    assert second[0].uploaded == 0 and rep.state["upload"] is upload
+    # rewrite every staged lane before the calls are harvested
+    disp.admit_lanes(lanes, list(ds.x_test[k:2 * k]), *knobs)
+    server.prefill(k, ds.x_test[0])
+    assert rep.state["upload"] is None
+    assert not np.array_equal(rep.stage, staged)
+    np.testing.assert_array_equal(np.asarray(upload), staged)
+    counts = dict.fromkeys(("lane_hops_walked", "lane_hops_served",
+                            "host_copies", "host_copies_prefetched",
+                            "span_uploads", "span_upload_bytes"), 0)
+    got = disp.harvest_packed(n, counts=counts)
+    assert (counts["span_uploads"], counts["span_upload_bytes"]) == (
+        1, staged.nbytes)
+    # the same two calls on a plane whose staging nobody rewrote
+    _, ref = plane()
+    ref.admit_lanes(lanes, list(ds.x_test[:k]), *knobs)
+    for prec in ("fp32", "int8"):
+        ref.dispatch_packed(lanes, 0.7, NO_BUDGET, precision=prec)
+    want = ref.harvest_packed(n)
+    for a, b in zip(got[:3], want[:3]):
+        np.testing.assert_array_equal(a, b)
+    # the rewrite is what the next call evaluates: a fresh upload
+    nxt = disp.dispatch_packed(lanes, 0.7, NO_BUDGET, precision="fp32")
+    assert nxt[0].uploaded == staged.nbytes
+    np.testing.assert_array_equal(np.asarray(rep.state["upload"]), rep.stage)
+    disp.harvest_packed(n)
+
+
 def test_forest_replica_server_energy_models(trained):
     ds, rf = trained
     server = ForestReplicaServer(split(rf, 2), ds.x_test.shape[1],

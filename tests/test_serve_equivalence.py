@@ -13,7 +13,12 @@ cross-process runs see different data, but within a process each mode
 rebuilds an identical plane from the same seed.
 """
 import heapq
+import json
+import os
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -98,6 +103,162 @@ def test_lane_policies_dirty_tracking_round_trip():
     rthr, rbud = lp.resolve(FogPolicy(threshold=0.9, hop_budget=7))
     assert rthr[0] == np.float32(0.9) and rbud[0] == 7
     assert rthr[1] == np.float32(0.5) and rbud[1] == 3
+
+
+# --------------------------------------------------------------------------
+# the packed replica against a plain replay
+# --------------------------------------------------------------------------
+
+_REPLAY = r"""
+import json, sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.core import split
+from repro.core.energy import EnergyModel
+from repro.core.engine import _eval_core
+from repro.core.policy import (BUDGET_DEFAULT, DEAD_BUDGET, DEAD_THRESH,
+                               NO_BUDGET, THRESH_DEFAULT)
+from repro.data import make_dataset
+from repro.forest import ForestPack, TrainConfig, train_random_forest
+from repro.launch.mesh import serve_devices
+from repro.serve.dispatch import DeviceDispatcher, ForestReplicaServer
+
+n_dev, SPAN, SEED = int(sys.argv[1]), 8, 5
+N = SPAN * n_dev
+ds = make_dataset("penbased")
+rf = train_random_forest(ds.x_train, ds.y_train, ds.n_classes,
+                         TrainConfig(n_trees=8, max_depth=5, seed=1))
+gc = split(rf, 2)
+F = ds.x_test.shape[1]
+PRECS = ("fp32", "int8")
+server = ForestReplicaServer(gc, F, backend="fused", precisions=PRECS,
+                             seed=SEED)
+disp = DeviceDispatcher(server.packed_factory, serve_devices(n_dev))
+disp.bind(N)
+
+# the replay's state: rows and knobs set on the host, one draw counter a
+# replica, one increment per call
+x = np.zeros((N, F), np.float32)
+thr = np.full((N,), DEAD_THRESH, np.float32)
+bud = np.full((N,), DEAD_BUDGET, np.int32)
+step = [1] * n_dev
+packs = {p: ForestPack.from_groves(gc, p) for p in PRECS}
+G = packs["fp32"].n_groves
+
+
+def replay(lanes, def_thr, def_bud, prec, spans):
+    pack = packs[prec]
+    m = EnergyModel.from_pack(pack, F)
+    out = {}
+    for d in np.unique(lanes // SPAN).tolist():
+        lo = d * SPAN
+        start = jax.random.randint(
+            jax.random.fold_in(jax.random.key(SEED + d), step[d]),
+            (SPAN,), 0, G)
+        step[d] += 1
+        t = thr[lo:lo + SPAN]
+        b = bud[lo:lo + SPAN]
+        t = np.where(np.isnan(t), np.float32(def_thr), t)
+        b = np.where(b < 0, np.int32(def_bud), b)
+        res = _eval_core(pack, jnp.asarray(x[lo:lo + SPAN]), start,
+                         jnp.asarray(t), jnp.asarray(b), G, "fused",
+                         SPAN, False, True)
+        nxt = np.asarray(jnp.argmax(res.proba, axis=-1)).astype(np.int32)
+        h = np.asarray(res.hops)
+        spans[f"{d}/{prec}"] = h.tolist()
+        hf = h.astype(np.float32)
+        e = (hf * np.float32(m.per_hop_pj)
+             + np.maximum(hf - np.float32(1), np.float32(0))
+             * np.float32(m.transfer_pj))
+        for i in lanes[(lanes >= lo) & (lanes < lo + SPAN)].tolist():
+            out[i] = (int(nxt[i - lo]), int(h[i - lo]),
+                      float(e[i - lo]))
+    return out
+
+
+rng = np.random.default_rng(11)
+live = {}                       # lane -> precision
+served, replayed = [], []
+served_spans, replayed_spans = [], []
+spilled = False
+for t in range(7):
+    # retires: about a third of the live lanes
+    gone = [i for i in sorted(live) if rng.random() < 0.35]
+    if gone:
+        disp.retire_lanes(gone)
+        thr[gone], bud[gone] = DEAD_THRESH, DEAD_BUDGET
+        for i in gone:
+            del live[i]
+    # an admission burst into free lanes, over every replica it finds
+    free = [i for i in range(N) if i not in live]
+    k = 0 if t == 4 else min(len(free), int(rng.integers(2, SPAN + 3)))
+    lanes = np.sort(rng.choice(free, size=k, replace=False))
+    spilled |= len(np.unique(lanes // SPAN)) > 1
+    rows = ds.x_test[rng.integers(0, len(ds.x_test), k)]
+    t_k = np.where(rng.random(k) < 0.5, THRESH_DEFAULT,
+                   rng.uniform(0.3, 0.95, k)).astype(np.float32)
+    b_k = np.where(rng.random(k) < 0.5, BUDGET_DEFAULT,
+                   np.where(rng.random(k) < 0.5, NO_BUDGET,
+                            rng.integers(1, G + 1, k))).astype(np.int32)
+    disp.admit_lanes(lanes, list(rows), t_k, b_k)
+    x[lanes], thr[lanes], bud[lanes] = rows, t_k, b_k
+    for i in lanes.tolist():
+        live[i] = PRECS[int(rng.integers(2))]
+    # a knob restamp of some live lanes (rows None)
+    if t % 3 == 2:
+        re = np.asarray(sorted(live)[::2], np.int64)
+        disp.admit_lanes(re, None, np.full(len(re), 0.6, np.float32),
+                         np.full(len(re), 2, np.int32))
+        thr[re], bud[re] = 0.6, 2
+    # the fleet's rung moves after step 3
+    def_thr, def_bud = (0.8, NO_BUDGET) if t < 3 else (0.5, 3)
+    want, spans = {}, {}
+    for prec in PRECS:          # fp32 and int8 buckets in the same step
+        lanes = np.asarray([i for i in sorted(live) if live[i] == prec],
+                           np.int64)
+        if lanes.size:
+            disp.dispatch_packed(lanes, def_thr, def_bud, precision=prec)
+            want.update(replay(lanes, def_thr, def_bud, prec, spans))
+    nxt, hops, energy, pend = disp.harvest_packed(N)
+    # every lane of each call's span, the retired ones included
+    served_spans.append({f"{p.device}/{p.precision}":
+                         np.asarray(p.hops).tolist() for p in pend})
+    replayed_spans.append(spans)
+    served.append({i: (int(nxt[i]), int(hops[i]), float(energy[i]))
+                   for i in want})
+    replayed.append(want)
+print(json.dumps({"served": [sorted(s.items()) for s in served],
+                  "replayed": [sorted(r.items()) for r in replayed],
+                  "spans": [served_spans, replayed_spans],
+                  "spilled": spilled}))
+"""
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_packed_replica_serves_as_a_plain_replay(n_dev):
+    """A seeded sequence of admits, retires (one step retires and admits
+    nothing), knob restamps, a fleet rung change and fp32 + int8 bucket
+    calls in one step (and, on four forced host devices, bursts spilled
+    over replicas) served through the packed replica gives, bit for bit,
+    the (label, hops, energy) of a plain replay that keeps rows and knobs
+    on the host and evaluates each call's span with the replica's start
+    draw, one counter step per call; the hops of every lane of each
+    call's span, retired lanes included, match too."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
+               PYTHONPATH=str(Path(__file__).resolve().parent.parent / "src"))
+    proc = subprocess.run([sys.executable, "-c", _REPLAY, str(n_dev)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["served"] == out["replayed"]
+    assert out["spans"][0] == out["spans"][1]
+    assert sum(len(s) for s in out["served"]) > 20
+    assert out["spilled"] == (n_dev > 1)
 
 
 # --------------------------------------------------------------------------

@@ -99,7 +99,7 @@ def test_trace_off_builds_no_span(trained, monkeypatch):
     assert b.counts == dict.fromkeys(
         ("lane_hops_walked", "lane_hops_served", "host_copies",
          "host_copies_prefetched", "routed_0", "policy_checks",
-         "admit_groups"), 0)
+         "admit_groups", "span_uploads", "span_upload_bytes"), 0)
     assert b.phase_ns["gc"] == 0
     # the same run with tracing on does build them (the stand-in works)
     b.trace = True
@@ -297,6 +297,27 @@ def test_policy_check_and_admit_group_counters(trained, trace):
     assert b.counts["policy_checks"] == 2
     assert b.counts["admit_groups"] == sum(tenants_per_burst)
     assert b.counts["admit_groups"] <= 2 * len(tenants_per_burst)
+
+
+def test_span_upload_counters(trained):
+    """With ``trace`` on, ``span_uploads`` counts one upload a step that
+    dispatched (every step admits or retires on the one replica), never
+    more than the calls made, and ``span_upload_bytes`` the whole staged
+    span each time; the upload stays inside the ``splice`` sub-phase."""
+    b = _plane(trained, 8)
+    F = trained[0].x_test.shape[1]
+    b.trace = True
+    try:
+        harvests = _serve(b, trained[0].x_test, 12)
+    finally:
+        b.trace = False
+    c = b.counts
+    calls = sum(len(pend) for pend in harvests)
+    assert len(harvests) <= c["span_uploads"] <= calls
+    assert c["span_uploads"] == len(harvests)
+    assert calls > len(harvests)        # the second tenant's calls reused it
+    assert c["span_upload_bytes"] == c["span_uploads"] * 8 * (F + 2) * 4
+    assert b.phase_ns["splice"] > 0
 
 
 def _recount_walked(h, block):

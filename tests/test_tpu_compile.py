@@ -1,4 +1,5 @@
-"""Compile every Pallas kernel of the forest path for a described TPU v5e.
+"""Compile every Pallas kernel of the forest path, and the serving
+replica's whole call around the fused one, for a described TPU v5e.
 
 Nothing runs: the TPU compiler (shipped with libtpu) compiles each kernel
 for a chip that is described, not attached, and raises what the chip's
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.forest.pack import ForestPack
 from repro.kernels.fused_fog import fit_block_b, fused_fog_pallas
 from repro.kernels.grove_aggregate import grove_aggregate_pallas
 from repro.kernels.histogram import histogram_level_pallas
@@ -91,6 +93,36 @@ def test_fused_fog_compiles(one_chip, field, dtype, compact):
             jax.ShapeDtypeStruct((B,), jnp.float32),
             jax.ShapeDtypeStruct((B,), jnp.int32)]
     compiled = _compile(run, args, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("field,dtype", [(BENCH, jnp.float32),
+                                         (BENCH, jnp.int8),
+                                         (ISOLET, jnp.float32)],
+                         ids=["bench-fp32", "bench-int8", "isolet-fp32"])
+def test_serve_eval_packed_compiles(one_chip, monkeypatch, field, dtype):
+    """The serving replica's whole call (``_serve_eval_packed``: the staged
+    ``[span, F + 2]`` span split into rows, thresholds and bitcast
+    budgets, the start draw, the fused kernel, argmax and pricing) at a
+    1,024-lane span, with the kernel compiled for Mosaic."""
+    from repro.serve.dispatch import _serve_eval_packed
+    # the kernel picks Mosaic over the interpreter from the backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    G, t, depth, C, F = field
+    pack = ForestPack(*(jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+                        for a in _field(field, dtype)),
+                      precision="fp32" if dtype == jnp.float32 else "int8")
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = _serve_eval_packed.lower(
+        pack, spec((B, F + 2), jnp.float32),
+        spec((), jax.random.key(0).dtype), spec((), jnp.int32),
+        spec((), jnp.float32), spec((), jnp.int32), spec((), jnp.float32),
+        spec((), jnp.float32), max_hops=G, backend="fused",
+        block_b=256).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
